@@ -480,9 +480,9 @@ class Config:
     # (ops/ordered_hist.py canonical_row_chunks) so nearby dataset sizes
     # share lowered executables through the persistent compile cache
     shape_bucketing: str = "auto"
-    # persistent XLA compilation cache: "auto" = LIGHTGBM_TPU_CACHE_DIR
-    # or ~/.cache/lightgbm_tpu/jax_cache, "off" disables, any other
-    # value is the cache directory (setup_compilation_cache below)
+    # persistent XLA compilation cache: "off" disables; anything else
+    # uses JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_cache
+    # (setup_compilation_cache below)
     compile_cache: str = "auto"
     profile: str = ""              # jax.profiler trace dir ("1" = default dir)
 
@@ -714,13 +714,17 @@ class Config:
 # Persistent compilation cache.
 #
 # The jitted tree builders are a single large XLA program per (shapes,
-# config) pair; a cold compile costs 10-60s — more than a whole scaled
-# CPU training run. Pointing jax at an on-disk cache makes that a
-# once-per-machine cost: every later process with the same lowered
-# program (shape bucketing in ops/ordered_hist.py canonical_row_chunks
-# widens "same") loads the executable in milliseconds.
+# config) pair, and a cold compile can cost more than a short training
+# run. Pointing jax at an on-disk cache makes that a once-per-machine
+# cost: every later process with the same lowered program (shape
+# bucketing in ops/ordered_hist.py canonical_row_chunks widens "same")
+# loads the executable instead. The directory is part of the cache key,
+# so it must not move between runs: it comes from outside
+# (JAX_COMPILATION_CACHE_DIR) or from the checkout, never from $HOME, a
+# temp name, a pid or the clock.
 
-_CACHE_HITS = {"hits": 0, "misses": 0, "listener": False}
+_CACHE_HITS = {"hits": 0, "misses": 0, "listener": False,
+               "warned_unusable": False}
 
 
 def _cache_event_listener(name, **kwargs):
@@ -736,15 +740,22 @@ def compile_cache_hits():
     return _CACHE_HITS["hits"]
 
 
+def checkout_cache_dir():
+    """`<checkout>/.jax_cache`: beside the package, wherever it lives."""
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+
+
 def setup_compilation_cache(config=None):
     """Configure jax's persistent compilation cache once per process.
 
-    Resolution order: an embedder's existing jax_compilation_cache_dir
-    wins (tests / bench children set their own); else
-    `config.compile_cache` ("off" disables, a path is used verbatim,
-    "auto"/"on" fall through to $LIGHTGBM_TPU_CACHE_DIR or
-    ~/.cache/lightgbm_tpu/jax_cache). Returns the active cache dir or
-    None. Never fatal: an unwritable directory only costs the cache.
+    One rule: `compile_cache=off` disables; if JAX_COMPILATION_CACHE_DIR
+    is set, jax already has that directory and no code sets another;
+    otherwise the cache lives in `<checkout>/.jax_cache`
+    (checkout_cache_dir). Returns the active directory, or None when the
+    cache is off — an unusable directory is reported once, by name, at
+    warning level and turns the cache off.
     """
     # the compile ledger rides the same monitoring stream; installing
     # it here covers every compile path (training learners AND the
@@ -758,33 +769,33 @@ def setup_compilation_cache(config=None):
     if not _CACHE_HITS["listener"]:
         _CACHE_HITS["listener"] = True
         jax.monitoring.register_event_listener(_cache_event_listener)
-    existing = jax.config.jax_compilation_cache_dir
-    if existing:
-        return existing
-    if mode.lower() in ("auto", "on", "true", "1", "+"):
-        path = (os.environ.get("LIGHTGBM_TPU_CACHE_DIR")
-                or os.path.join(os.path.expanduser("~"), ".cache",
-                                "lightgbm_tpu", "jax_cache"))
-    else:
-        path = mode
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
         # the tree builders' XLA-backend compile can land under the 1s
         # default threshold even when the full trace+lower+compile is
         # 10s+ — cache every executable, the disk cost is a few MB
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # the cache backend freezes on the process's FIRST compile
-        # (dataset construction usually compiles before training config
-        # exists); re-initialize it against the directory just set
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          0.0)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return jax.config.jax_compilation_cache_dir
+    path = checkout_cache_dir()
+    if jax.config.jax_compilation_cache_dir == path:
+        return path
+    try:
+        os.makedirs(path, exist_ok=True)
+        if not os.access(path, os.W_OK):
+            raise PermissionError("directory is not writable")
     except OSError as e:
-        Log.warning("compile cache disabled (cannot use %s: %s)", path, e)
+        if not _CACHE_HITS["warned_unusable"]:
+            _CACHE_HITS["warned_unusable"] = True
+            Log.warning("compile cache off: cannot use %s (%s); set "
+                        "JAX_COMPILATION_CACHE_DIR to place it elsewhere",
+                        path, e)
         return None
-    except Exception as e:  # cache API drift must never break training
-        Log.warning("compile cache reset failed (%s); continuing", e)
+    jax.config.update("jax_compilation_cache_dir", path)
+    # the cache backend freezes on the process's FIRST compile (dataset
+    # construction usually compiles before a training config exists);
+    # re-initialize it against the directory just set
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
     return path
 
 

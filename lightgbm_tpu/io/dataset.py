@@ -156,8 +156,7 @@ def check_bins_budget(rows, cols, itemsize, what):
 def _bin_dense_on_device(mat, real_idx, mappers, dtype):
     """Full-matrix binning on the accelerator: bin k = #(bounds < v)
     == np.searchsorted(bounds, v, 'left') for every numerical mapper.
-    The host pass costs ~82 s at 11M x 28 on this single-core box;
-    the device compare-sum is O(N*F*B) VPU compares (~0.1 s) plus the
+    The device compare-sum is O(N*F*B) VPU compares plus the
     raw-matrix transfer — the reference bins on CPU because it IS a
     CPU framework (bin.cpp FindBin/value_to_bin); an accelerator-first
     loader puts the scan where the FLOPs are.
@@ -168,69 +167,67 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
     thresholds, models/gbdt.py _device_model).
 
     Gated by LIGHTGBM_TPU_DEVICE_BIN (default auto = non-CPU backends,
-    numerical features only). Returns (F, N) bins or None (caller
-    falls back to the threaded host pass)."""
+    numerical features only). Returns (F, N) bins, or None when the
+    inputs are ineligible or the gate is off (the caller then bins on
+    the host). Those are decisions; a failure of the device pass itself
+    propagates — a host result in its place would hide a broken
+    device path."""
     mode = os.environ.get("LIGHTGBM_TPU_DEVICE_BIN", "auto")
     if mode == "0":
         return None
-    try:
-        import jax
-        import jax.numpy as jnp
-        if mode == "auto" and jax.default_backend() == "cpu":
-            return None
-        if any(m.bin_type != NUMERICAL for m in mappers):
-            return None
-        if mat.dtype != np.float32:
-            # the -inf-rounded f32 bounds make the compare exact for
-            # f32 INPUTS only; f64 matrices (text loads keep f64 so
-            # boundaries survive the last digit, parser.py) must bin
-            # through the host f64 searchsorted
-            return None
-        n = mat.shape[0]
-        f = len(real_idx)
-        b_max = max(len(m.bin_upper_bound) for m in mappers)
-        bounds = np.full((f, b_max), np.inf)
-        for u, m in enumerate(mappers):
-            bounds[u, :len(m.bin_upper_bound)] = m.bin_upper_bound
-        b32 = bounds.astype(np.float32)
-        lifted = b32.astype(np.float64) > bounds
-        b32 = np.where(lifted,
-                       np.nextafter(b32, np.float32(-np.inf),
-                                    dtype=np.float32), b32)
-        # (+inf pad bounds contribute 0 to the strict-compare count)
-        chunk = 1 << 16
-        n_pad = -(-n // chunk) * chunk
-        all_cols = (f == mat.shape[1]
-                    and np.array_equal(real_idx, np.arange(f)))
-        if n_pad == n and all_cols and mat.flags.c_contiguous:
-            x_used = mat            # zero-copy fast path
-        else:
-            # ONE full-size buffer: pad rows + column-select in place
-            x_used = np.zeros((n_pad, f), np.float32)
-            x_used[:n] = mat if all_cols else mat[:, real_idx]
-        # host rule bins NaN like the value 0.0 (bin.h NaN->zero-bin);
-        # on device NaN compares false everywhere -> raw bin 0, which
-        # differs when a column has negative bounds
-        if np.isnan(x_used).any():
-            x_used = np.nan_to_num(x_used, nan=0.0)
-        xdev = jnp.asarray(x_used).reshape(n_pad // chunk, chunk, f)
-        bdev = jnp.asarray(b32)
-        out_dt = jnp.dtype(dtype)
-
-        @jax.jit
-        def bin_all(xc):
-            def one(xb):   # (chunk, F) -> (chunk, F) narrow ints
-                return jnp.sum(xb[:, :, None] > bdev[None, :, :],
-                               axis=-1, dtype=jnp.int32).astype(out_dt)
-            return jax.lax.map(one, xc)
-
-        # narrow on device: the download is N x F bytes, not 4x that
-        out = np.asarray(bin_all(xdev)).reshape(n_pad, f)[:n]
-        return np.ascontiguousarray(out.T).astype(dtype, copy=False)
-    except Exception as e:   # any device hiccup: host pass is the truth
-        Log.warning("Device binning unavailable (%s); binning on host",
-                    e)
+    import jax
+    import jax.numpy as jnp
+    if mode == "auto" and jax.default_backend() == "cpu":
         return None
+    if any(m.bin_type != NUMERICAL for m in mappers):
+        return None
+    if mat.dtype != np.float32:
+        # the -inf-rounded f32 bounds make the compare exact for
+        # f32 INPUTS only; f64 matrices (text loads keep f64 so
+        # boundaries survive the last digit, parser.py) must bin
+        # through the host f64 searchsorted
+        return None
+    n = mat.shape[0]
+    f = len(real_idx)
+    b_max = max(len(m.bin_upper_bound) for m in mappers)
+    bounds = np.full((f, b_max), np.inf)
+    for u, m in enumerate(mappers):
+        bounds[u, :len(m.bin_upper_bound)] = m.bin_upper_bound
+    b32 = bounds.astype(np.float32)
+    lifted = b32.astype(np.float64) > bounds
+    b32 = np.where(lifted,
+                   np.nextafter(b32, np.float32(-np.inf),
+                                dtype=np.float32), b32)
+    # (+inf pad bounds contribute 0 to the strict-compare count)
+    chunk = 1 << 16
+    n_pad = -(-n // chunk) * chunk
+    all_cols = (f == mat.shape[1]
+                and np.array_equal(real_idx, np.arange(f)))
+    if n_pad == n and all_cols and mat.flags.c_contiguous:
+        x_used = mat            # zero-copy fast path
+    else:
+        # ONE full-size buffer: pad rows + column-select in place
+        x_used = np.zeros((n_pad, f), np.float32)
+        x_used[:n] = mat if all_cols else mat[:, real_idx]
+    # host rule bins NaN like the value 0.0 (bin.h NaN->zero-bin);
+    # on device NaN compares false everywhere -> raw bin 0, which
+    # differs when a column has negative bounds
+    if np.isnan(x_used).any():
+        x_used = np.nan_to_num(x_used, nan=0.0)
+    xdev = jnp.asarray(x_used).reshape(n_pad // chunk, chunk, f)
+    bdev = jnp.asarray(b32)
+    out_dt = jnp.dtype(dtype)
+
+    @jax.jit
+    def bin_all(xc):
+        def one(xb):   # (chunk, F) -> (chunk, F) narrow ints
+            return jnp.sum(xb[:, :, None] > bdev[None, :, :],
+                           axis=-1, dtype=jnp.int32).astype(out_dt)
+        return jax.lax.map(one, xc)
+
+    # narrow on device: the download is N x F bytes, not 4x that
+    out = np.asarray(bin_all(xdev)).reshape(n_pad, f)[:n]
+    return np.ascontiguousarray(out.T).astype(dtype, copy=False)
 
 
 def _bin_columns_threaded(col_fn, count):
@@ -377,6 +374,7 @@ class CoreDataset:
         self.raw_data = None          # optional (N, C) float32 original values
         self.global_num_data = None   # set by per-rank loading (multi-host)
         self.bundle_plan = None       # io/bundling.py BundlePlan or None
+        self.binned_on_device = False  # _bin_dense_on_device produced bins
         # training-time baseline distribution (io/profile.py
         # DatasetProfile): per-feature bin occupancy + missing counts,
         # captured once at binning and persisted through the binary
@@ -1318,6 +1316,7 @@ class DatasetLoader:
                                              np.asarray(real_idx),
                                              mappers, dtype)
                         if isinstance(src, DenseColumns) else None)
+            ds.binned_on_device = dev_bins is not None
             ds.bins = dev_bins if dev_bins is not None else np.stack(
                 _bin_columns_threaded(
                     lambda u: mappers[u].value_to_bin(

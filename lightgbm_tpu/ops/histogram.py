@@ -41,10 +41,11 @@ env, resolved by `chunk_mode()` / `use_pallas()`):
 - "einsum" — the one-hot MXU contraction: right where compares are
   cheaper than scatters (non-TPU accelerators, TPU XLA fallback).
 
-A non-auto mode forces that formulation everywhere it can run (pallas
-off-TPU falls back with a warning; einsum/segment/bincount on TPU
-disable the Pallas kernels — the supported escape hatch, superseding
-LIGHTGBM_TPU_DISABLE_PALLAS which remains honored).
+A non-auto mode forces that formulation everywhere it can run
+(einsum/segment/bincount on TPU take the XLA path instead of the Pallas
+kernels). hist_mode=pallas without a TPU backend is a fatal error: the
+kernels cannot run there, and substituting another formulation would
+report a path that did not run.
 
 Smaller-child compaction (compacted_histograms): the default dense
 training path (models/tree_learner.py) gathers the active leaf's rows
@@ -112,7 +113,6 @@ def _parse_hist_mode():
 # always had).
 _DEFAULT_HIST_MODE = _parse_hist_mode()
 HIST_MODE = _DEFAULT_HIST_MODE
-_WARNED_PALLAS_FALLBACK = False
 
 
 def set_hist_mode(mode):
@@ -120,31 +120,25 @@ def set_hist_mode(mode):
     (models/tree_learner.py init). "auto" RESTORES the env-derived
     process default (LIGHTGBM_TPU_HIST_MODE or auto), so one Booster's
     forced mode never leaks into the next Booster's."""
-    global HIST_MODE, _WARNED_PALLAS_FALLBACK
+    global HIST_MODE
+    from ..utils.log import Log
     mode = str(mode).lower()
     if mode not in _HIST_MODES:
-        from ..utils.log import Log
         Log.fatal("hist_mode must be one of %s, got [%s]",
                   "/".join(_HIST_MODES), mode)
-    HIST_MODE = _DEFAULT_HIST_MODE if mode == "auto" else mode
-    if (HIST_MODE == "pallas" and jax.default_backend() != "tpu"
-            and not _WARNED_PALLAS_FALLBACK):
-        from ..utils.log import Log
-        Log.warning("hist_mode=pallas needs a TPU backend (got %s); "
-                    "falling back to the auto formulation",
-                    jax.default_backend())
-        _WARNED_PALLAS_FALLBACK = True
+    resolved = _DEFAULT_HIST_MODE if mode == "auto" else mode
+    if resolved == "pallas" and jax.default_backend() != "tpu":
+        Log.fatal("hist_mode=pallas needs a TPU backend, got [%s]",
+                  jax.default_backend())
+    HIST_MODE = resolved
 
 
 def use_pallas():
     """Whether the Pallas TPU kernels are the active histogram engine
-    (resolved at trace time). True only on a real TPU backend with
-    hist_mode auto/pallas and the legacy escape hatch unset."""
-    if jax.default_backend() != "tpu":
-        return False
-    if os.environ.get("LIGHTGBM_TPU_DISABLE_PALLAS"):
-        return False
-    return HIST_MODE in ("auto", "pallas")
+    (resolved at trace time): a TPU backend with hist_mode
+    auto/pallas."""
+    return (jax.default_backend() == "tpu"
+            and HIST_MODE in ("auto", "pallas"))
 
 
 _NO_CALLBACKS = threading.local()
@@ -203,8 +197,8 @@ def chunk_mode():
     "bincount" | "segment" | "einsum"."""
     mode = HIST_MODE
     if mode in ("auto", "pallas"):
-        # pallas off-TPU falls back like auto (the kernels cannot run);
-        # on TPU this path is only reached for XLA fallbacks
+        # on TPU this is only reached by the XLA reference paths
+        # (masked_histograms_xla / _seg_hist_xla)
         mode = ("bincount" if jax.default_backend() == "cpu"
                 else "einsum")
     if mode == "bincount" and getattr(_NO_CALLBACKS, "depth", 0):

@@ -31,7 +31,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_hist import HIST_CHUNK
+from .pallas_hist import (HIST_CHUNK, STAT_TERMS, fold_stats, onehot_dot,
+                          split_stats)
 
 
 def pack_feature_words(bins_u8):
@@ -133,16 +134,13 @@ def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad):
     # (pallas_guide.md "TPU requires at least 2D iota"), and staying
     # (C, 1) lets the mask broadcast into (C, 3) with no rank changes
     pos = step * c + jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
-    mask = ((pos >= lohi_ref[0]) & (pos < lohi_ref[1])).astype(jnp.float32)
-    ghc_m = ghc_ref[...] * mask                                   # (C, 3)
+    mask = (pos >= lohi_ref[0]) & (pos < lohi_ref[1])             # (C, 1)
+    ghc_m = jnp.where(mask, ghc_ref[...], 0)                      # (C, 9)
     b_iota = jax.lax.broadcasted_iota(jnp.int32, (b_pad, c), 0)
     for i in range(f):
         word = words_ref[i >> 2, :]
         bins_f = (word >> ((i & 3) * 8)) & 0xFF
-        onehot = (bins_f[None, :] == b_iota).astype(jnp.float32)  # (B_pad, C)
-        out_ref[i, :, :] += jax.lax.dot_general(
-            onehot, ghc_m, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                   # (B_pad, 3)
+        out_ref[i, :, :] += onehot_dot(bins_f[None, :], b_iota, ghc_m)
 
 
 def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
@@ -161,14 +159,14 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
             pl.BlockSpec(memory_space=pltpu.SMEM),  # (2,) lo/hi
             pl.BlockSpec((w, HIST_CHUNK), lambda i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((HIST_CHUNK, 3), lambda i: (i, 0),
+            pl.BlockSpec((HIST_CHUNK, STAT_TERMS), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((f, b_pad, 3), lambda i: (0, 0, 0),
+        out_specs=pl.BlockSpec((f, b_pad, STAT_TERMS), lambda i: (0, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((f, b_pad, 3), jnp.float32),
-    )(jnp.stack([lo, hi]).astype(jnp.int32), words_sl, ghc_sl)
-    return out[:, :num_bins_total, :]
+        out_shape=jax.ShapeDtypeStruct((f, b_pad, STAT_TERMS), jnp.float32),
+    )(jnp.stack([lo, hi]).astype(jnp.int32), words_sl, split_stats(ghc_sl))
+    return fold_stats(out[:, :num_bins_total, :])
 
 
 def _seg_hist_xla(words_sl, ghc_sl, lo, hi, f, num_bins_total):
@@ -214,9 +212,8 @@ def segment_histograms(words, ghc_t, begin, cnt, num_bins_total, f,
     if interpret_backend is None:
         # same dispatch as ops/pallas_hist.py masked_histograms: TPU
         # with hist_mode auto/pallas runs the kernel; einsum/segment/
-        # bincount (or LIGHTGBM_TPU_DISABLE_PALLAS=1) force the XLA
-        # path (bench.py fallback ladder); an explicit
-        # interpret_backend wins
+        # bincount take the XLA path; an explicit interpret_backend
+        # wins
         from .histogram import use_pallas
         on_tpu = use_pallas()
     else:

@@ -41,16 +41,10 @@ def _call_initialize(coordinator, num_processes, rank, timeout_s):
     harness (`fail_distributed_init`) and tests can intercept it."""
     if faults.consume("fail_distributed_init"):
         raise RuntimeError("injected distributed-init failure")
-    try:
-        jax.distributed.initialize(coordinator_address=coordinator,
-                                   num_processes=num_processes,
-                                   process_id=rank,
-                                   initialization_timeout=timeout_s)
-    except TypeError:
-        # older jax without initialization_timeout
-        jax.distributed.initialize(coordinator_address=coordinator,
-                                   num_processes=num_processes,
-                                   process_id=rank)
+    jax.distributed.initialize(coordinator_address=coordinator,
+                               num_processes=num_processes,
+                               process_id=rank,
+                               initialization_timeout=timeout_s)
 
 
 def _initialize_with_retry(coordinator, num_processes, rank, retries=3,
@@ -78,10 +72,9 @@ def _initialize_with_retry(coordinator, num_processes, rank, retries=3,
             return True
         except RuntimeError as e:
             msg = str(e)
-            # jax 0.4.x raises "distributed.initialize should only be
-            # called once."; other versions say "already initialized"
-            if ("already" in msg.lower()
-                    or "only be called once" in msg.lower()):
+            # jax raises "distributed.initialize should only be called
+            # once."
+            if "only be called once" in msg.lower():
                 # backend already up (e.g. an external launcher
                 # initialized distributed itself) — keep going with it
                 Log.warning("jax.distributed.initialize skipped "

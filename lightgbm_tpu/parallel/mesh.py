@@ -57,36 +57,27 @@ from ..utils.log import Log
 
 AXIS = "data"
 
-# shard_map across jax versions: new jax exports jax.shard_map with the
-# `check_vma` knob; older releases (<= 0.4.x, this image's pinned
-# toolchain) ship jax.experimental.shard_map with `check_rep` instead.
-# Same semantics for our use — both knobs only disable the replication-
-# consistency checker. ONE shim for every mesh user (parallel/learners
-# today; any future meshed subsystem imports it from here).
-if hasattr(jax, "shard_map"):
-    def shard_map(fn, mesh, in_specs, out_specs):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
 
-    def shard_map(fn, mesh, in_specs, out_specs):
-        return _exp_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(fn, mesh, in_specs, out_specs):
+    """jax.shard_map without the replication-consistency checker: the
+    builders return replicated tree arrays computed from psum'd
+    histograms, which the checker cannot prove replicated."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def meshed_trace_guard():
     """The guard every meshed builder must trace under.
 
     Host-callback kernels embedded in MULTI-DEVICE shard_map programs
-    deadlock this image's XLA CPU runtime: the dispatching thread
+    deadlock the XLA CPU runtime: the dispatching thread
     blocks in a sharded execute while the callback worker threads park
     on the GIL it holds (observed as a hang in the data-parallel
     compacted build; single-device programs are unaffected). Inside
     this context ops/histogram.py resolves "bincount" to the pure-XLA
     segment kernel instead, so the traced program holds no callbacks.
-    Lives here, next to the shard_map shim, so every future mesh user
-    picks up the caveat with the shim."""
+    Lives here, next to shard_map, so every mesh user picks up the
+    caveat with it."""
     from ..ops.histogram import callbacks_disabled
     return callbacks_disabled()
 

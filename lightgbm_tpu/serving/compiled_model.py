@@ -85,11 +85,15 @@ def _leaf_kernel(xb, sf, thr, cat, lc, rc, node0, depth):
 
 @jax.jit
 def _raw_kernel(xb, sf, thr, cat, lc, rc, lv, node0, cls_onehot, depth):
-    """(B, F) f32 rows -> (B, K) f32 raw class sums (MXU reduction)."""
+    """(B, F) f32 rows -> (B, K) f32 raw class sums (MXU reduction).
+    HIGHEST: the TPU's default f32 contraction rounds its operands to
+    bfloat16, which the ~1e-6 contract of the `_device` variants cannot
+    absorb."""
     node = device_traverse(xb, sf, thr, cat, lc, rc, node0, depth)
     t_idx = jnp.arange(sf.shape[0])
     vals = lv[t_idx[None, :], ~node]                        # (B, T)
-    return vals @ cls_onehot                                # (B, K)
+    return jnp.dot(vals, cls_onehot,
+                   precision=jax.lax.Precision.HIGHEST)     # (B, K)
 
 
 @functools.partial(jax.jit, static_argnums=(10,))
@@ -176,6 +180,7 @@ def _linraw_kernel(xb, sf, thr, cat, lc, rc, lv, node0, cls_onehot, depth,
     node = device_traverse(xb, sf, thr, cat, lc, rc, node0, depth)
     vals = _linear_leaf_values(xb, node, lv, const, coef, cfeat, ccnt)
     return jax.lax.dot(vals, cls_onehot.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
 
 

@@ -94,7 +94,17 @@ def test_frontier_kernel_interpret_matches_masked():
 
 def test_frontier_kernel_vmem_fallback(monkeypatch):
     """A frontier whose accumulator would blow the VMEM budget falls
-    back to stacked per-leaf kernel calls with identical results."""
+    back to stacked per-leaf kernel calls with identical results. The
+    budget counts TILED bytes: a trailing dim of 9 occupies 128 lanes."""
+    tiled = pallas_hist.tiled_vmem_bytes
+    assert tiled((28, 256, 9), jnp.float32) == 28 * 256 * 128 * 4
+    assert tiled((28, HIST_CHUNK), jnp.uint8) == 32 * HIST_CHUNK
+    assert tiled((HIST_CHUNK, 9), jnp.bfloat16) == HIST_CHUNK * 128 * 2
+    # both children at the smoke geometry fit; a 1000-feature frontier
+    # does not
+    assert tiled((2, 28, 256, 9), jnp.float32) \
+        <= pallas_hist.FRONTIER_VMEM_BYTES \
+        < tiled((2, 1000, 256, 9), jnp.float32)
     rng = np.random.RandomState(5)
     f, n, b = 3, HIST_CHUNK, 16
     bins = jnp.asarray(rng.randint(0, b, size=(f, n), dtype=np.uint8))
