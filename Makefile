@@ -76,7 +76,7 @@ verify-serve:
 # observability suite: span tracer nesting/isolation, registry
 # thread-safety, journal atomicity across hard kills, multi-rank merge,
 # /trainz + /metricz (JSON and Prometheus exposition), compile ledger,
-# roofline table, trace export, comm-latency attribution + fleet
+# device scopes, trace export, comm-latency attribution + fleet
 # aggregator + run-history sentinel (tests/test_comm_obs.py) — then
 # the journal-schema lint + trace-export roundtrip on a freshly
 # generated journal (check_journal.py --demo trains a tiny run with

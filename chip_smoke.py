@@ -186,7 +186,7 @@ def stage_train(x, y, on_tpu, clock):
     jax.block_until_ready(booster.gbdt.get_training_score())
     wall, comp, run = clock.split(start)
     learner = booster.gbdt.tree_learner
-    fused = any(e["label"] == f"fused_scan_{ITERATIONS}it"
+    fused = any(e["label"] == f"fused_scan_{ITERATIONS}it:compile"
                 for e in LEDGER.snapshot(recent_n=256)["recent"])
     print(f"  learner: {type(learner).__name__}, partitioned: "
           f"{learner._use_partitioned}, pallas: {use_pallas()}, "

@@ -288,17 +288,10 @@ class Config:
     telemetry_dir: str = ""
     # >0 serves the live GET /trainz endpoint on 127.0.0.1:<port>
     telemetry_port: int = 0
-    # wrap tracer spans in jax.profiler.TraceAnnotation so host-side
-    # phases line up with XLA device traces (`profile=1` workflow)
-    telemetry_jax_annotations: bool = False
     # dump the tracer's recent-span ring into the journal at close (a
     # `spans` record) so `tools/export_trace.py` renders fine-grained
     # per-thread slices next to the journal timeline
     telemetry_trace: bool = False
-    # warn at end of run for histogram kernels whose live achieved
-    # bytes/s (telemetry/roofline.py) fall below this fraction of the
-    # measured STREAM copy peak; 0 = off
-    roofline_warn_fraction: float = 0.0
     # serving: requests slower than this emit a structured slow-request
     # log line (the `python -m lightgbm_tpu.serve --slow-request-ms`
     # flag mirrors it); 0 = off
@@ -630,8 +623,6 @@ class Config:
         check(self.max_restarts >= 0, "max_restarts should be >= 0")
         check(self.telemetry_port >= 0, "telemetry_port should be >= 0")
         check(self.aggregate_port >= 0, "aggregate_port should be >= 0")
-        check(0.0 <= self.roofline_warn_fraction <= 1.0,
-              "roofline_warn_fraction in [0, 1]")
         check(self.slow_request_ms >= 0,
               "slow_request_ms should be >= 0")
         check(self.deadline_default_ms >= 0,

@@ -26,10 +26,12 @@ token) is an .npz with the same role: skip text parsing + binning on
 reload; auto-detected next to the data file.
 """
 
+import functools
 import os
 
 import numpy as np
 
+from ..telemetry.trace import PROCESS_TRACER
 from ..utils.log import Log
 from ..utils.random import Random
 from .bin_mapper import BinMapper, NUMERICAL, CATEGORICAL
@@ -171,7 +173,12 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
     inputs are ineligible or the gate is off (the caller then bins on
     the host). Those are decisions; a failure of the device pass itself
     propagates — a host result in its place would hide a broken
-    device path."""
+    device path.
+
+    Spans `dataset/host_prep`, `upload`, `bin_device`, `download`,
+    `pack` on the process tracer tell the phases apart; the
+    `block_until_ready` between upload and compute only makes visible
+    an order that was serial already."""
     mode = os.environ.get("LIGHTGBM_TPU_DEVICE_BIN", "auto")
     if mode == "0":
         return None
@@ -189,33 +196,37 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
         return None
     n = mat.shape[0]
     f = len(real_idx)
-    b_max = max(len(m.bin_upper_bound) for m in mappers)
-    bounds = np.full((f, b_max), np.inf)
-    for u, m in enumerate(mappers):
-        bounds[u, :len(m.bin_upper_bound)] = m.bin_upper_bound
-    b32 = bounds.astype(np.float32)
-    lifted = b32.astype(np.float64) > bounds
-    b32 = np.where(lifted,
-                   np.nextafter(b32, np.float32(-np.inf),
-                                dtype=np.float32), b32)
-    # (+inf pad bounds contribute 0 to the strict-compare count)
-    chunk = 1 << 16
-    n_pad = -(-n // chunk) * chunk
-    all_cols = (f == mat.shape[1]
-                and np.array_equal(real_idx, np.arange(f)))
-    if n_pad == n and all_cols and mat.flags.c_contiguous:
-        x_used = mat            # zero-copy fast path
-    else:
-        # ONE full-size buffer: pad rows + column-select in place
-        x_used = np.zeros((n_pad, f), np.float32)
-        x_used[:n] = mat if all_cols else mat[:, real_idx]
-    # host rule bins NaN like the value 0.0 (bin.h NaN->zero-bin);
-    # on device NaN compares false everywhere -> raw bin 0, which
-    # differs when a column has negative bounds
-    if np.isnan(x_used).any():
-        x_used = np.nan_to_num(x_used, nan=0.0)
-    xdev = jnp.asarray(x_used).reshape(n_pad // chunk, chunk, f)
-    bdev = jnp.asarray(b32)
+    span = functools.partial(PROCESS_TRACER.span, rows=n, features=f)
+    with span("host_prep"):
+        b_max = max(len(m.bin_upper_bound) for m in mappers)
+        bounds = np.full((f, b_max), np.inf)
+        for u, m in enumerate(mappers):
+            bounds[u, :len(m.bin_upper_bound)] = m.bin_upper_bound
+        b32 = bounds.astype(np.float32)
+        lifted = b32.astype(np.float64) > bounds
+        b32 = np.where(lifted,
+                       np.nextafter(b32, np.float32(-np.inf),
+                                    dtype=np.float32), b32)
+        # (+inf pad bounds contribute 0 to the strict-compare count)
+        chunk = 1 << 16
+        n_pad = -(-n // chunk) * chunk
+        all_cols = (f == mat.shape[1]
+                    and np.array_equal(real_idx, np.arange(f)))
+        if n_pad == n and all_cols and mat.flags.c_contiguous:
+            x_used = mat            # zero-copy fast path
+        else:
+            # ONE full-size buffer: pad rows + column-select in place
+            x_used = np.zeros((n_pad, f), np.float32)
+            x_used[:n] = mat if all_cols else mat[:, real_idx]
+        # host rule bins NaN like the value 0.0 (bin.h NaN->zero-bin);
+        # on device NaN compares false everywhere -> raw bin 0, which
+        # differs when a column has negative bounds
+        if np.isnan(x_used).any():
+            x_used = np.nan_to_num(x_used, nan=0.0)
+    with span("upload"):
+        xdev = jnp.asarray(x_used).reshape(n_pad // chunk, chunk, f)
+        bdev = jnp.asarray(b32)
+        jax.block_until_ready((xdev, bdev))
     out_dt = jnp.dtype(dtype)
 
     @jax.jit
@@ -225,9 +236,13 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
                            axis=-1, dtype=jnp.int32).astype(out_dt)
         return jax.lax.map(one, xc)
 
-    # narrow on device: the download is N x F bytes, not 4x that
-    out = np.asarray(bin_all(xdev)).reshape(n_pad, f)[:n]
-    return np.ascontiguousarray(out.T).astype(dtype, copy=False)
+    with span("bin_device"):     # first call: compile or load, then run
+        binned = jax.block_until_ready(bin_all(xdev))
+    with span("download"):
+        # narrow on device: the download is N x F bytes, not 4x that
+        out = np.asarray(binned).reshape(n_pad, f)[:n]
+    with span("pack"):
+        return np.ascontiguousarray(out.T).astype(dtype, copy=False)
 
 
 def _bin_columns_threaded(col_fn, count):
@@ -1278,10 +1293,21 @@ class DatasetLoader:
         .n / .num_total / .col(j) (sparse FFI inputs bin one column at a
         time and never materialize the dense raw matrix, the TPU-side
         analog of c_api.cpp:317-427's row-iterator construction)."""
-        cfg = self.config
         src = feats if is_column_source(feats) else DenseColumns(feats)
+        # a dataset precedes any Booster: its spans go to the process
+        # tracer (telemetry/trace.py), under `dataset/...`
+        with PROCESS_TRACER.span("dataset", rows=src.n,
+                                 features=src.num_total):
+            return self._construct_spanned(src, names, ignore, categorical,
+                                           meta)
+
+    def _construct_spanned(self, src, names, ignore, categorical, meta):
+        cfg = self.config
         n, num_total = src.n, src.num_total
-        sample_idx = self._sample_rows(n)
+        span = functools.partial(PROCESS_TRACER.span, rows=n,
+                                 features=num_total)
+        with span("sample"):
+            sample_idx = self._sample_rows(n)
 
         def sample_col(j):
             return src.col(j)[sample_idx]
@@ -1291,8 +1317,9 @@ class DatasetLoader:
         ds.feature_names = (list(names) if names is not None
                             else [f"Column_{i}" for i in range(num_total)])
 
-        mappers, used_map, real_idx = self._make_mappers(
-            sample_col, num_total, ignore, categorical)
+        with span("bin_bounds"):
+            mappers, used_map, real_idx = self._make_mappers(
+                sample_col, num_total, ignore, categorical)
 
         # exclusive feature bundling: sparse columns share dense slots
         # (io/bundling.py; replaces the reference's sparse_bin storage)

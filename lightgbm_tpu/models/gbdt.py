@@ -27,7 +27,7 @@ from ..parallel import heartbeat
 from ..telemetry import disttrace
 from ..telemetry import journal as run_journal
 from ..telemetry.registry import MetricsRegistry
-from ..telemetry.trace import SpanTracer
+from ..telemetry.trace import SpanTracer, scope
 from ..utils import common, faults, guardrails
 from ..utils.log import Log
 from .score_updater import ScoreUpdater
@@ -461,20 +461,15 @@ class GBDT:
 
     # ------------------------------------------------------------- telemetry
     def _setup_telemetry(self, config):
-        """Wire the `telemetry_*` knobs (docs/Observability.md): span ->
-        jax.profiler annotation passthrough, the structured run journal
-        (rank-suffixed JSONL in `telemetry_dir`), the collective
-        sync-wait timing sink, and the opt-in /trainz endpoint.
-        Idempotent per booster — a reset_parameter() config rebuild must
-        not open a second journal."""
-        self.tracer.jax_annotations = bool(
-            getattr(config, "telemetry_jax_annotations", False))
-        # performance-introspection knobs (read again at close_telemetry;
-        # stored so a reset_parameter() rebuild keeps the latest values)
+        """Wire the `telemetry_*` knobs (docs/Observability.md): the
+        structured run journal (rank-suffixed JSONL in `telemetry_dir`),
+        the collective sync-wait timing sink, and the opt-in /trainz
+        endpoint. Idempotent per booster — a reset_parameter() config
+        rebuild must not open a second journal."""
+        # read again at close_telemetry; stored so a reset_parameter()
+        # rebuild keeps the latest value
         self._telemetry_trace = bool(getattr(config, "telemetry_trace",
                                              False))
-        self._roofline_warn_fraction = float(
-            getattr(config, "roofline_warn_fraction", 0.0) or 0.0)
         # quality telemetry works with or without the journal: the
         # split-ledger tracker always feeds the registry gauges
         # (/trainz + Prometheus); `quality` journal records need
@@ -599,7 +594,6 @@ class GBDT:
                     tracer=self.tracer,
                     registry=self.metrics,
                     journal=self.journal,
-                    roofline_warn_fraction=self._roofline_warn_fraction,
                     quality_fn=(quality_fn if self.quality is not None
                                 else None),
                     comm_fn=(comm_fn if self.comm_profile is not None
@@ -686,7 +680,7 @@ class GBDT:
 
     def finalize_introspection(self):
         """Final introspection drain: last memory/compile records, the
-        `telemetry_trace` span-ring dump, the roofline warning. The CLI
+        `telemetry_trace` span-ring dump. The CLI
         calls it BEFORE writing `run_end` so that record stays the
         timeline's last event; close_telemetry runs it as a fallback
         for the Python-API path (engine/bench write no run_end).
@@ -703,7 +697,6 @@ class GBDT:
             self.journal.event("spans",
                                epoch_ts=self.tracer.epoch_wall,
                                spans=self.tracer.recent(n=None))
-        self._warn_roofline()
 
     def close_telemetry(self, merge=False):
         """End-of-run hook: drain the introspection layer (see
@@ -745,25 +738,6 @@ class GBDT:
                 and heartbeat._BEAT_EXTRA is self._beat_extra_fn):
             heartbeat.bind_beat_extra(None)
         self._beat_extra_fn = None
-
-    def _warn_roofline(self):
-        """End-of-run roofline check (`roofline_warn_fraction` knob):
-        name every histogram kernel whose live achieved bytes/s fell
-        below the configured fraction of the measured STREAM peak."""
-        frac = getattr(self, "_roofline_warn_fraction", 0.0)
-        if frac <= 0:
-            return
-        from ..telemetry import roofline
-        snap = roofline.TABLE.snapshot(warn_fraction=frac)
-        for name, k in (snap.get("kernels") or {}).items():
-            if k.get("below_peak_fraction"):
-                Log.warning(
-                    "roofline: kernel [%s] achieved %.2f GB/s = %.1f%% "
-                    "of the %.2f GB/s STREAM peak (< %.0f%% warn "
-                    "fraction; %d calls, %.3fs)", name,
-                    k["bytes_per_s"] / 1e9, k.get("pct_of_peak", 0.0),
-                    snap["peak_bytes_per_s"] / 1e9, 100.0 * frac,
-                    k["calls"], k["seconds"])
 
     # --------------------------------------------------------------- bagging
     def _bagging_device_fn(self):
@@ -1093,20 +1067,25 @@ class GBDT:
                 fmask, it = xs  # fmask: (K, F) — one mask PER CLASS
                 # TREE, matching the sequential path's per-tree feature
                 # sampling (serial_tree_learner.cpp:160-165)
-                if grad_pure is not None:
-                    g, h = grad_pure(d["gops"], score)
-                else:
-                    g, h = grad_fn(score)
-                gp = jnp.pad(g, ((0, 0), (0, pad)))
-                hp = jnp.pad(h, ((0, 0), (0, pad)))
-                # per-iteration in-bag weights (GOSS); pad rows stay zero
-                ib = (inbag if inbag_fn is None
-                      else inbag_fn(it, gp, hp) * inbag)
+                # device scopes (telemetry/trace.py DEVICE_SCOPES): the
+                # builder writes its own between these two
+                with scope("gradients"):
+                    if grad_pure is not None:
+                        g, h = grad_pure(d["gops"], score)
+                    else:
+                        g, h = grad_fn(score)
+                    gp = jnp.pad(g, ((0, 0), (0, pad)))
+                    hp = jnp.pad(h, ((0, 0), (0, pad)))
+                    # per-iteration in-bag weights (GOSS); pad rows stay
+                    # zero
+                    ib = (inbag if inbag_fn is None
+                          else inbag_fn(it, gp, hp) * inbag)
                 if num_class == 1:
                     out = core(bins, gp[0], hp[0], ib, fmask[0], nbpf,
                                iscat)
-                    upd = jnp.take(out["leaf_value"],
-                                   out["row_leaf"][:n])[None, :]
+                    with scope("score_update"):
+                        upd = jnp.take(out["leaf_value"],
+                                       out["row_leaf"][:n])[None, :]
                 elif not use_switch_core:
                     # one device program for ALL classes: vmap the
                     # whole-tree builder over the class axis (SURVEY M2;
@@ -1115,9 +1094,10 @@ class GBDT:
                     out = jax.vmap(
                         lambda gg, hh, fm: core(bins, gg, hh, ib, fm,
                                                 nbpf, iscat))(gp, hp, fmask)
-                    upd = jax.vmap(
-                        lambda lv, rl: jnp.take(lv, rl[:n]))(
-                            out["leaf_value"], out["row_leaf"])
+                    with scope("score_update"):
+                        upd = jax.vmap(
+                            lambda lv, rl: jnp.take(lv, rl[:n]))(
+                                out["leaf_value"], out["row_leaf"])
                 else:
                     # partitioned/compacted builder: scan the class axis
                     # instead of vmap — vmapping the bucketed lax.switch
@@ -1128,12 +1108,15 @@ class GBDT:
                     def class_step(_, gh):
                         gg, hh, fm = gh
                         o = core(bins, gg, hh, ib, fm, nbpf, iscat)
-                        u = jnp.take(o["leaf_value"], o["row_leaf"][:n])
+                        with scope("score_update"):
+                            u = jnp.take(o["leaf_value"],
+                                         o["row_leaf"][:n])
                         return None, (o, u)
 
                     _, (out, upd) = jax.lax.scan(class_step, None,
                                                  (gp, hp, fmask))
-                score = score + upd * shrink
+                with scope("score_update"):
+                    score = score + upd * shrink
                 del out["row_leaf"]  # keep the ys O(iter * num_leaves)
                 return score, out
 
@@ -1146,7 +1129,10 @@ class GBDT:
         from ..telemetry.ledger import LEDGER
         hits_before = compile_cache_hits()
         # the compile ledger attributes this lowering to its shape
-        # bucket — the fused scan length is what keys recompiles.
+        # bucket — the fused scan length is what keys recompiles — in
+        # two labels whose wall seconds it keeps: `:lower` (trace +
+        # lower, which no cache serves) and `:compile` (backend compile,
+        # or the load from the persistent cache).
         # 1-core/1-device runners deadlock embedded host callbacks
         # (ops/histogram.py host_callbacks_hazardous; our entry points
         # clear the hazard by forcing a second virtual device, see
@@ -1156,9 +1142,11 @@ class GBDT:
         guard = (hist_ops.callbacks_disabled
                  if hist_ops.host_callbacks_hazardous()
                  else contextlib.nullcontext)
-        with LEDGER.label(f"fused_scan_{num_iters}it"), guard():
-            compiled = jax.jit(fused).lower(score, fmasks, iters,
-                                            data).compile()
+        bucket = f"fused_scan_{num_iters}it"
+        with LEDGER.label(bucket + ":lower"), guard():
+            lowered = jax.jit(fused).lower(score, fmasks, iters, data)
+        with LEDGER.label(bucket + ":compile"):
+            compiled = lowered.compile()
         # whether the persistent compile cache served this lowering —
         # surfaced by bench.py as phases.compile_cache_hit
         self.last_compile_cache_hit = compile_cache_hits() > hits_before
@@ -1199,52 +1187,71 @@ class GBDT:
         heartbeat.WATCHDOG.set_iteration(self.iter)
         fn = self._get_fused_fn(num_iters)
         learner = self.tree_learner
-        # same RNG stream and consumption order as the sequential path:
-        # one mask per (iteration, class) tree
-        fmasks = jnp.asarray(np.stack(
-            [[learner._sample_features() for _ in range(self.num_class)]
-             for _ in range(num_iters)]))
-        iters = jnp.arange(self.iter, self.iter + num_iters, dtype=jnp.int32)
+        tags = {"iterations": num_iters, "first_iter": self.iter}
+        span = self.tracer.span
         # the whole block is one device program; its host-side waits
         # (score pull, stacked-tree transfer) are THE block-boundary
-        # sync points the collective watchdog brackets
-        with self.tracer.phase("fused_block", iterations=num_iters), \
-                heartbeat.collective_guard("fused_block"):
-            final_score, stacked = fn(self.train_score_updater.score,
-                                      fmasks, iters)
-            self.train_score_updater.score = final_score
-            policy = getattr(self.config, "nonfinite_guard", "raise")
-            if policy != "off":
-                # in-graph iterations cannot be guarded individually;
-                # the block boundary is where divergence becomes
-                # detectable
-                guardrails.guard_scores(np.asarray(final_score),
-                                        self.iter + num_iters, policy)
-            host = jax.device_get(stacked)  # ONE transfer for the block
-        nsp = np.asarray(host["n_splits"]).reshape(num_iters, -1)  # (T, K)
-        empty = (nsp == 0).any(axis=1)
-        t_eff = int(np.argmax(empty)) if bool(empty.any()) else num_iters
-        # classes BEFORE the first empty one in the stopping iteration are
-        # kept, matching the sequential path (gbdt.cpp:222-236 push_back
-        # each class tree until the empty one)
-        k_stop = (int(np.argmax(nsp[t_eff] == 0))
-                  if t_eff < num_iters else 0)
+        # sync points the collective watchdog brackets. The child spans
+        # name what the host does around that program (docs/
+        # Observability.md): everything but `wait` is time the device
+        # may sit idle for
+        with span("fused_block", **tags):
+            with span("masks", **tags):
+                # same RNG stream and consumption order as the
+                # sequential path: one mask per (iteration, class) tree
+                fmasks = jnp.asarray(np.stack(
+                    [[learner._sample_features()
+                      for _ in range(self.num_class)]
+                     for _ in range(num_iters)]))
+                iters = jnp.arange(self.iter, self.iter + num_iters,
+                                   dtype=jnp.int32)
+            with heartbeat.collective_guard("fused_block"):
+                with span("launch", **tags):
+                    final_score, stacked = fn(
+                        self.train_score_updater.score, fmasks, iters)
+                    self.train_score_updater.score = final_score
+                with span("wait", **tags):
+                    # the guard's host read below would block here anyway
+                    jax.block_until_ready(final_score)
+                policy = getattr(self.config, "nonfinite_guard", "raise")
+                if policy != "off":
+                    # in-graph iterations cannot be guarded individually;
+                    # the block boundary is where divergence becomes
+                    # detectable
+                    guardrails.guard_scores(np.asarray(final_score),
+                                            self.iter + num_iters, policy)
+                with span("tree_fetch", **tags):
+                    # ONE transfer for the block
+                    host = jax.device_get(stacked)
+            nsp = np.asarray(host["n_splits"]).reshape(num_iters, -1)
+            empty = (nsp == 0).any(axis=1)       # nsp: (T, K)
+            t_eff = (int(np.argmax(empty)) if bool(empty.any())
+                     else num_iters)
+            # classes BEFORE the first empty one in the stopping
+            # iteration are kept, matching the sequential path
+            # (gbdt.cpp:222-236 push_back each class tree until the
+            # empty one)
+            k_stop = (int(np.argmax(nsp[t_eff] == 0))
+                      if t_eff < num_iters else 0)
 
-        def slice_at(t, k):
-            if self.num_class == 1:
-                return {key: v[t] for key, v in host.items()}
-            return {key: v[t, k] for key, v in host.items()}
+            def slice_at(t, k):
+                if self.num_class == 1:
+                    return {key: v[t] for key, v in host.items()}
+                return {key: v[t, k] for key, v in host.items()}
 
-        n_before = len(self.models)
-        for t in range(t_eff):
-            for k in range(self.num_class):
-                self.models.append(learner.host_out_to_tree(
-                    slice_at(t, k), shrink=self.shrinkage_rate))
-        if t_eff < num_iters:
-            for k in range(k_stop):
-                self.models.append(learner.host_out_to_tree(
-                    slice_at(t_eff, k), shrink=self.shrinkage_rate))
+            n_before = len(self.models)
+            with span("materialize", **tags):
+                for t in range(t_eff):
+                    for k in range(self.num_class):
+                        self.models.append(learner.host_out_to_tree(
+                            slice_at(t, k), shrink=self.shrinkage_rate))
+                if t_eff < num_iters:
+                    for k in range(k_stop):
+                        self.models.append(learner.host_out_to_tree(
+                            slice_at(t_eff, k),
+                            shrink=self.shrinkage_rate))
         self.iter += t_eff
+        self.metrics.inc("fused_blocks")
         self.metrics.inc("tree_build_dispatches",
                          len(self.models) - n_before)
         self.metrics.inc("transfer_bytes",
@@ -1302,8 +1309,10 @@ class GBDT:
             # n_before is a multiple of num_class (partial-class appends
             # only happen when training ends), so the slice is class-major
             new_trees = self.models[n_before:]
-            for updater in self.valid_score_updaters:
-                updater.add_score_by_trees(new_trees, self.num_class)
+            with self.tracer.span("valid_update", iterations=t_eff,
+                                  first_iter=self.iter - t_eff):
+                for updater in self.valid_score_updaters:
+                    updater.add_score_by_trees(new_trees, self.num_class)
         if t_eff < num_iters:
             Log.info("Stopped training because there are no more leafs "
                      "that meet the split requirements.")

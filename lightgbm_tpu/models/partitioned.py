@@ -50,6 +50,7 @@ from ..ops.pallas_hist import HIST_CHUNK
 from ..ops.partition import (apply_partition, invert_permutation,
                              split_destinations)
 from ..ops.split import SplitParams, find_best_split, K_MIN_SCORE
+from ..telemetry.trace import scope
 from .tree_learner import apply_tree_split, init_split_state, write_candidate
 
 
@@ -70,37 +71,49 @@ def _partition_segment(words, ghc, perm, seg_b, seg_c, feat, thr, cat,
 
     Returns (words, ghc, perm, n_left) with n_left counting ALL left
     rows of the segment (in-bag + out-of-bag + padding).
+
+    Everything here sits under the device scope `partition`, its steps
+    under the sub-scopes of telemetry/trace.py DEVICE_SUBSCOPES.
     """
     w, n = words.shape
     n_chunks = n // HIST_CHUNK
-    idx, c_first = cover_index(seg_b, seg_c, n_chunks)
 
-    def make_branch(bk):
+    def make_branch(bk, c_first):
         length = bk * HIST_CHUNK
 
         def branch(seg_b, seg_c):
-            start = window_start(c_first, bk, n_chunks)
-            w_sl = jax.lax.dynamic_slice(words, (jnp.int32(0), start),
-                                         (w, length))
-            g_sl = jax.lax.dynamic_slice(ghc, (jnp.int32(0), start),
-                                         (3, length))
-            p_sl = jax.lax.dynamic_slice(perm, (start,), (length,))
-            col = decode_fn(w_sl, feat)
-            go_left = jnp.where(cat, col == thr, col <= thr)
-            dest, n_left = split_destinations(go_left, seg_b - start, seg_c)
-            src = invert_permutation(dest)
-            w_new, g_new, p_new = apply_partition(src, w_sl, g_sl, p_sl)
-            return (jax.lax.dynamic_update_slice(
-                        words, w_new, (jnp.int32(0), start)),
-                    jax.lax.dynamic_update_slice(
-                        ghc, g_new, (jnp.int32(0), start)),
-                    jax.lax.dynamic_update_slice(perm, p_new, (start,)),
-                    n_left)
+            with scope("window_in"):
+                start = window_start(c_first, bk, n_chunks)
+                w_sl = jax.lax.dynamic_slice(words, (jnp.int32(0), start),
+                                             (w, length))
+                g_sl = jax.lax.dynamic_slice(ghc, (jnp.int32(0), start),
+                                             (3, length))
+                p_sl = jax.lax.dynamic_slice(perm, (start,), (length,))
+            with scope("decide"):
+                col = decode_fn(w_sl, feat)
+                go_left = jnp.where(cat, col == thr, col <= thr)
+            with scope("destinations"):
+                dest, n_left = split_destinations(go_left, seg_b - start,
+                                                  seg_c)
+            with scope("invert"):
+                src = invert_permutation(dest)
+            with scope("move"):
+                w_new, g_new, p_new = apply_partition(src, w_sl, g_sl, p_sl)
+            with scope("write_back"):
+                return (jax.lax.dynamic_update_slice(
+                            words, w_new, (jnp.int32(0), start)),
+                        jax.lax.dynamic_update_slice(
+                            ghc, g_new, (jnp.int32(0), start)),
+                        jax.lax.dynamic_update_slice(perm, p_new, (start,)),
+                        n_left)
 
         return branch
 
-    return jax.lax.switch(idx, [make_branch(b) for b in bucket_sizes(n_chunks)],
-                          seg_b, seg_c)
+    with scope("partition"):
+        idx, c_first = cover_index(seg_b, seg_c, n_chunks)
+        return jax.lax.switch(
+            idx, [make_branch(b, c_first) for b in bucket_sizes(n_chunks)],
+            seg_b, seg_c)
 
 
 def _identity(x):
@@ -157,6 +170,11 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
 
     Returns the same output dict as build_tree_device (tree arrays +
     original-order row->leaf partition, local rows under shard_map).
+
+    Every operation traced here carries one of the device scopes of
+    telemetry/trace.py DEVICE_SCOPES in its op_name (`jax.named_scope`:
+    HLO metadata, nothing at run time), so a profiler trace can be read
+    by phase (benchmarks/scopereduce.py, docs/Observability.md).
     """
     w, n_pad = words.shape
     l = num_leaves
@@ -176,83 +194,105 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
                                    params)
 
     def scan_leaf(hist3, sum_g, sum_h, cnt):
-        return evaluate_fn(expand_fn(hist3), sum_g, sum_h, cnt)
+        with scope("split_scan"):
+            return evaluate_fn(expand_fn(hist3), sum_g, sum_h, cnt)
 
-    g_in = grad * inbag
-    h_in = hess * inbag
-    ghc0 = jnp.stack([g_in, h_in, inbag], axis=0)  # (3, N_pad)
+    with scope("gradients"):
+        g_in = grad * inbag
+        h_in = hess * inbag
+        ghc0 = jnp.stack([g_in, h_in, inbag], axis=0)  # (3, N_pad)
 
     def leaf_histogram(words_c, ghc_c, begin, cnt):
-        return hist_reduce_fn(
-            segment_histograms(words_c, ghc_c, begin, cnt, b, s_pad))
+        with scope("hist"):
+            hist = segment_histograms(words_c, ghc_c, begin, cnt, b, s_pad)
+        with scope("hist_reduce"):
+            return hist_reduce_fn(hist)
 
     # ---- root ----------------------------------------------------------
     hist_root = leaf_histogram(words, ghc0, jnp.int32(0), jnp.int32(n_pad))
     # root sums from the histogram: feature 0's bins partition the rows
-    root_g = sum_psum_fn(jnp.sum(hist_root[0, :, 0]))
-    root_h = sum_psum_fn(jnp.sum(hist_root[0, :, 1]))
-    root_c = sum_psum_fn(jnp.sum(hist_root[0, :, 2]))
+    with scope("split_scan"):
+        local_sums = [jnp.sum(hist_root[0, :, k]) for k in range(3)]
+    with scope("hist_reduce"):
+        root_g, root_h, root_c = (sum_psum_fn(s) for s in local_sums)
     root_split = scan_leaf(hist_root, root_g, root_h, root_c)
 
-    state = init_split_state(l, root_split, root_c)
-    state["words"] = words
-    state["ghc"] = ghc0
-    state["perm"] = jnp.arange(n_pad, dtype=jnp.int32)  # position -> orig row
-    state["pos_leaf"] = jnp.zeros(n_pad, dtype=jnp.int32)
-    state["seg_begin"] = jnp.zeros(l, dtype=jnp.int32)
-    # FULL row counts (in-bag + oob + pad), not the tree's in-bag counts
-    state["seg_cnt"] = jnp.zeros(l, dtype=jnp.int32).at[0].set(n_pad)
-    if cache_hists:
-        state["hist_cache"] = (jnp.zeros((l, s_pad, b, 3), dtype=f32)
-                               .at[0].set(hist_root))
+    with scope("tree_state"):
+        state = init_split_state(l, root_split, root_c)
+        state["words"] = words
+        state["ghc"] = ghc0
+        # position -> orig row
+        state["perm"] = jnp.arange(n_pad, dtype=jnp.int32)
+        with scope("pos_leaf"):
+            state["pos_leaf"] = jnp.zeros(n_pad, dtype=jnp.int32)
+        state["seg_begin"] = jnp.zeros(l, dtype=jnp.int32)
+        # FULL row counts (in-bag + oob + pad), not the tree's in-bag
+        # counts
+        state["seg_cnt"] = jnp.zeros(l, dtype=jnp.int32).at[0].set(n_pad)
+        if cache_hists:
+            with scope("hist_cache"):
+                state["hist_cache"] = (
+                    jnp.zeros((l, s_pad, b, 3), dtype=f32)
+                    .at[0].set(hist_root))
 
     def body(i, st):
-        best_leaf = jnp.argmax(st["best_gain"]).astype(jnp.int32)
-        gain = st["best_gain"][best_leaf]
-        do = jnp.logical_and(jnp.logical_not(st["done"]), gain > 0.0)
+        with scope("tree_state"):
+            best_leaf = jnp.argmax(st["best_gain"]).astype(jnp.int32)
+            gain = st["best_gain"][best_leaf]
+            do = jnp.logical_and(jnp.logical_not(st["done"]), gain > 0.0)
 
         def no_split(st):
             st = dict(st)
-            st["done"] = jnp.asarray(True)
+            with scope("tree_state"):
+                st["done"] = jnp.asarray(True)
             return st
 
         def do_split(st):
             st = dict(st)
-            st, node, right_id, feat, thr = apply_tree_split(
-                st, i, best_leaf, gain, l)
+            with scope("tree_state"):
+                st, node, right_id, feat, thr = apply_tree_split(
+                    st, i, best_leaf, gain, l)
+                seg_b = st["seg_begin"][best_leaf]
+                seg_c = st["seg_cnt"][best_leaf]
+                cat = is_cat[feat]
 
             # ---- physical re-partition (DataPartition::Split),
             # bucketed to the segment's chunk range
-            seg_b = st["seg_begin"][best_leaf]
-            seg_c = st["seg_cnt"][best_leaf]
             st["words"], st["ghc"], st["perm"], n_left = _partition_segment(
                 st["words"], st["ghc"], st["perm"], seg_b, seg_c,
-                feat, thr, is_cat[feat], decode_fn)
-            st["seg_begin"] = st["seg_begin"].at[right_id].set(seg_b + n_left)
-            st["seg_cnt"] = (st["seg_cnt"].at[best_leaf].set(n_left)
-                             .at[right_id].set(seg_c - n_left))
-            pos = jnp.arange(n_pad, dtype=jnp.int32)
-            st["pos_leaf"] = jnp.where(
-                (pos >= seg_b + n_left) & (pos < seg_b + seg_c),
-                right_id, st["pos_leaf"])
+                feat, thr, cat, decode_fn)
+            with scope("tree_state"):
+                st["seg_begin"] = (st["seg_begin"]
+                                   .at[right_id].set(seg_b + n_left))
+                st["seg_cnt"] = (st["seg_cnt"].at[best_leaf].set(n_left)
+                                 .at[right_id].set(seg_c - n_left))
+                with scope("pos_leaf"):
+                    pos = jnp.arange(n_pad, dtype=jnp.int32)
+                    st["pos_leaf"] = jnp.where(
+                        (pos >= seg_b + n_left) & (pos < seg_b + seg_c),
+                        right_id, st["pos_leaf"])
 
             if cache_hists:
                 # ---- smaller-child histogram + parent subtraction
                 # smaller side by GLOBAL in-bag count, matching the
                 # masked builder (data_parallel_tree_learner.cpp:178-187)
-                left_is_small = (st["best_lc"][best_leaf]
-                                 <= st["best_rc"][best_leaf])
-                small_b = jnp.where(left_is_small, seg_b, seg_b + n_left)
-                small_c = jnp.where(left_is_small, n_left, seg_c - n_left)
+                with scope("tree_state"):
+                    left_is_small = (st["best_lc"][best_leaf]
+                                     <= st["best_rc"][best_leaf])
+                    small_b = jnp.where(left_is_small, seg_b, seg_b + n_left)
+                    small_c = jnp.where(left_is_small, n_left,
+                                        seg_c - n_left)
                 hist_small = leaf_histogram(st["words"], st["ghc"],
                                             small_b, small_c)
-                hist_large = st["hist_cache"][best_leaf] - hist_small
-                hist_left = jnp.where(left_is_small, hist_small, hist_large)
-                hist_right = jnp.where(left_is_small, hist_large,
-                                       hist_small)
-                st["hist_cache"] = (st["hist_cache"]
-                                    .at[best_leaf].set(hist_left)
-                                    .at[right_id].set(hist_right))
+                with scope("tree_state"), scope("hist_cache"):
+                    hist_large = st["hist_cache"][best_leaf] - hist_small
+                    hist_left = jnp.where(left_is_small, hist_small,
+                                          hist_large)
+                    hist_right = jnp.where(left_is_small, hist_large,
+                                           hist_small)
+                    st["hist_cache"] = (st["hist_cache"]
+                                        .at[best_leaf].set(hist_left)
+                                        .at[right_id].set(hist_right))
             else:
                 # memory-bounded mode: both children's segments scanned
                 hist_left = leaf_histogram(st["words"], st["ghc"],
@@ -262,30 +302,37 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
                                             seg_c - n_left)
 
             # ---- children leaf state (LeafSplits::Init after split)
-            child_depth = st["leaf_depth"][best_leaf] + 1
-            st["leaf_depth"] = (st["leaf_depth"].at[best_leaf].set(child_depth)
-                                .at[right_id].set(child_depth))
+            with scope("tree_state"):
+                child_depth = st["leaf_depth"][best_leaf] + 1
+                st["leaf_depth"] = (st["leaf_depth"]
+                                    .at[best_leaf].set(child_depth)
+                                    .at[right_id].set(child_depth))
+                lsums = [st[k][best_leaf]
+                         for k in ("best_lg", "best_lh", "best_lc")]
+                rsums = [st[k][best_leaf]
+                         for k in ("best_rg", "best_rh", "best_rc")]
 
-            lsplit = scan_leaf(hist_left, st["best_lg"][best_leaf],
-                               st["best_lh"][best_leaf], st["best_lc"][best_leaf])
-            rsplit = scan_leaf(hist_right, st["best_rg"][best_leaf],
-                               st["best_rh"][best_leaf], st["best_rc"][best_leaf])
+            lsplit = scan_leaf(hist_left, *lsums)
+            rsplit = scan_leaf(hist_right, *rsums)
 
-            # max_depth guard (serial_tree_learner.cpp:238-247)
-            depth_ok = jnp.logical_or(max_depth < 0, child_depth < max_depth)
-            lgain = jnp.where(depth_ok, lsplit.gain, K_MIN_SCORE)
-            rgain = jnp.where(depth_ok, rsplit.gain, K_MIN_SCORE)
+            with scope("tree_state"):
+                # max_depth guard (serial_tree_learner.cpp:238-247)
+                depth_ok = jnp.logical_or(max_depth < 0,
+                                          child_depth < max_depth)
+                lgain = jnp.where(depth_ok, lsplit.gain, K_MIN_SCORE)
+                rgain = jnp.where(depth_ok, rsplit.gain, K_MIN_SCORE)
 
-            st = write_candidate(st, best_leaf, lsplit, lgain)
-            st = write_candidate(st, right_id, rsplit, rgain)
+                st = write_candidate(st, best_leaf, lsplit, lgain)
+                st = write_candidate(st, right_id, rsplit, rgain)
             return st
 
         return jax.lax.cond(do, do_split, no_split, st)
 
     state = jax.lax.fori_loop(0, l - 1, body, state)
     # original-order row->leaf map: one scatter at tree end
-    row_leaf = (jnp.zeros(n_pad, dtype=jnp.int32)
-                .at[state["perm"]].set(state["pos_leaf"]))
+    with scope("score_update"):
+        row_leaf = (jnp.zeros(n_pad, dtype=jnp.int32)
+                    .at[state["perm"]].set(state["pos_leaf"]))
     return {
         "n_splits": state["n_splits"],
         "row_leaf": row_leaf,

@@ -67,7 +67,6 @@ full-matrix streams (docs/Histogram-Engine.md).
 import contextlib
 import os
 import threading
-import time
 
 import jax
 import jax.numpy as jnp
@@ -78,18 +77,6 @@ from .pallas_hist import HIST_CHUNK
 
 DEFAULT_ROW_CHUNK = 8192
 
-
-def _roofline_record(kernel, seconds, nbytes, rows):
-    """Live roofline attribution (telemetry/roofline.py): the bincount
-    host callbacks are the one place kernel execution is host-observable
-    (they ARE the kernel on the CPU default path), so each call records
-    its (wall seconds, bytes streamed, rows scanned) here. One O(1)
-    table update per histogram build — far below the <1% telemetry bar.
-    In-graph kernels (pallas/einsum/segment) are invisible to host
-    timers inside one XLA program; they stay covered by the bench's
-    single-op microprobes."""
-    from ..telemetry.roofline import TABLE
-    TABLE.record(kernel, seconds, nbytes, rows)
 
 _HIST_MODES = ("auto", "pallas", "einsum", "segment", "bincount")
 
@@ -360,7 +347,6 @@ def _hist_pair_bincount(bins, ghc, b, row_chunk):
     nchunks, c = _chunk_bounds(n, row_chunk)
 
     def cb(bins_h, ghc_h):
-        t_start = time.perf_counter()
         bins_h = np.asarray(bins_h)
         ghc_h = np.asarray(ghc_h, dtype=np.float64)
         base = (np.arange(f, dtype=np.int64) * b)[:, None]
@@ -376,11 +362,7 @@ def _hist_pair_bincount(bins, ghc, b, row_chunk):
                                         minlength=fb)
             return out.astype(np.float32).reshape(f, b, k)
 
-        res = _bincount_chunk_loop(nchunks, (f, b, k), one_chunk)
-        _roofline_record("bincount_masked",
-                         time.perf_counter() - t_start,
-                         bins_h.nbytes + ghc_h.nbytes, n)
-        return res
+        return _bincount_chunk_loop(nchunks, (f, b, k), one_chunk)
 
     out = jax.pure_callback(
         cb, jax.ShapeDtypeStruct((2, f, b, k), jnp.float32), bins, ghc,
@@ -480,7 +462,6 @@ def _frontier_pair_bincount(bins, ghc_t, row_leaf, leaf_ids, b, row_chunk):
     nchunks, c = _chunk_bounds(n, row_chunk)
 
     def cb(bins_h, ghc_h, rl_h, lids_h):
-        t_start = time.perf_counter()
         bins_h = np.asarray(bins_h)
         ghc_h = np.asarray(ghc_h, dtype=np.float64)
         rl_h = np.asarray(rl_h)
@@ -506,11 +487,7 @@ def _frontier_pair_bincount(bins, ghc_t, row_leaf, leaf_ids, b, row_chunk):
                                         minlength=(l + 1) * fb)
             return out[:l * fb].astype(np.float32).reshape(l, f, b, k)
 
-        res = _bincount_chunk_loop(nchunks, (l, f, b, k), one_chunk)
-        _roofline_record("bincount_frontier",
-                         time.perf_counter() - t_start,
-                         bins_h.nbytes + ghc_h.nbytes + rl_h.nbytes, n)
-        return res
+        return _bincount_chunk_loop(nchunks, (l, f, b, k), one_chunk)
 
     out = jax.pure_callback(
         cb, jax.ShapeDtypeStruct((2, l, f, b, k), jnp.float32),
@@ -528,7 +505,6 @@ def _compacted_bincount(bins, ghc_t, row_leaf, leaf_id, b, chunk):
     k = ghc_t.shape[0]
 
     def cb(bins_h, ghc_h, rl_h, lid_h):
-        t_start = time.perf_counter()
         bins_h = np.asarray(bins_h)
         ghc_h = np.asarray(ghc_h, dtype=np.float64)
         rl_h = np.asarray(rl_h)
@@ -548,15 +524,7 @@ def _compacted_bincount(bins, ghc_t, row_leaf, leaf_id, b, chunk):
                                         minlength=fb)
             return out.astype(np.float32).reshape(f, b, k)
 
-        res = _bincount_chunk_loop(nchunks, (f, b, k), one_chunk)
-        # bytes actually streamed: the full row->leaf scan plus the
-        # GATHERED bins/stats columns (cost scales with the leaf)
-        touched = (rl_h.nbytes
-                   + len(src) * (f * bins_h.itemsize + k * ghc_h.itemsize))
-        _roofline_record("bincount_compacted",
-                         time.perf_counter() - t_start,
-                         touched, len(src))
-        return res
+        return _bincount_chunk_loop(nchunks, (f, b, k), one_chunk)
 
     out = jax.pure_callback(
         cb, jax.ShapeDtypeStruct((2, f, b, k), jnp.float32),

@@ -31,6 +31,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..telemetry.trace import scope
 from .pallas_hist import (HIST_CHUNK, STAT_TERMS, fold_stats, onehot_dot,
                           split_stats)
 
@@ -151,8 +152,12 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
     w = words_sl.shape[0]
     b_pad = max(((num_bins_total + 127) // 128) * 128, 128)
     kernel = functools.partial(_seg_hist_kernel, f=f, b_pad=b_pad)
+    with scope("window"):
+        lohi = jnp.stack([lo, hi]).astype(jnp.int32)
+        stats = split_stats(ghc_sl)
     out = pl.pallas_call(
         kernel,
+        name="seg_hist",  # the kernel's name in a trace, and its scope
         interpret=interpret,
         grid=(n_blocks,),
         in_specs=[
@@ -165,8 +170,9 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
         out_specs=pl.BlockSpec((f, b_pad, STAT_TERMS), lambda i: (0, 0, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((f, b_pad, STAT_TERMS), jnp.float32),
-    )(jnp.stack([lo, hi]).astype(jnp.int32), words_sl, split_stats(ghc_sl))
-    return fold_stats(out[:, :num_bins_total, :])
+    )(lohi, words_sl, stats)
+    with scope("fold"):
+        return fold_stats(out[:, :num_bins_total, :])
 
 
 def _seg_hist_xla(words_sl, ghc_sl, lo, hi, f, num_bins_total):
@@ -198,6 +204,9 @@ def segment_histograms(words, ghc_t, begin, cnt, num_bins_total, f,
 
     Returns (F, B, 3) float32. Cost scales with the geometric chunk
     bucket covering the segment (bucket_sizes), not with N.
+
+    Sub-scopes of the device scope `hist` (telemetry/trace.py): `window`
+    (slices, transpose, stat split), the kernel `seg_hist`, `fold`.
     """
     w, n = words.shape
     if n % HIST_CHUNK != 0:
@@ -221,13 +230,14 @@ def segment_histograms(words, ghc_t, begin, cnt, num_bins_total, f,
 
     def make_branch(bk):
         def branch(begin, cnt):
-            start = window_start(c_first, bk, n_chunks)
-            words_sl = jax.lax.dynamic_slice(
-                words, (jnp.int32(0), start), (w, bk * HIST_CHUNK))
-            ghc_sl = jax.lax.dynamic_slice(
-                ghc_t, (jnp.int32(0), start), (3, bk * HIST_CHUNK)).T
-            lo = begin - start
-            hi = lo + cnt
+            with scope("window"):
+                start = window_start(c_first, bk, n_chunks)
+                words_sl = jax.lax.dynamic_slice(
+                    words, (jnp.int32(0), start), (w, bk * HIST_CHUNK))
+                ghc_sl = jax.lax.dynamic_slice(
+                    ghc_t, (jnp.int32(0), start), (3, bk * HIST_CHUNK)).T
+                lo = begin - start
+                hi = lo + cnt
             if on_tpu:
                 return _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f,
                                      num_bins_total, bk, interpret=interpret)

@@ -197,6 +197,7 @@ def masked_histograms_tpu(bins, ghc_t, row_leaf, leaf_id, num_bins_total,
     kernel = functools.partial(_hist_kernel, f=f, b_pad=b_pad)
     out = pl.pallas_call(
         kernel,
+        name="masked_hist",
         interpret=interpret,  # CPU kernel-semantics tests
         grid=grid,
         in_specs=[
@@ -245,6 +246,7 @@ def frontier_histograms_tpu(bins, ghc_t, row_leaf, leaf_ids, num_bins_total,
     kernel = functools.partial(_frontier_kernel, l=l, f=f, b_pad=b_pad)
     out = pl.pallas_call(
         kernel,
+        name="frontier_hist",
         interpret=interpret,
         grid=grid,
         in_specs=[

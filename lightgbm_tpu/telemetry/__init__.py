@@ -4,8 +4,11 @@ live /trainz endpoint.
 The training-side observability stack (docs/Observability.md):
 
 - `trace.SpanTracer` — per-Booster nested span timing (replaces the
-  global `utils/timers.py` singleton), with optional
-  `jax.profiler.TraceAnnotation` passthrough.
+  global `utils/timers.py` singleton), each span also a
+  `jax.profiler.TraceAnnotation` once the embedder has imported jax;
+  `trace.PROCESS_TRACER` for work that belongs to no Booster;
+  `trace.DEVICE_SCOPES`, the `jax.named_scope` vocabulary of the
+  device program.
 - `registry.MetricsRegistry` — thread-safe counters/gauges/histograms;
   the serving layer's `/metricz` accounting (serving/metrics.py) is
   built on the same primitives.
@@ -17,8 +20,6 @@ The training-side observability stack (docs/Observability.md):
 - `ledger.CompileLedger` / `ledger.sample_memory` — jit-lowering
   ledger (shape-bucket labels, persistent-cache hit/miss) and device/
   host memory watermarks.
-- `roofline.TABLE` — live per-kernel achieved bytes/s vs a measured
-  STREAM-style peak.
 - `prometheus.render` — the registry in Prometheus text exposition
   (`?format=prometheus` on /metricz and /trainz), with the canonical
   naming contract (`canonical_name`/`lint_names`) and the labeled
@@ -34,15 +35,15 @@ The training-side observability stack (docs/Observability.md):
 - `history.append_run_summary` — the append-only RUN_HISTORY.jsonl
   store `tools/sentinel.py` trends over.
 
-Everything here is jax-free unless the jax-annotation passthrough is
-explicitly enabled (the compile ledger's `install()` touches jax's
-monitoring API only when jax is importable), so the supervisor and CPU
-test harness can import it without touching the accelerator runtime.
+Nothing here imports jax (spans annotate, and the compile ledger's
+`install()` listens, only where the embedder already has), so the
+supervisor and CPU test harness can import it without touching the
+accelerator runtime.
 """
 
 from . import aggregate, comm_profile, export, history  # noqa: F401
 from . import journal, ledger, prometheus  # noqa: F401
-from . import registry, roofline, trace, trainz  # noqa: F401
+from . import registry, trace, trainz  # noqa: F401
 from .aggregate import FleetAggregator  # noqa: F401
 from .comm_profile import CommProfiler  # noqa: F401
 from .export import build_trace, export_trace, validate_trace  # noqa: F401
@@ -50,5 +51,5 @@ from .history import append_run_summary, read_history  # noqa: F401
 from .journal import RunJournal, merge_journals, read_journal  # noqa: F401
 from .ledger import LEDGER, CompileLedger, sample_memory  # noqa: F401
 from .registry import MetricsRegistry  # noqa: F401
-from .trace import SpanTracer  # noqa: F401
+from .trace import PROCESS_TRACER, SpanTracer  # noqa: F401
 from .trainz import start_trainz, stop_trainz  # noqa: F401

@@ -183,8 +183,9 @@ SCHEMA = {
                             "host_peak_rss_bytes": int}},
     # one jit lowering (telemetry/ledger.py CompileLedger): label names
     # the shape bucket ("fused_scan_10it", "serving_bucket_256"),
-    # seconds is backend-compile wall time (0.0 on a persistent-cache
-    # hit), cache_hit whether the persistent compile cache served it
+    # seconds is backend-compile wall time (the load's on a
+    # persistent-cache hit), cache_hit whether the persistent compile
+    # cache served it
     "compile": {"required": {"label": str},
                 "optional": {"seconds": float, "cache_hit": bool,
                              "count": int, "source": str}},

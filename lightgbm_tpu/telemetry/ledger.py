@@ -12,12 +12,21 @@ cannot answer:
   diagnosable from the timeline instead of a post-mortem.
 - **Compiles** (`CompileLedger`): every jit lowering the process pays
   for, attributed to a caller-named shape bucket. jax's monitoring
-  stream has the raw events (`/jax/core/compile/backend_compile_duration`
-  per backend compile, `/jax/compilation_cache/cache_hits|misses` for
-  the persistent cache) but no attribution; the ledger adds a
-  thread-local label stack (`with LEDGER.label("fused_scan_10it"):`)
+  stream has the raw events but no attribution; the ledger adds a
+  thread-local label stack (`with LEDGER.label("fused_scan_10it:lower"):`)
   so the fused trainer's lowerings and the serving warmup's per-bucket
-  compiles are separable line items on /trainz and /metricz.
+  compiles are separable line items on /trainz and /metricz, and keeps
+  the wall seconds each label covered (`label_seconds`), which is how
+  trace + lower — served by no cache — is told from compile-or-load.
+  On jax 0.9.0 `/jax/core/compile/backend_compile_duration` brackets
+  `compile_or_get_cached`: it fires for a persistent-cache HIT too (its
+  duration is then the load), after `/jax/compilation_cache/cache_hits`
+  and `.../cache_retrieval_time_sec` on the same thread. The ledger
+  therefore writes one entry per lowering, when the duration arrives,
+  and marks it `cache_hit` if a hit event preceded it. It does not sum
+  jax's `jaxpr_trace_duration` / `jaxpr_to_mlir_module_duration`: a
+  nested jit reports its own inside its caller's, so the sum counts
+  the inner traces twice.
 
 The module is jax-free until `CompileLedger.install()` runs (a no-op
 without jax); `sample_memory` only touches jax when the embedder
@@ -35,15 +44,17 @@ RECENT_COMPILES = 256
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 class CompileLedger:
     """Process-wide ledger of jit lowerings (see module docstring).
 
     `install()` registers the jax.monitoring listeners once;
-    `label(name)` attributes compiles on the current thread;
-    `snapshot()` is the /trainz / /metricz view; `drain()` hands new
-    entries to the journal writer exactly once each.
+    `label(name)` attributes compiles on the current thread and keeps
+    the wall seconds it covered; `snapshot()` is the /trainz / /metricz
+    view; `drain()` hands new entries to the journal writer exactly
+    once each.
     """
 
     def __init__(self, ring=RECENT_COMPILES):
@@ -55,6 +66,8 @@ class CompileLedger:
         self.total_s = 0.0
         self.cache_hits = 0
         self.cache_misses = 0
+        self.cache_load_s = 0.0
+        self.label_seconds = {}    # label -> wall seconds it covered
         self._installed = False
 
     # ----------------------------------------------------------- labels
@@ -70,7 +83,8 @@ class CompileLedger:
 
     def label(self, name):
         """Context manager attributing compiles inside it to `name`
-        (innermost label wins)."""
+        (innermost label wins) and adding the wall seconds it covered
+        to `label_seconds[name]`."""
         return _LabelContext(self, str(name))
 
     # -------------------------------------------------------- listeners
@@ -97,26 +111,31 @@ class CompileLedger:
         self._undrained.append(entry)
 
     def _on_duration(self, name, secs, **kwargs):
+        if name == _CACHE_LOAD_EVENT:
+            with self._lock:
+                self.cache_load_s += float(secs)
+            return
         if name != _COMPILE_EVENT:
             return
+        # the hit event, if any, came first on this thread (module
+        # docstring): the duration is then a load, not a compile
+        hit = bool(getattr(self._local, "hit", False))
+        self._local.hit = False
         entry = {"label": self.current_label(), "seconds": float(secs),
-                 "ts": time.time(), "cache_hit": False}
+                 "ts": time.time(), "cache_hit": hit}
         with self._lock:
-            self.compiles += 1
-            self.total_s += float(secs)
+            if not hit:
+                self.compiles += 1
+                self.total_s += float(secs)
             self._append(entry)
 
     def _on_event(self, name, **kwargs):
         if name == _CACHE_HIT_EVENT:
-            # a hit deserializes the executable instead of compiling:
-            # no backend_compile_duration fires, so the hit IS the
-            # ledger entry for that lowering
-            entry = {"label": self.current_label(), "seconds": 0.0,
-                     "ts": time.time(), "cache_hit": True}
+            self._local.hit = True
             with self._lock:
                 self.cache_hits += 1
-                self._append(entry)
         elif name == _CACHE_MISS_EVENT:
+            self._local.hit = False
             with self._lock:
                 self.cache_misses += 1
 
@@ -130,6 +149,9 @@ class CompileLedger:
                     "total_s": round(self.total_s, 6),
                     "cache_hits": self.cache_hits,
                     "cache_misses": self.cache_misses,
+                    "cache_load_s": round(self.cache_load_s, 6),
+                    "label_seconds": {k: round(v, 6) for k, v
+                                      in self.label_seconds.items()},
                     "recent": [dict(e) for e in recent]}
 
     def drain(self):
@@ -148,23 +170,32 @@ class CompileLedger:
             self.total_s = 0.0
             self.cache_hits = 0
             self.cache_misses = 0
+            self.cache_load_s = 0.0
+            self.label_seconds = {}
 
 
 class _LabelContext:
-    __slots__ = ("_ledger", "_name")
+    __slots__ = ("_ledger", "_name", "_t0")
 
     def __init__(self, ledger, name):
         self._ledger = ledger
         self._name = name
+        self._t0 = None
 
     def __enter__(self):
         self._ledger._labels().append(self._name)
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        stack = self._ledger._labels()
+        elapsed = time.perf_counter() - self._t0
+        led = self._ledger
+        stack = led._labels()
         if stack and stack[-1] == self._name:
             stack.pop()
+        with led._lock:
+            led.label_seconds[self._name] = (
+                led.label_seconds.get(self._name, 0.0) + elapsed)
         return False
 
 

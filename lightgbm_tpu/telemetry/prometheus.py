@@ -179,7 +179,7 @@ def render(snapshot, prefix="lightgbm_tpu", extra_gauges=None,
     """Registry snapshot (MetricsRegistry.snapshot(): counters/gauges/
     histograms) -> exposition text. `extra_gauges` is a flat
     {name: number} dict appended as gauges (serving warmup stats,
-    queue depth, roofline numbers...); `labels` attach to every sample
+    queue depth, memory watermarks...); `labels` attach to every sample
     (the aggregator's `rank`/`role`)."""
     return _emit(families(snapshot, prefix, extra_gauges, labels))
 

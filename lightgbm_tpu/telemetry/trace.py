@@ -13,20 +13,38 @@ Three views of the same spans:
 
 - **Accumulator** (`acc`/`cnt`/`snapshot`/`report`): per-phase total
   seconds and call counts, drop-in compatible with the old PhaseTimers
-  API so existing call sites and the bench keep working.
-- **Deltas** (`delta_snapshot`): per-phase seconds since the previous
-  call — what the run journal attaches to each iteration record.
+  API so existing call sites and the bench keep working. A top-level
+  span counts under its name, a child under its path
+  (`fused_block/wait`).
+- **Deltas** (`delta_snapshot`): per-phase seconds of the TOP-LEVEL
+  spans since the previous call — what the run journal attaches to each
+  iteration record; children are left out so a record's phases stay a
+  partition of its wall time.
 - **Recent spans** (`recent`): a bounded ring of completed spans with
   nesting path, start offset and tags — the `/trainz` endpoint's live
   breakdown.
 
 Spans nest via a thread-local stack ("train/build" style paths), are
-exception-safe (the `finally` always closes the span), and optionally
-pass through to `jax.profiler.TraceAnnotation` so host spans line up
-with XLA device traces (`telemetry_jax_annotations` knob; the import
-is lazy so this module stays jax-free unless the passthrough is on).
+exception-safe (the `finally` always closes the span), and pass through
+to `jax.profiler.TraceAnnotation` under their PATH whenever the
+embedder has already imported jax, so host spans sit on the profiler's
+clock beside the XLA device trace (`profile=1`; with no trace running
+an annotation costs well under a microsecond). The module itself never
+imports jax.
+
+Two more things live here because every reader of a trace needs them in
+one place:
+
+- `DEVICE_SCOPES` / `DEVICE_SUBSCOPES`: the `jax.named_scope` vocabulary
+  the fused step (models/gbdt.py) and the partitioned builder
+  (models/partitioned.py, ops/ordered_hist.py) write into each device
+  operation's `op_name`; `scope(word)` is how they write it.
+- `PROCESS_TRACER`: one process-level tracer for work that belongs to no
+  Booster (dataset construction precedes any, and a reader may outlive
+  all of them). Nothing is mirrored between it and a Booster's tracer.
 """
 
+import sys
 import threading
 import time
 from collections import defaultdict, deque
@@ -34,6 +52,34 @@ from collections import defaultdict, deque
 from . import disttrace
 
 RECENT_SPANS = 256
+
+# Top-level device scopes, in the order of one boosting iteration. A
+# device operation belongs to the FIRST of these words on its op_name
+# path (docs/Observability.md; benchmarks/scopereduce.py holds its own
+# copy, as a yardstick must). None is the name of a jax primitive:
+# those stand on the same path as components of their own.
+DEVICE_SCOPES = ("gradients", "partition", "hist", "hist_reduce",
+                 "split_scan", "tree_state", "score_update")
+DEVICE_SUBSCOPES = {
+    "partition": ("window_in", "decide", "destinations", "invert", "move",
+                  "write_back"),
+    "hist": ("window", "seg_hist", "fold"),
+    "tree_state": ("hist_cache", "pos_leaf"),
+}
+# names of the Pallas kernels (`pallas_call(name=...)`): the segment
+# kernel of the fused path and the two full-matrix kernels chip_smoke.py
+# runs
+KERNEL_NAMES = ("seg_hist", "masked_hist", "frontier_hist")
+_SCOPE_WORDS = frozenset(DEVICE_SCOPES).union(*DEVICE_SUBSCOPES.values())
+
+
+def scope(word):
+    """`jax.named_scope(word)` for a word of the device vocabulary —
+    HLO metadata only, nothing at run time. Called under a jax trace,
+    so jax is imported by then."""
+    if word not in _SCOPE_WORDS:
+        raise ValueError(f"{word!r} is not in the device-scope vocabulary")
+    return sys.modules["jax"].named_scope(word)
 
 
 class Span:
@@ -81,10 +127,11 @@ class _SpanContext:
         self._path = ("/".join(s for s in stack) + "/" + self._name
                       if stack else self._name)
         stack.append(self._name)
-        if tr.jax_annotations:
-            self._ann = tr._annotation(self._name)
-            if self._ann is not None:
-                self._ann.__enter__()
+        # getattr: a thread may open a span while jax is mid-import
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(self._path)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -101,17 +148,16 @@ class _SpanContext:
 
 
 class SpanTracer:
-    """Per-Booster span registry (see module docstring).
+    """Span registry, one per Booster plus `PROCESS_TRACER` (see module
+    docstring).
 
-    The accumulator keys on the LEAF name (not the path) so nested and
-    flat call sites aggregate the same way the old PhaseTimers did.
-    Thread-safe: concurrent threads keep independent nesting stacks and
+    The accumulator keys on the span's path: a top-level span's is its
+    name, as the old PhaseTimers kept it. Thread-safe: concurrent threads keep independent nesting stacks and
     the shared accumulator mutates under one lock.
     """
 
-    def __init__(self, rank=0, jax_annotations=False):
+    def __init__(self, rank=0):
         self.rank = int(rank)
-        self.jax_annotations = bool(jax_annotations)
         self.acc = defaultdict(float)
         self.cnt = defaultdict(int)
         self._lock = threading.Lock()
@@ -131,14 +177,6 @@ class SpanTracer:
             stack = self._local.stack = []
         return stack
 
-    @staticmethod
-    def _annotation(name):
-        try:
-            import jax
-            return jax.profiler.TraceAnnotation(name)
-        except Exception:   # jax absent / profiler API drift: span still times
-            return None
-
     def span(self, name, **tags):
         """Context manager timing one (possibly nested) span."""
         return _SpanContext(self, name, tags)
@@ -148,8 +186,8 @@ class SpanTracer:
 
     def _record(self, name, path, elapsed, t0, tags):
         with self._lock:
-            self.acc[name] += elapsed
-            self.cnt[name] += 1
+            self.acc[path] += elapsed
+            self.cnt[path] += 1
             self._recent.append(Span(name, path, t0 - self._epoch,
                                      elapsed, tags,
                                      tid=threading.get_ident()))
@@ -198,12 +236,14 @@ class SpanTracer:
 
     def delta_snapshot(self):
         """{phase: seconds since the previous delta_snapshot call} —
-        only phases that moved. The run journal attaches this to each
-        iteration record so per-record phase seconds sum back to the
-        run totals."""
+        only top-level phases that moved. The run journal attaches this
+        to each iteration record so per-record phase seconds sum back
+        to the run totals (and, children left out, to wall time)."""
         out = {}
         with self._lock:
             for name, total in self.acc.items():
+                if "/" in name:
+                    continue
                 d = total - self._last.get(name, 0.0)
                 if d > 0:
                     out[name] = round(d, 6)
@@ -229,3 +269,6 @@ class SpanTracer:
                         self.cnt[name])
                      for name, total in items]
         return "\n".join(lines)
+
+
+PROCESS_TRACER = SpanTracer()

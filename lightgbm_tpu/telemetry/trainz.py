@@ -14,8 +14,6 @@ training run as one JSON document:
 - `journal_tail`: the last records of this rank's run journal
 - `memory`: device/host memory watermarks (telemetry/ledger.py)
 - `compile`: the jit-lowering ledger (counts, seconds, cache hits)
-- `roofline`: live per-kernel achieved bandwidth vs the measured
-  STREAM peak (telemetry/roofline.py)
 - `comm`: per-collective wait attribution, comm_overlap_pct and the
   per-rank straggler deltas (telemetry/comm_profile.py; the fleet
   aggregator `python -m lightgbm_tpu.telemetry.aggregate` merges this
@@ -80,8 +78,8 @@ class TrainzHandler(BaseHTTPRequestHandler):
 
     def _prometheus(self):
         """The single registry (plus the scalar extras a scraper
-        wants: iteration, compile totals, memory watermarks, per-
-        kernel roofline bandwidth) in text exposition format."""
+        wants: iteration, compile totals, memory watermarks) in text
+        exposition format."""
         snapshot = self._source("metrics") or {}
         extra = {}
         it = self._source("iteration")
@@ -94,14 +92,6 @@ class TrainzHandler(BaseHTTPRequestHandler):
         mem = self._source("memory")
         if isinstance(mem, dict):
             extra.update(mem)
-        roof = self._source("roofline")
-        if isinstance(roof, dict):
-            if roof.get("peak_bytes_per_s"):
-                extra["stream_peak_bytes_per_s"] = roof["peak_bytes_per_s"]
-            for kname, k in (roof.get("kernels") or {}).items():
-                for field in ("bytes_per_s", "rows_per_s", "calls"):
-                    if isinstance(k.get(field), (int, float)):
-                        extra[f"roofline_{kname}_{field}"] = k[field]
         # GBDT mirrors the memory sample into registry gauges — drop
         # any extra whose name the registry already owns: a duplicate
         # metric name makes a real Prometheus server reject the WHOLE
@@ -145,11 +135,10 @@ class TrainzHandler(BaseHTTPRequestHandler):
 
 
 def build_sources(iteration_fn=None, tracer=None, registry=None,
-                  journal=None, tail_n=20, roofline_warn_fraction=0.0,
-                  quality_fn=None, comm_fn=None):
+                  journal=None, tail_n=20, quality_fn=None, comm_fn=None):
     """Assemble the /trainz source map from whatever exists. The
     heartbeat service is resolved lazily per request (it may start
-    after the endpoint does); memory/compile/roofline read the
+    after the endpoint does); memory/compile read the
     process-wide telemetry singletons."""
     sources = {}
     if iteration_fn is not None:
@@ -192,14 +181,8 @@ def build_sources(iteration_fn=None, tracer=None, registry=None,
         from . import ledger
         return ledger.LEDGER.snapshot()
 
-    def roofline_view():
-        from . import roofline
-        return roofline.TABLE.snapshot(
-            warn_fraction=roofline_warn_fraction)
-
     sources["memory"] = memory
     sources["compile"] = compile_ledger
-    sources["roofline"] = roofline_view
     return sources
 
 

@@ -1,0 +1,509 @@
+"""The names a trace is read by: device scopes in the compiled program
+(`jax.named_scope`, telemetry/trace.py DEVICE_SCOPES), the kernel's name,
+host spans and their annotations, the compile ledger's labels, and the
+benchmark's reader of all of them (benchmarks/scopereduce.py).
+
+One file, late in the alphabet: the AOT case compiles for a described
+v5e, and only one worker may load the TPU's library. Nothing here waits
+on a port, a thread or a sleep.
+"""
+
+import contextlib
+import os
+import re
+import struct
+import sys
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.models.partitioned import build_tree_partitioned
+from lightgbm_tpu.ops.ordered_hist import segment_histograms
+from lightgbm_tpu.ops.split import SplitParams
+from lightgbm_tpu.telemetry.ledger import (_CACHE_HIT_EVENT,
+                                           _CACHE_LOAD_EVENT,
+                                           _COMPILE_EVENT, LEDGER,
+                                           CompileLedger)
+from lightgbm_tpu.telemetry.trace import (DEVICE_SCOPES, DEVICE_SUBSCOPES,
+                                          KERNEL_NAMES, PROCESS_TRACER,
+                                          SpanTracer)
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+sys.path.insert(0, BENCH)
+import scopereduce  # noqa: E402
+import tracereduce  # noqa: E402
+
+ALL_WORDS = set(DEVICE_SCOPES).union(*DEVICE_SUBSCOPES.values())
+BLOCK = 2
+ROWS = 5000
+
+
+@contextlib.contextmanager
+def fresh_compiles():
+    """jax keys its persistent cache on the program without its debug
+    info, so an entry written before a scope existed serves an executable
+    without it: what reads names compiles afresh."""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def words_in(text):
+    """Vocabulary words that stand as a path component of some op_name."""
+    found = set()
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        found.update(ALL_WORDS.intersection(path.split("/")))
+    return found
+
+
+def builder(n_pad, w=2, b=16, l=5):
+    """build_tree_partitioned at a tiny shape, with a stand-in for the
+    collective: on one chip `hist_reduce` is the identity and leaves no
+    operation to carry the name."""
+    def core(words, g, h, ib, fm, nb, ic):
+        return build_tree_partitioned(
+            words, g, h, ib, fm, nb, ic, num_leaves=l, max_bin=b,
+            params=SplitParams(1.0, 1e-3, 0.0, 0.0, 0.0), max_depth=-1,
+            f_real=4 * w, hist_reduce_fn=lambda x: x * 2.0,
+            sum_psum_fn=lambda x: x * 2.0)
+    f = 4 * w
+    shapes = [((w, n_pad), jnp.int32), ((n_pad,), jnp.float32),
+              ((n_pad,), jnp.float32), ((n_pad,), jnp.float32),
+              ((f,), jnp.bool_), ((f,), jnp.int32), ((f,), jnp.bool_)]
+    return core, shapes
+
+
+@pytest.fixture(scope="module")
+def trained():
+    """A 5,000-row booster (two row chunks: with one, writing a window
+    back is writing the whole array and XLA drops it) on the partitioned
+    builder, one fused block of BLOCK iterations with a valid set; the
+    compiled text of every program it lowered; and what the process
+    tracer held once the dataset was dropped. Device binning is forced
+    on, as it is on the chip."""
+    rng = np.random.RandomState(7)
+    x = rng.randn(ROWS, 6).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] * x[:, 2] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 7, "max_bin": 31,
+              "partitioned_build": "true", "verbose": -1, "metric": "none"}
+    texts = []
+    compile_ = jax.stages.Lowered.compile
+
+    def recording(self, *a, **k):
+        out = compile_(self, *a, **k)
+        texts.append(out.as_text())
+        return out
+
+    PROCESS_TRACER.reset()
+    LEDGER.reset()
+    with pytest.MonkeyPatch.context() as mp, fresh_compiles():
+        mp.setenv("LIGHTGBM_TPU_DEVICE_BIN", "1")
+        mp.setattr(jax.stages.Lowered, "compile", recording)
+        ds = lgb.Dataset(x, label=y, params=dict(params)).construct()
+        vs = lgb.Dataset(x[:500], label=y[:500], reference=ds).construct()
+        booster = lgb.Booster(params=dict(params), train_set=ds)
+        booster.add_valid(vs, "v")
+        gbdt = booster.gbdt
+        gbdt.train_many(BLOCK, ignore_train_metrics=True)
+    assert gbdt.tree_learner._use_partitioned
+    del ds, vs
+    return {"gbdt": gbdt, "booster": booster, "texts": texts,
+            "process_spans": PROCESS_TRACER.recent(None),
+            "ledger": LEDGER.snapshot()}
+
+
+# ----------------------------------------------------- 1. device scopes
+def builder_text():
+    core, shapes = builder(4 * 4096)
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    with fresh_compiles():
+        return jax.jit(core).lower(*args).compile().as_text()
+
+
+def fused_text(trained):
+    fused = [t for t in trained["texts"] if "jit(fused)" in t]
+    assert len(fused) == 1
+    return fused[0]
+
+
+def kernel_text():
+    """segment_histograms through its kernel branch, interpreted: on CPU
+    the builder takes the XLA branch, which has neither kernel nor fold."""
+    words = jax.ShapeDtypeStruct((2, 2 * 4096), jnp.int32)
+    ghc = jax.ShapeDtypeStruct((3, 2 * 4096), jnp.float32)
+
+    def f(words, ghc, begin, cnt):
+        with jax.named_scope("hist"):
+            return segment_histograms(words, ghc, begin, cnt, 16, 8,
+                                      interpret_backend="tpu",
+                                      interpret=True)
+    with fresh_compiles():
+        return jax.jit(f).lower(words, ghc, jnp.int32(0),
+                                jnp.int32(5000)).compile().as_text()
+
+
+@pytest.mark.parametrize("program,expect", [
+    ("builder", ALL_WORDS - {"seg_hist", "fold"}),
+    ("fused_step", ALL_WORDS - {"seg_hist", "fold", "hist_reduce"}),
+    ("kernel_branch", {"hist", "window", "seg_hist", "fold"}),
+])
+def test_device_scopes_in_compiled_text(program, expect, request):
+    if program == "builder":
+        text = builder_text()
+    elif program == "fused_step":
+        text = fused_text(request.getfixturevalue("trained"))
+    else:
+        text = kernel_text()
+    assert expect - words_in(text) == set()
+    # sub-scopes stand under their own top-level word, nowhere else
+    for path in re.findall(r'op_name="([^"]*)"', text):
+        parts = path.split("/")
+        for top, subs in DEVICE_SUBSCOPES.items():
+            for sub in set(subs).intersection(parts):
+                assert top in parts[:parts.index(sub)], path
+
+
+def test_vocabulary_avoids_primitive_names():
+    from jax.extend import core as jex_core
+    prims = {p.name for p in vars(jax.lax).values()
+             if isinstance(p, jex_core.Primitive)}
+    assert {"gather", "slice", "scatter", "sort", "cond", "while"} <= prims
+    assert ALL_WORDS.union(KERNEL_NAMES).isdisjoint(prims)
+    assert scopereduce.VOCABULARY == DEVICE_SCOPES
+    assert scopereduce.SUBSCOPES == DEVICE_SUBSCOPES
+
+
+# -------------------------------------- 2. scopereduce on a built XSpace
+def varint(n):
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def field(no, value):
+    if isinstance(value, int):
+        return varint(no << 3) + varint(value)
+    if isinstance(value, float):
+        return varint(no << 3 | 1) + struct.pack("<d", value)
+    if isinstance(value, str):
+        value = value.encode()
+    return varint(no << 3 | 2) + varint(len(value)) + value
+
+
+STATS = {1: "tf_op", 2: "bytes_accessed", 3: "peak"}
+
+
+def plane(name, lines, metadata):
+    """lines: [(name, timestamp_ns, [(metadata id, offset_ps, dur_ps)])];
+    metadata: {id: (name, tf_op or None, bytes)}."""
+    out = field(2, name)
+    for lname, t0, events in lines:
+        body = field(2, lname) + field(3, t0)
+        for mid, off, dur in events:
+            body += field(4, field(1, mid) + field(2, off) + field(3, dur))
+        out += field(3, body)
+    for mid, (mname, tf_op, nbytes) in metadata.items():
+        md = field(1, mid) + field(2, mname)
+        if tf_op is not None:
+            md += field(5, field(1, 1) + field(5, tf_op))
+        md += field(5, field(1, 2) + field(3, nbytes))
+        md += field(5, field(1, 3) + field(2, 819.0))     # a double stat
+        out += field(4, field(1, mid) + field(2, md))
+    for sid, sname in STATS.items():
+        out += field(5, field(1, sid) + field(2, field(1, sid)
+                                              + field(2, sname)))
+    return field(1, out)
+
+
+US = 1_000_000      # ps in a microsecond
+P = "jit(fused)/while/body/closed_call/"
+# id: (name, tf_op, bytes). The loop (1) and the conditional of the
+# partition switch (2) are containers, and carry no tf_op, as in the
+# chip's trace; 3-4 are scoped leaves, 5 a bare
+# copy (once inside the conditional, once at the loop's level), 6 a copy
+# with the loop body's path and no word, 7 the kernel, 8 a zero-length
+# buffer allocation that starts in the same ns as the copy after it
+DEVICE_MD = {
+    1: ("%while.1 = while()", None, 0),
+    2: ("%cond.2 = conditional()", None, 0),
+    3: ("%fusion.3 = s32[64,7] fusion()",
+        P + "partition/cond/branch_1_fun/move/jit(_take)/gather:", 4000),
+    4: ("%fusion.4 = s32[64] fusion()",
+        P + "partition/cond/branch_1_fun/destinations/cumsum:", 1000),
+    5: ("%copy.5 = s32[7,64] copy()", None, 2000),
+    6: ("%copy.6 = f32[3,64] copy()", P.rstrip("/"), 3000),
+    7: ("%seg_hist.7 = f32[8,128,9] custom-call()",
+        P + "hist/cond/branch_0_fun/seg_hist/pallas_call:", 0),
+    8: ("%custom-call.8 = custom-call()", None, 0),
+}
+#            id  offset   duration (us)
+DEVICE_EVENTS = [(1, 100, 1000),
+                 (6, 110, 40),
+                 (2, 200, 500), (3, 210, 300), (5, 520, 100), (4, 630, 50),
+                 (7, 800, 150),
+                 (8, 960, 0), (5, 960, 20),
+                 (6, 1200, 30)]          # after the window: not counted
+HOST_MD = {1: ("bench_block", None, 0), 2: ("fused_block", None, 0),
+           3: ("fused_block/wait", None, 0), 4: ("valid_update", None, 0)}
+HOST_EVENTS = [(1, 50, 1100), (2, 60, 1000), (3, 100, 900), (4, 1070, 20)]
+
+
+def write_trace(tmp_path, device_md):
+    data = plane("/device:TPU:0",
+                 [("XLA Modules", 0, [(1, 0, 5 * US)]),
+                  ("XLA Ops", 0, [(m, o * US, d * US)
+                                  for m, o, d in DEVICE_EVENTS])],
+                 device_md)
+    # the host line starts 7 ns in: its events are offset from there
+    data += plane("/host:CPU",
+                  [("main/1", 7, [(m, o * US - 7000, d * US)
+                                  for m, o, d in HOST_EVENTS])], HOST_MD)
+    d = tmp_path / "plugins" / "profile" / "2026_01_01"
+    d.mkdir(parents=True)
+    (d / "host.xplane.pb").write_bytes(data)
+    return str(d / "host.xplane.pb")
+
+
+def unscoped_md():
+    """The same program from a cache entry written before the scopes."""
+    return {k: (n, tf and P + "cond/branch_1_fun/mul:", b)
+            for k, (n, tf, b) in DEVICE_MD.items()}
+
+
+@pytest.mark.parametrize("case", ["per_scope", "inherited_and_unscoped",
+                                  "sum_rule", "host_annotations",
+                                  "stale_executable", "readers"])
+def test_scopereduce_on_built_trace(case, tmp_path, monkeypatch):
+    stale = case == "stale_executable"
+    path = write_trace(tmp_path, unscoped_md() if stale else DEVICE_MD)
+    tab = scopereduce.reduce(scopereduce.read_xspace(path))
+    rows = tab["rows"]
+    us = lambda key: round(rows[key][0] * 1e6, 6)
+    if case == "per_scope":
+        assert us(("partition", "move", False)) == 300
+        assert us(("partition", "destinations", False)) == 50
+        assert us(("hist", "seg_hist", False)) == 150
+        assert rows[("partition", "move", False)][1] == 4000
+        assert scopereduce.seconds_of(tab, ("partition",)) == \
+            pytest.approx(450e-6)
+    elif case == "inherited_and_unscoped":
+        # the bare copy inside the conditional takes the word all scoped
+        # leaves in there agree on, apart; the one with the loop body's
+        # path sits in a container of two words and inherits none. A
+        # zero-length allocation that starts in the same ns as the copy
+        # after it makes that copy a container (tracereduce's leaf rule
+        # on whole-ns clocks): its 20 us are in nobody's busy time
+        assert us(("partition", "", True)) == 100
+        assert us(("unscoped", "", False)) == 40
+        assert rows[("unscoped", "", False)][2] == 2
+        assert tab["scoped_s"] == pytest.approx(500e-6)
+    elif case == "sum_rule":
+        devices, host = {}, []
+        names = {k: v[0] for k, v in DEVICE_MD.items()}
+        devices["/device:TPU:0"] = [
+            (names[m], float(o * 1000), float((o + d) * 1000))
+            for m, o, d in DEVICE_EVENTS]
+        host = [(HOST_MD[m][0], float(o * 1000), float((o + d) * 1000))
+                for m, o, d in HOST_EVENTS]
+        ref = tracereduce.reduce(devices, host)
+        assert tab["busy_s"] == pytest.approx(ref["busy_s"], rel=1e-9)
+        assert tab["window_s"] == pytest.approx(ref["window_s"], rel=1e-9)
+        words = scopereduce.VOCABULARY + (scopereduce.UNSCOPED,)
+        assert scopereduce.seconds_of(tab, words) == \
+            pytest.approx(ref["busy_s"], rel=1e-9)
+    elif case == "host_annotations":
+        assert tab["host"] == pytest.approx(
+            {"fused_block": 1000e-6, "fused_block/wait": 900e-6,
+             "valid_update": 20e-6})
+        assert "bench_block" not in tab["host"]
+    elif case == "stale_executable":
+        assert tab["scoped_s"] == 0
+        assert tab["busy_s"] == pytest.approx(640e-6)
+    if case in ("stale_executable", "readers"):
+        monkeypatch.setattr(scopereduce, "TRACE_ROOT", str(tmp_path))
+        scopereduce.table.cache_clear()
+        from datagen import load_module
+        ctx = {"trace": {"busy_s": tab["busy_s"]}, "block_iterations": 2}
+        read = lambda name: load_module("metrics", name).read(ctx)
+        device = ["partition_ms_per_iter", "hist_ms_per_iter",
+                  "split_scan_ms_per_iter", "tree_state_ms_per_iter",
+                  "boost_ms_per_iter", "unscoped_ms_per_iter"]
+        got = {name: read(name) for name in device}
+        if stale:
+            assert got == dict.fromkeys(device)       # None, never 0
+        else:
+            assert got["partition_ms_per_iter"] == pytest.approx(0.225)
+            assert got["unscoped_ms_per_iter"] == pytest.approx(0.020)
+            assert got["split_scan_ms_per_iter"] == 0.0
+            assert sum(got.values()) == pytest.approx(
+                1e3 * tab["busy_s"] / 2)
+        # the host's annotations do not depend on the executable
+        assert read("block_host_ms_per_iter") == pytest.approx(0.060)
+        # off a TPU the harness hands no trace: nothing is read
+        ctx["trace"] = None
+        assert [read(n) for n in device + ["block_host_ms_per_iter",
+                                          "fused_trace_lower_s",
+                                          "dataset_device_s"]] == [None] * 9
+        scopereduce.table.cache_clear()
+
+
+# ------------------------------------------------------- 3. host spans
+def test_fused_block_child_spans(trained):
+    spans = trained["gbdt"].tracer.recent(None)
+    by_path = {s["path"]: s for s in spans}
+    children = ["masks", "launch", "wait", "tree_fetch", "materialize"]
+    for name in children:
+        s = by_path["fused_block/" + name]
+        assert s["name"] == name and s["duration_s"] > 0
+        assert s["tags"] == {"iterations": BLOCK, "first_iter": 0}
+    block = by_path["fused_block"]
+    assert sum(by_path["fused_block/" + c]["duration_s"]
+               for c in children) <= block["duration_s"]
+    assert by_path["valid_update"]["tags"]["iterations"] == BLOCK
+    assert len(trained["gbdt"].models) == BLOCK
+    # children count under their path; the journal's deltas stay a
+    # partition of wall time (top-level spans only)
+    snap = trained["gbdt"].tracer.snapshot()
+    assert "fused_block/wait" in snap and "wait" not in snap
+    deltas = trained["gbdt"].tracer.delta_snapshot()
+    assert set(deltas) <= {"fused_block", "valid_update"}
+    counters = trained["gbdt"].metrics.snapshot()["counters"]
+    assert counters["fused_blocks"] == 1
+    assert counters["tree_build_dispatches"] == BLOCK
+
+
+def test_process_tracer_holds_dataset_spans(trained):
+    paths = [s["path"] for s in trained["process_spans"]]
+    first = paths[:paths.index("dataset") + 1]     # the train set's
+    assert first == ["dataset/sample", "dataset/bin_bounds",
+                     "dataset/host_prep", "dataset/upload",
+                     "dataset/bin_device", "dataset/download",
+                     "dataset/pack", "dataset"]
+    tags = trained["process_spans"][0]["tags"]
+    assert tags == {"rows": ROWS, "features": 6}
+    # a Booster's tracer holds none of it, and the other way round
+    assert not any(s["path"].startswith("dataset")
+                   for s in trained["gbdt"].tracer.recent(None))
+    assert "fused_block" not in paths
+    from datagen import load_module
+    held = PROCESS_TRACER.snapshot()
+    assert load_module("metrics", "dataset_device_s").read(
+        {"trace": {"busy_s": 1.0}}) == pytest.approx(
+            held["dataset/upload"] + held["dataset/bin_device"]
+            + held["dataset/download"])
+
+
+def test_span_annotation_carries_the_path():
+    seen = []
+
+    class Recorder:
+        def __init__(self, name):
+            seen.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    tracer = SpanTracer()
+    with mock.patch.object(jax.profiler, "TraceAnnotation", Recorder):
+        with tracer.span("outer"):
+            with tracer.span("inner", k=1):
+                pass
+    assert seen == ["outer", "outer/inner"]
+    assert not hasattr(tracer, "jax_annotations")
+
+
+# --------------------------------------------------- 4. compile ledger
+def test_ledger_holds_lower_and_compile_seconds(trained):
+    held = trained["ledger"]["label_seconds"]
+    assert held[f"fused_scan_{BLOCK}it:lower"] > 0
+    assert held[f"fused_scan_{BLOCK}it:compile"] > 0
+    labels = {e["label"] for e in trained["ledger"]["recent"]}
+    assert f"fused_scan_{BLOCK}it:compile" in labels
+    # the benchmark's reader takes the same field once the Booster is gone
+    from datagen import load_module
+    reader = load_module("metrics", "fused_trace_lower_s")
+    lower = sum(v for k, v in LEDGER.label_seconds.items()
+                if k.startswith("fused_scan_") and k.endswith(":lower"))
+    assert reader.read({"trace": {"busy_s": 1.0}}) == pytest.approx(lower)
+    # (the snapshot rounds to the microsecond)
+    assert lower >= held[f"fused_scan_{BLOCK}it:lower"] - 1e-5
+
+
+def test_ledger_reads_a_cache_hit_as_a_load():
+    """jax 0.9.0 fires backend_compile_duration for a persistent-cache
+    hit too, after the hit and its retrieval time: one entry, a load."""
+    led = CompileLedger()
+    with led.label("fused_scan_3it:compile"):
+        led._on_event(_CACHE_HIT_EVENT)
+        led._on_duration(_CACHE_LOAD_EVENT, 0.25)
+        led._on_duration(_COMPILE_EVENT, 0.3)
+        led._on_duration(_COMPILE_EVENT, 2.0)      # a miss: compiled
+    snap = led.snapshot()
+    assert [(e["cache_hit"], e["seconds"]) for e in snap["recent"]] == \
+        [(True, 0.3), (False, 2.0)]
+    assert snap["compiles"] == 1 and snap["cache_hits"] == 1
+    assert snap["total_s"] == pytest.approx(2.0)
+    assert snap["cache_load_s"] == pytest.approx(0.25)
+    assert snap["label_seconds"]["fused_scan_3it:compile"] >= 0
+
+
+# ------------------------------------------------ 5. AOT, described v5e
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_scopes_survive_the_tpu_compiler(one_chip):
+    """What the chip's compiler keeps of the names (ISSUE 25, 1a): the
+    kernel under its own name, every scope, the conditionals of both
+    switches under their word (which is what a bare copy inside a branch
+    inherits), and copies at the loop's own level bare."""
+    core, shapes = builder(4 * 4096)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    with fresh_compiles(), \
+            mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(core).lower(*args).compile().as_text()
+    assert ALL_WORDS - words_in(text) == set()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert kernels
+    for ln in kernels:
+        assert re.match(r"\s*(ROOT )?%seg_hist[.\d]* = ", ln), ln[:120]
+        assert "/hist/" in ln and "/seg_hist/" in ln
+    conditionals = [ln for ln in text.splitlines()
+                    if re.search(r" conditional\(", ln)]
+    paths = [re.search(r'op_name="([^"]*)"', ln).group(1)
+             for ln in conditionals]
+    assert any(p.endswith("/partition/cond") for p in paths), paths
+    assert any(p.endswith("/hist/cond") for p in paths), paths
+    # XLA's own copies carry the path of the computation they were put
+    # in, with no primitive of their own: inside a branch that is a
+    # scoped conditional's, at the loop's level it is bare
+    bare = [ln for ln in text.splitlines()
+            if re.search(r" copy\(", ln)
+            and 'op_name="jit(core)/while/body/closed_call"' in ln]
+    assert bare

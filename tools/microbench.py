@@ -106,16 +106,6 @@ def main():
     print(f"backend={jax.default_backend()} device_kind={kind} "
           f"peak_hbm={peak} GB/s n={n} k={k}", flush=True)
 
-    # host STREAM-style copy peak: the denominator the live roofline
-    # table (telemetry/roofline.py) rates the bincount host-callback
-    # kernels against; pin it fleet-wide via LIGHTGBM_TPU_STREAM_PEAK
-    from lightgbm_tpu.telemetry.roofline import measure_stream_peak
-    host_peak = measure_stream_peak()
-    RESULTS["stream_host"] = {"bytes_per_s": round(host_peak, 1),
-                              "gbs": round(host_peak / 1e9, 2)}
-    print(f"{'stream_host copy peak':34s} {host_peak / 1e9:8.2f} GB/s  "
-          f"(LIGHTGBM_TPU_STREAM_PEAK={host_peak:.0f})", flush=True)
-
     # device STREAM-style analog: a dependent elementwise add chain
     # streams read+write of the buffer — the device-side copy peak
     stream_v = jnp.asarray(rng.rand(n).astype(np.float32))
