@@ -7,7 +7,9 @@ is built around — synthetic HIGGS-shaped binary data from a seed,
 parameter default — and checks what comes out:
 
   K  kernels  each Pallas histogram kernel, compiled (never interpreted)
-              at F=28/B=255, against an f64 numpy histogram
+              at F=28/B=255, against an f64 numpy histogram; the
+              partition kernel at the smoke's row count against the XLA
+              formulation it replaces, every word bit-equal
   A  train    lgb.Dataset -> lgb.train (fused lax.scan, leaf-contiguous
               builder, Pallas segment kernel); 20 trees x 63 leaves; the
               root's left-child count of tree 0 recounted in numpy;
@@ -99,8 +101,10 @@ def check(cond, msg):
         raise AssertionError(msg)
 
 
-def stage_kernels(on_tpu):
-    """K: the three Pallas kernels at the smoke geometry vs numpy f64.
+def stage_kernels(on_tpu, rows=ROWS):
+    """K: the three Pallas histogram kernels at the smoke geometry vs
+    numpy f64, and the partition kernel over `rows` rows vs the XLA
+    formulation.
 
     Tolerance: the kernels accumulate exact 0/1 x f32 products in f32
     over 4096-row chunks, so a cell's error is a few f32 roundings of
@@ -161,6 +165,37 @@ def stage_kernels(on_tpu):
               f"counts exact: {counts_exact}")
         check(err <= 1e-5 and counts_exact,
               f"{name} kernel disagrees with the f64 histogram")
+
+    # the partition kernel moves rows as byte planes through a bfloat16
+    # one-hot product on the MXU: exact on paper (bytes are bfloat16
+    # numbers, one non-zero a column, f32 accumulation), and this is the
+    # chip's proof. Against the prefix-sum + scatter + gathers it
+    # replaces there, on a segment whose ends share chunks with rows
+    # that must stay put; any differing word fails.
+    from lightgbm_tpu.models.partitioned import (_partition_segment,
+                                                 _partition_segment_rows)
+    from lightgbm_tpu.ops.ordered_hist import unpack_feature
+    from lightgbm_tpu.ops.partition import pack_rows
+    n = -(-rows // HIST_CHUNK) * HIST_CHUNK
+    bins = rng.randint(0, b, size=(f, n)).astype(np.uint8)
+    words = jnp.asarray(pack_feature_words(bins))
+    stats = jnp.asarray(rng.randn(3, n).astype(np.float32))
+    perm = jnp.asarray(rng.permutation(n).astype(np.int32))
+    seg = (jnp.int32(n // 7 + 3), jnp.int32(n - n // 5), jnp.int32(11),
+           jnp.int32(b // 3), jnp.asarray(False), unpack_feature)
+    want_rows = jax.jit(lambda w, g, p: pack_rows(
+        *_partition_segment(w, g, p, *seg)[:3]))(words, stats, perm)
+    got_rows = jax.jit(lambda w, g, p: _partition_segment_rows(
+        *pack_rows(w, g, p), *seg, interpret=interpret)[:2])(
+            words, stats, perm)
+    differing = sum(
+        int(jnp.sum(jax.lax.bitcast_convert_type(a, jnp.int32)
+                    != jax.lax.bitcast_convert_type(e, jnp.int32)))
+        for a, e in zip(got_rows, want_rows))
+    print(f"  kernel partition_rows: {n} rows, segment of {int(seg[1])}, "
+          f"{differing} words differ from the XLA formulation")
+    check(differing == 0,
+          "partition_rows kernel disagrees with the XLA formulation")
 
 
 def train(x, y, extra=None, iterations=ITERATIONS):
@@ -418,7 +453,7 @@ def main(argv=None):
     t_start = time.perf_counter()
 
     print("stage K: kernels")
-    stage_kernels(on_tpu)
+    stage_kernels(on_tpu, args.rows)
     x, y = make_data(args.rows)
     print(f"stage A: train {args.rows} x {FEATURES}, {PARAMS}, "
           f"{ITERATIONS} iterations")

@@ -842,6 +842,7 @@ class GBDT:
         multi_host = getattr(self.tree_learner, "n_proc", 1) > 1
         linear = bool(getattr(self.config, "linear_tree", False))
         new_leaves = 0
+        self._note_partition_engine()
         for k in range(self.num_class):
             with self.tracer.phase("build"):
                 out = self.tree_learner.train_device(
@@ -1012,6 +1013,15 @@ class GBDT:
                 and not bool(getattr(cfg, "linear_tree", False))
                 and type(self.tree_learner).__name__ == "SerialTreeLearner")
 
+    def _note_partition_engine(self):
+        """Which engine the partitioned builder's partition step compiles
+        to (ops/partition.py partition_engine: `pallas` on a TPU, `xla`
+        off it), as the registry gauge `partition_engine` beside
+        `tree_build_dispatches` in /trainz."""
+        if getattr(self.tree_learner, "_use_partitioned", False):
+            from ..ops.partition import partition_engine
+            self.metrics.set("partition_engine", partition_engine())
+
     def _get_fused_fn(self, num_iters):
         if not hasattr(self, "_fused_cache"):
             self._fused_cache = {}
@@ -1050,6 +1060,7 @@ class GBDT:
         # learner's hist_mode for the trace (a sibling Booster may have
         # moved the process global since learner init)
         learner.apply_hist_mode()
+        self._note_partition_engine()
         num_class = self.num_class
         # both the partitioned and the gather-compacted builders dispatch
         # histogram work through a bucketed lax.switch: vmapping them

@@ -21,9 +21,16 @@ contraction on TPU, segment-sum scatter-add on CPU):
 
 - rows live in packed words (4 features/int32, ops/ordered_hist.py);
   a leaf is a position range [seg_begin[leaf], +seg_cnt[leaf]);
-- a split stable-partitions the segment with one vectorized prefix-sum
-  pass + one scatter + gathers (ops/partition.py) — the TPU analog of
-  DataPartition::Split's per-thread buffers + prefix-sum copy-back;
+- a split stable-partitions the segment (ops/partition.py) — the analog
+  of DataPartition::Split's per-thread buffers + prefix-sum copy-back.
+  On a TPU that is ONE streaming compaction kernel call, in place
+  (`partition_rows`: the row tiles covering the segment pass through
+  VMEM once; no gather, no scatter, and the only windowed thing left
+  is the 0/1 decision vector XLA hands it); off the TPU one vectorized
+  prefix-sum pass + one scatter + gathers inside a bucketed
+  `lax.switch`. Both give the same bits; the predicate that picks the
+  histogram kernel picks the engine (ops/partition.py
+  partition_engine);
 - the smaller child's histogram streams only the chunks covering its
   segment (geometric-bucketed `lax.switch`, ops/ordered_hist.py);
   the larger child is parent - smaller, as everywhere else.
@@ -48,16 +55,78 @@ from ..ops.ordered_hist import (bucket_sizes, cover_index,
                                 window_start)
 from ..ops.pallas_hist import HIST_CHUNK
 from ..ops.partition import (apply_partition, invert_permutation,
+                             pack_rows, partition_engine, partition_rows,
                              split_destinations)
 from ..ops.split import SplitParams, find_best_split, K_MIN_SCORE
 from ..telemetry.trace import scope
 from .tree_learner import apply_tree_split, init_split_state, write_candidate
 
 
+def _partition_segment_rows(rows_i, rows_f, seg_b, seg_c, feat, thr, cat,
+                            decode_fn, interpret=False):
+    """The TPU engine of the partition step: the split decision of the
+    rows covering [seg_b, seg_b+seg_c) in XLA (`decode_fn` keeps the one
+    implementation of EFB's slot decode and the categorical `==`), then
+    one `partition_rows` kernel call that rewrites those rows in place.
+
+    The decision reads a word row, and a word row of a tiled (8, N)
+    array costs all eight (0.64 ms at 11.5M rows, measured), so it is
+    taken on the geometric chunk bucket covering the segment, like the
+    histogram's window (ops/ordered_hist.py cover_index): the switch's
+    branches read a slice and return a fresh decision vector and a
+    count, nothing else. The kernel has no window: it takes the
+    segment's bounds as scalars and reads the decisions of the chunks it
+    streams.
+
+    rows_i / rows_f are the kernel's arrays (ops/partition.py pack_rows:
+    packed words with `perm` as the last row, statistics padded to 4
+    rows). Returns (rows_i, rows_f, n_left); bit for bit the arrays
+    `_partition_segment` gives.
+    """
+    wp, n = rows_i.shape
+    n_chunks = n // HIST_CHUNK
+
+    def make_branch(bk, c_first):
+        length = bk * HIST_CHUNK
+
+        def branch(seg_b, seg_c):
+            with scope("window_in"):
+                start = window_start(c_first, bk, n_chunks)
+                w_sl = jax.lax.dynamic_slice(
+                    rows_i, (jnp.int32(0), start), (wp, length))
+            with scope("decide"):
+                col = decode_fn(w_sl, feat)
+                go_left = jnp.where(cat, col == thr, col <= thr)
+            with scope("destinations"):
+                pos = start + jnp.arange(length, dtype=jnp.int32)
+                n_left = jnp.sum(
+                    go_left & (pos >= seg_b) & (pos < seg_b + seg_c),
+                    dtype=jnp.int32)
+            with scope("write_back"):
+                return (jax.lax.dynamic_update_slice(
+                            jnp.zeros(n, jnp.int32),
+                            go_left.astype(jnp.int32), (start,)),
+                        n_left)
+
+        return branch
+
+    with scope("partition"):
+        idx, c_first = cover_index(seg_b, seg_c, n_chunks)
+        go_left, n_left = jax.lax.switch(
+            idx, [make_branch(b, c_first) for b in bucket_sizes(n_chunks)],
+            seg_b, seg_c)
+        with scope("move"):
+            rows_i, rows_f = partition_rows(rows_i, rows_f, go_left,
+                                            seg_b, seg_c, n_left,
+                                            interpret=interpret)
+    return rows_i, rows_f, n_left
+
+
 def _partition_segment(words, ghc, perm, seg_b, seg_c, feat, thr, cat,
                        decode_fn):
-    """Stable-partition the segment [seg_b, seg_b+seg_c) by the split
-    decision, touching only the geometric chunk bucket covering it.
+    """The off-TPU engine of the partition step: stable-partition the
+    segment [seg_b, seg_b+seg_c) by the split decision, touching only
+    the geometric chunk bucket covering it.
 
     The permutation is identical to a full-array stable partition —
     split_destinations runs on the slice with slice-local bounds, where
@@ -202,6 +271,16 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
         h_in = hess * inbag
         ghc0 = jnp.stack([g_in, h_in, inbag], axis=0)  # (3, N_pad)
 
+    # which engine moves the rows (resolved at trace time, as the
+    # histogram's is): "pallas" keeps the words, `perm` and the
+    # statistics in the kernel's two arrays for the whole tree (the
+    # histogram reads the rows it needs of either form)
+    kernel_engine = partition_engine() == "pallas"
+    perm0 = jnp.arange(n_pad, dtype=jnp.int32)  # position -> orig row
+    if kernel_engine:
+        with scope("partition"), scope("window_in"):
+            words, ghc0 = pack_rows(words, ghc0, perm0)
+
     def leaf_histogram(words_c, ghc_c, begin, cnt):
         with scope("hist"):
             hist = segment_histograms(words_c, ghc_c, begin, cnt, b, s_pad)
@@ -221,8 +300,8 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
         state = init_split_state(l, root_split, root_c)
         state["words"] = words
         state["ghc"] = ghc0
-        # position -> orig row
-        state["perm"] = jnp.arange(n_pad, dtype=jnp.int32)
+        if not kernel_engine:
+            state["perm"] = perm0
         with scope("pos_leaf"):
             state["pos_leaf"] = jnp.zeros(n_pad, dtype=jnp.int32)
         state["seg_begin"] = jnp.zeros(l, dtype=jnp.int32)
@@ -258,9 +337,15 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
 
             # ---- physical re-partition (DataPartition::Split),
             # bucketed to the segment's chunk range
-            st["words"], st["ghc"], st["perm"], n_left = _partition_segment(
-                st["words"], st["ghc"], st["perm"], seg_b, seg_c,
-                feat, thr, cat, decode_fn)
+            if kernel_engine:
+                st["words"], st["ghc"], n_left = _partition_segment_rows(
+                    st["words"], st["ghc"], seg_b, seg_c, feat, thr, cat,
+                    decode_fn)
+            else:
+                (st["words"], st["ghc"], st["perm"],
+                 n_left) = _partition_segment(
+                    st["words"], st["ghc"], st["perm"], seg_b, seg_c,
+                    feat, thr, cat, decode_fn)
             with scope("tree_state"):
                 st["seg_begin"] = (st["seg_begin"]
                                    .at[right_id].set(seg_b + n_left))
@@ -331,8 +416,9 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
     state = jax.lax.fori_loop(0, l - 1, body, state)
     # original-order row->leaf map: one scatter at tree end
     with scope("score_update"):
+        perm = state["words"][-1] if kernel_engine else state["perm"]
         row_leaf = (jnp.zeros(n_pad, dtype=jnp.int32)
-                    .at[state["perm"]].set(state["pos_leaf"]))
+                    .at[perm].set(state["pos_leaf"]))
     return {
         "n_splits": state["n_splits"],
         "row_leaf": row_leaf,

@@ -2,17 +2,26 @@
 
 Reference: DataPartition::Split (data_partition.hpp:100-140) — per-
 thread left/right buffers merged by prefix sum keep each leaf's row
-indices contiguous and in stable order. The TPU translation is the
-same prefix-sum idea without threads: one vectorized pass computes
-every row's destination position, and the permutation is applied as a
-single scatter + gathers.
+indices contiguous and in stable order. Two engines do the same here
+and give the same bits (`partition_engine`): off the TPU the same
+prefix-sum idea without threads — one vectorized pass computes every
+row's destination position, and the permutation is applied as a single
+scatter + gathers (`split_destinations`, `invert_permutation`,
+`apply_partition`); on a TPU one streaming compaction kernel call a
+split, in place, with no indexed operation in it (`partition_rows`,
+further down).
 
 All rows of the split segment move — including out-of-bag and padding
 rows (their statistics are zero, so placement is free of side effects);
 the counts used by the tree remain the in-bag histogram counts.
 """
 
+import functools
+
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def split_destinations(go_left, begin, cnt):
@@ -80,3 +89,303 @@ def apply_partition(src, words, ghc_t, perm):
     return (jnp.take(words, src, axis=1),
             jnp.take(ghc_t, src, axis=1),
             jnp.take(perm, src))
+
+
+# ---------------------------------------------------------------------
+# The TPU engine: one streaming compaction kernel a split, in place.
+#
+# On a TPU the prefix-sum + scatter + gathers above are bound by the
+# gather unit (about 50M indices a second whatever the bytes, PERF.md
+# section 5). `partition_rows` does the same stable partition with no
+# indexed operation: the row tiles covering the segment stream through
+# VMEM once, each 128-row tile is compacted by a one-hot permutation
+# matrix on the MXU (the 32-bit words split into bytes, which bfloat16
+# holds exactly, one non-zero a column, f32 accumulation: the product
+# moves bits and rounds nothing), and whole aligned chunks are DMA'd
+# back. Left rows overwrite the input (their write chunk never passes
+# the read chunk); right rows go to an HBM scratch at their final
+# offsets and are copied back chunk-aligned once the left stream is
+# done, the boundary chunk merged with the left stream's residue.
+#
+# Mosaic slices an HBM array only in whole (8, 128) / (4, 128) tiles,
+# so the kernel's arrays carry whole tiles of rows (`pack_rows`): the
+# packed words padded to a multiple of 8 rows with `perm` riding as the
+# last one, the three statistics padded to 4. In HBM those are the
+# bytes the (7, N) and (3, N) arrays occupy anyway.
+
+def partition_engine():
+    """"pallas" where the Pallas TPU kernels are the active engine (the
+    histogram's predicate, ops/histogram.py use_pallas, resolved at
+    trace time), else "xla": a Mosaic kernel cannot run off the TPU, and
+    on it a kernel that fails to compile is an error, not a fall-back."""
+    from .histogram import use_pallas
+    return "pallas" if use_pallas() else "xla"
+
+
+PART_CHUNK = 2048   # lanes a DMA moves; divides HIST_CHUNK
+PART_TILE = 128     # rows one permutation matrix compacts
+_COPY_DEPTH = 4     # chunk copies of the right stream kept in flight
+
+
+def pack_rows(words, ghc, perm):
+    """(W, N) words, (3, N) stats, (N,) perm -> the kernel's arrays:
+    (WP, N) int32 [words; zeros; perm] with WP = 8 * ceil((W + 1) / 8),
+    and (4, N) float32 [stats; zeros]."""
+    w, n = words.shape
+    wp = -(-(w + 1) // 8) * 8
+    rows_i = jnp.concatenate(
+        [words, jnp.zeros((wp - w - 1, n), jnp.int32), perm[None, :]],
+        axis=0)
+    rows_f = jnp.concatenate([ghc, jnp.zeros((1, n), ghc.dtype)], axis=0)
+    return rows_i, rows_f
+
+
+def unpack_rows(rows_i, rows_f, w):
+    """Inverse of pack_rows for `w` word rows."""
+    return rows_i[:w], rows_f[:3], rows_i[-1]
+
+
+def _partition_rows_kernel(sc, ri_in, rf_in, go_hbm, ri, rf, si, sf,
+                           ibuf, fbuf, gbuf, li, lf, rgi, rgf, xs, ys,
+                           tri, sem_in, sem_fl, sem_cp, *, wp):
+    """sc = [seg_b, seg_c, n_left]. ri/rf are the arrays, in place
+    (ri_in/rf_in alias them and are not touched); si/sf the HBM scratch
+    of the right stream; go_hbm the (1, N) 0/1 decision vector."""
+    del ri_in, rf_in
+    c, t = PART_CHUNK, PART_TILE
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    seg_b, seg_c, n_left = sc[0], sc[1], sc[2]
+    seg_e = seg_b + seg_c
+    c0 = seg_b // c
+    c1 = jnp.where(seg_c > 0, (seg_e - 1) // c + 1, c0)
+    nch = c1 - c0
+    p0 = seg_b + n_left          # where the right stream starts
+    kb = p0 // c                 # the chunk both streams share
+
+    def chunk_at(ref, k):
+        return ref.at[:, pl.ds(pl.multiple_of(k * c, c), c)]
+
+    def half_of(ring, k):
+        return ring.at[:, pl.ds(pl.multiple_of((k % 2) * c, c), c)]
+
+    def loads(k, slot):
+        return [pltpu.make_async_copy(chunk_at(src, k), dst.at[slot],
+                                      sem_in.at[slot, j])
+                for j, (src, dst) in enumerate(
+                    [(ri, ibuf), (rf, fbuf), (go_hbm, gbuf)])]
+
+    def flushes(k, stream):
+        rings, dsts = ((li, lf), (ri, rf)) if stream == 0 else \
+            ((rgi, rgf), (si, sf))
+        return [pltpu.make_async_copy(half_of(ring, k), chunk_at(dst, k),
+                                      sem_fl.at[stream, j])
+                for j, (ring, dst) in enumerate(zip(rings, dsts))]
+
+    # constants of the tile: U[s, t] = s <= t gives inclusive ranks
+    s_iota = jax.lax.broadcasted_iota(i32, (t, t), 0)
+    tri[...] = (s_iota <= jax.lax.broadcasted_iota(i32, (t, t), 1)
+                ).astype(bf16)
+    lane = jax.lax.broadcasted_iota(i32, (1, t), 1)
+
+    def permute(x, dest):
+        """x (R, T) bf16 byte planes; dest (1, T) lane each row goes to
+        (-1: nowhere) -> (R, T) int32 with the rows moved."""
+        onehot = (s_iota == dest).astype(bf16)               # [n, t]
+        y = jax.lax.dot_general(x, onehot, (((1,), (1,)), ((), ())),
+                                preferred_element_type=f32)
+        return y.astype(i32)
+
+    def assemble(y):
+        """(R, T) int32 byte planes -> (wp, T) int32, (4, T) float32."""
+        ys[...] = y
+        wi = ys[0:wp]
+        gi = ys[4 * wp:4 * wp + 4]
+        for b in range(1, 4):
+            wi = wi | (ys[b * wp:(b + 1) * wp] << (8 * b))
+            gi = gi | (ys[4 * wp + 4 * b:4 * wp + 4 * b + 4] << (8 * b))
+        return wi, jax.lax.bitcast_convert_type(gi, f32)
+
+    def append(ring_i, ring_f, wi, gf, fill, cnt):
+        """Rows sit in wi/gf at lanes (fill + r) % T, r < cnt: select
+        them into the two ring tiles position `fill` onwards covers."""
+        off = fill % t
+        s0 = pl.multiple_of((fill // t * t) % (2 * c), t)
+        s1 = pl.multiple_of((s0 + t) % (2 * c), t)
+        m0 = (lane >= off) & (lane < off + cnt)
+        m1 = lane < off + cnt - t
+        for s, m in ((s0, m0), (s1, m1)):
+            ring_i[:, pl.ds(s, t)] = jnp.where(m, wi, ring_i[:, pl.ds(s, t)])
+            ring_f[:, pl.ds(s, t)] = jnp.where(m, gf, ring_f[:, pl.ds(s, t)])
+
+    def tile(k, slot, j, fl, fr):
+        sl = slice(j * t, (j + 1) * t)
+        wv = ibuf[slot, :, sl]
+        gv = jax.lax.bitcast_convert_type(fbuf[slot, :, sl], i32)
+        pos = k * c + j * t + lane
+        left = (pos < seg_b) | ((pos < seg_e) & (gbuf[slot, :, sl] != 0))
+        ones = left.astype(f32)
+        incl = jnp.dot(jnp.broadcast_to(ones, (16, t)).astype(bf16),
+                       tri[...], preferred_element_type=f32)[0:1]
+        excl = (incl - ones).astype(i32)        # left rows before this one
+        cl = jnp.sum(left.astype(i32))
+        for b in range(4):
+            xs[b * wp:(b + 1) * wp] = ((wv >> (8 * b)) & 0xFF).astype(f32)
+            xs[4 * wp + 4 * b:4 * wp + 4 * b + 4] = (
+                (gv >> (8 * b)) & 0xFF).astype(f32)
+        x = xs[...].astype(bf16)
+        dl = jnp.where(left, (fl + excl) & (t - 1), -1)
+        dr = jnp.where(left, -1, (fr + lane - excl) & (t - 1))
+        append(li, lf, *assemble(permute(x, dl)), fl, cl)
+        append(rgi, rgf, *assemble(permute(x, dr)), fr, t - cl)
+        return fl + cl, fr + (t - cl)
+
+    def wait_flush(stream, pending):
+        @pl.when(pending == 1)
+        def _():
+            for d in flushes(0, stream):
+                d.wait()
+
+    def maybe_flush(stream, fill, done):
+        """Flush chunk `done` of a stream once its fill has passed it."""
+        full = fill >= (done + 1) * c
+
+        @pl.when(full)
+        def _():
+            for d in flushes(done, stream):
+                d.start()
+        full = full.astype(i32)
+        return done + full, full
+
+    @pl.when(nch > 0)
+    def _():
+        for d in loads(c0, 0):
+            d.start()
+
+    def chunk_body(i, carry):
+        fl, fr, done_l, done_r, pend_l, pend_r = carry
+        k = c0 + i
+        slot = i % 2
+        for d in loads(k, slot):
+            d.wait()
+
+        @pl.when(i + 1 < nch)
+        def _():
+            for d in loads(k + 1, 1 - slot):
+                d.start()
+        # a ring half is written again a chunk after its flush started
+        wait_flush(0, pend_l)
+        wait_flush(1, pend_r)
+        for j in range(c // t):
+            fl, fr = tile(k, slot, j, fl, fr)
+        done_l, pend_l = maybe_flush(0, fl, done_l)
+        done_r, pend_r = maybe_flush(1, fr, done_r)
+        return fl, fr, done_l, done_r, pend_l, pend_r
+
+    zero = jnp.int32(0)
+    carry = jax.lax.fori_loop(
+        0, nch, chunk_body, (c0 * c, p0, c0, kb, zero, zero))
+    wait_flush(0, carry[4])
+    wait_flush(1, carry[5])
+
+    # ---- the right stream comes home: chunk kb merged with the left
+    # stream's residue (lanes below p0 % c), the rest copied whole
+    @pl.when(kb < c1)
+    def _():
+        back = [pltpu.make_async_copy(chunk_at(src, kb), dst.at[0],
+                                      sem_in.at[0, j])
+                for j, (src, dst) in enumerate([(si, ibuf), (sf, fbuf)])]
+        for d in back:
+            d.start()
+        for d in back:
+            d.wait()
+        lane_c = jax.lax.broadcasted_iota(i32, (1, c), 1)
+        keep = lane_c < p0 % c
+        ibuf[0] = jnp.where(keep, half_of(li, kb)[...], ibuf[0])
+        fbuf[0] = jnp.where(keep, half_of(lf, kb)[...], fbuf[0])
+        home = [pltpu.make_async_copy(src.at[0], chunk_at(dst, kb),
+                                      sem_in.at[0, j])
+                for j, (src, dst) in enumerate([(ibuf, ri), (fbuf, rf)])]
+        for d in home:
+            d.start()
+        for d in home:
+            d.wait()
+
+    def copies(k):
+        return [pltpu.make_async_copy(chunk_at(src, k), chunk_at(dst, k),
+                                      sem_cp.at[k % _COPY_DEPTH, j])
+                for j, (src, dst) in enumerate([(si, ri), (sf, rf)])]
+
+    def copy_body(k, _):
+        @pl.when(k - _COPY_DEPTH > kb)
+        def _():
+            for d in copies(k - _COPY_DEPTH):
+                d.wait()
+        for d in copies(k):
+            d.start()
+        return 0
+
+    jax.lax.fori_loop(kb + 1, c1, copy_body, 0)
+
+    def drain_body(k, _):
+        for d in copies(k):
+            d.wait()
+        return 0
+
+    jax.lax.fori_loop(jnp.maximum(c1 - _COPY_DEPTH, kb + 1), c1,
+                      drain_body, 0)
+
+
+def partition_rows(rows_i, rows_f, go_left, seg_b, seg_c, n_left,
+                   interpret=False):
+    """Stable two-way partition of positions [seg_b, seg_b + seg_c) of
+    the packed arrays (pack_rows), in place: rows whose `go_left` is set
+    first, each side in its old order, every other position untouched.
+
+    Args:
+      rows_i: (WP, N) int32, rows_f: (4, N) float32, N a multiple of
+        PART_CHUNK.
+      go_left: (N,) the decision of every position (only the segment's
+        values matter).
+      seg_b, seg_c: traced int32 segment bounds.
+      n_left: the number of set decisions inside the segment.
+
+    Returns (rows_i, rows_f): bit for bit what apply_partition(
+    invert_permutation(split_destinations(...))) gives. `interpret`
+    runs the kernel body in pallas interpret mode (CPU tests).
+    """
+    wp, n = rows_i.shape
+    c, t = PART_CHUNK, PART_TILE
+    if n % c or wp % 8 or rows_f.shape != (4, n):
+        raise ValueError(f"partition_rows: bad shapes {rows_i.shape} "
+                         f"{rows_f.shape} (N a multiple of {c})")
+    sc = jnp.stack([seg_b, seg_c, n_left]).astype(jnp.int32)
+    go = go_left.astype(jnp.int32).reshape(1, n)
+    planes = 4 * wp + 16
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_partition_rows_kernel, wp=wp),
+        name="partition_rows",   # the kernel's name in a trace
+        interpret=interpret,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[any_spec] * 3, out_specs=[any_spec] * 4,
+            scratch_shapes=[
+                pltpu.VMEM((2, wp, c), jnp.int32),      # chunks read
+                pltpu.VMEM((2, 4, c), jnp.float32),
+                pltpu.VMEM((2, 1, c), jnp.int32),
+                pltpu.VMEM((wp, 2 * c), jnp.int32),     # left ring
+                pltpu.VMEM((4, 2 * c), jnp.float32),
+                pltpu.VMEM((wp, 2 * c), jnp.int32),     # right ring
+                pltpu.VMEM((4, 2 * c), jnp.float32),
+                pltpu.VMEM((planes, t), jnp.float32),   # byte planes in
+                pltpu.VMEM((planes, t), jnp.int32),     # ... and out
+                pltpu.VMEM((t, t), jnp.bfloat16),       # rank matrix
+                pltpu.SemaphoreType.DMA((2, 3)),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((_COPY_DEPTH, 2)),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(rows_i.shape, rows_i.dtype),
+                   jax.ShapeDtypeStruct(rows_f.shape, rows_f.dtype)] * 2,
+        input_output_aliases={1: 0, 2: 1},
+    )(sc, rows_i, rows_f, go)
+    return out[0], out[1]

@@ -67,9 +67,10 @@ DEVICE_SUBSCOPES = {
     "tree_state": ("hist_cache", "pos_leaf"),
 }
 # names of the Pallas kernels (`pallas_call(name=...)`): the segment
-# kernel of the fused path and the two full-matrix kernels chip_smoke.py
-# runs
-KERNEL_NAMES = ("seg_hist", "masked_hist", "frontier_hist")
+# kernel and the partition kernel of the fused path, and the two
+# full-matrix kernels chip_smoke.py runs
+KERNEL_NAMES = ("seg_hist", "partition_rows", "masked_hist",
+                "frontier_hist")
 _SCOPE_WORDS = frozenset(DEVICE_SCOPES).union(*DEVICE_SUBSCOPES.values())
 
 

@@ -5,6 +5,7 @@ tolerance. Catches kernel-body bugs without TPU hardware (Mosaic
 compilation itself is only exercised on a real chip)."""
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
@@ -136,3 +137,88 @@ def test_segment_kernel_interpret_bench_shape():
                               interpret_backend="cpu")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
+
+
+# ------------------------------------------ the partition kernel (PR 26)
+def _partition_fixture():
+    from lightgbm_tpu.ops.partition import PART_CHUNK
+    rng = np.random.RandomState(6)
+    n, f = 4 * PART_CHUNK, 7
+    bins = rng.randint(0, 16, size=(f, n), dtype=np.uint8)
+    words = pack_feature_words(bins)
+    # every bit pattern has to survive: full-range words in the row no
+    # feature of this fixture reads, signed statistics, a shuffled perm
+    words[1] = rng.randint(-2**31, 2**31 - 1, size=n,
+                           dtype=np.int64).astype(np.int32)
+    ghc = rng.randn(3, n).astype(np.float32)
+    perm = rng.permutation(n).astype(np.int32)
+    return n, bins, jnp.asarray(words), jnp.asarray(ghc), jnp.asarray(perm)
+
+
+def _efb_decode(w_sl, feat):
+    """A stand-in for the bundled learner's slot decode: virtual feature
+    v lives in stored slot v // 2, its bins offset by 5 * (v % 2)."""
+    from lightgbm_tpu.ops.ordered_hist import unpack_feature
+    return jnp.maximum(unpack_feature(w_sl, feat // 2) - 5 * (feat % 2), 0)
+
+
+_N = 4 * 2048
+PARTITION_CASES = {
+    # name: (seg_b, seg_c, feat, thr, categorical, decode)
+    "whole_array": (0, _N, 2, 7, False, None),
+    "begin_not_tile_aligned": (100, 3000, 2, 7, False, None),
+    "inside_one_tile": (37, 50, 2, 7, False, None),
+    "across_a_chunk_edge": (2048 - 5, 10, 2, 7, False, None),
+    "ends_at_n": (_N - 200, 200, 2, 7, False, None),
+    "chunk_aligned": (2048, 4096, 2, 7, False, None),
+    "empty": (5, 0, 2, 7, False, None),
+    "one_row": (5, 1, 2, 7, False, None),
+    "all_left": (130, 5000, 2, 15, False, None),
+    "all_right": (130, 5000, 2, -1, False, None),
+    "categorical": (999, 4103, 3, 4, True, None),
+    "efb_decode": (1000, 5000, 5, 3, False, _efb_decode),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARTITION_CASES))
+def test_partition_kernel_interpret_matches_xla(case):
+    """`partition_rows` (one streaming compaction kernel, in place)
+    against the off-TPU formulation it replaces on the chip: prefix
+    sums, the inverse-permutation scatter and the three gathers. A
+    permutation of 32-bit patterns: every word, statistic, perm entry
+    and n_left bit-equal."""
+    from lightgbm_tpu.models.partitioned import _partition_segment_rows
+    from lightgbm_tpu.ops.ordered_hist import unpack_feature
+    from lightgbm_tpu.ops.partition import (apply_partition,
+                                            invert_permutation, pack_rows,
+                                            split_destinations, unpack_rows)
+    n, bins, words, ghc, perm = _partition_fixture()
+    assert n == _N
+    seg_b, seg_c, feat, thr, cat, decode = PARTITION_CASES[case]
+    decode = decode or unpack_feature
+    b, c = jnp.int32(seg_b), jnp.int32(seg_c)
+
+    @jax.jit
+    def kernel(b, c):
+        rows_i, rows_f, n_left = _partition_segment_rows(
+            *pack_rows(words, ghc, perm), b, c, jnp.int32(feat),
+            jnp.int32(thr), jnp.asarray(cat), decode, interpret=True)
+        return unpack_rows(rows_i, rows_f, words.shape[0]) + (n_left,)
+
+    @jax.jit
+    def formulation(b, c):
+        col = decode(words, jnp.int32(feat))
+        go_left = jnp.where(cat, col == thr, col <= thr)
+        dest, n_left = split_destinations(go_left, b, c)
+        return apply_partition(invert_permutation(dest), words, ghc,
+                               perm) + (n_left,)
+
+    got, want = kernel(b, c), formulation(b, c)
+    if case == "all_left":
+        assert int(want[3]) == seg_c
+    if case == "all_right":
+        assert int(want[3]) == 0
+    for name, g, w in zip(("words", "ghc", "perm", "n_left"), got, want):
+        np.testing.assert_array_equal(
+            np.asarray(g).view(np.int32), np.asarray(w).view(np.int32),
+            err_msg=f"{case}: {name}")

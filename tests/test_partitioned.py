@@ -358,3 +358,45 @@ def test_partitioned_bundled_fused_matches_per_iter():
         np.testing.assert_array_equal(ts.split_feature, tf.split_feature)
         np.testing.assert_array_equal(ts.threshold_in_bin,
                                       tf.threshold_in_bin)
+
+
+def test_builder_engines_grow_the_same_tree(monkeypatch):
+    """The TPU engine of the partition step (ops/partition.py
+    partition_rows, here through the Pallas interpreter, with the
+    builder's state in the kernel's packed arrays) and the off-TPU
+    engine move the same rows to the same places, so the builder grows
+    the same tree to the bit, row->leaf map included."""
+    from lightgbm_tpu.models import partitioned
+    from lightgbm_tpu.ops.pallas_hist import HIST_CHUNK
+    from lightgbm_tpu.ops.split import SplitParams
+
+    rng = np.random.RandomState(11)
+    n, f, b, leaves = 2 * HIST_CHUNK, 6, 16, 6
+    bins = rng.randint(0, b, size=(f, n), dtype=np.uint8)
+    words = jnp.asarray(pack_feature_words(bins))
+    grad = jnp.asarray(rng.randn(n).astype(np.float32))
+    hess = jnp.ones(n, jnp.float32)
+    inbag = jnp.asarray((np.arange(n) < n - 100).astype(np.float32))
+    is_cat = jnp.asarray([False, False, True, False, False, False,
+                          False, False])
+
+    def grow():
+        return jax.jit(lambda: partitioned.build_tree_partitioned(
+            words, grad, hess, inbag, jnp.ones(8, bool),
+            jnp.full(8, b, jnp.int32), is_cat, num_leaves=leaves,
+            max_bin=b, params=SplitParams(1.0, 1e-3, 0.0, 0.0, 0.0),
+            max_depth=-1, f_real=f))()
+
+    assert partitioned.partition_engine() == "xla"
+    want = grow()
+    monkeypatch.setattr(partitioned, "partition_engine", lambda: "pallas")
+    kernel = partitioned.partition_rows
+    monkeypatch.setattr(
+        partitioned, "partition_rows",
+        lambda *a, **k: kernel(*a, **dict(k, interpret=True)))
+    got = grow()
+    assert int(want["n_splits"]) == leaves - 1
+    assert set(got) == set(want)
+    for key in want:
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), err_msg=key)

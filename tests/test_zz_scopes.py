@@ -383,6 +383,9 @@ def test_fused_block_child_spans(trained):
     counters = trained["gbdt"].metrics.snapshot()["counters"]
     assert counters["fused_blocks"] == 1
     assert counters["tree_build_dispatches"] == BLOCK
+    # the engine the learner's partition step compiled to, beside it
+    gauges = trained["gbdt"].metrics.snapshot()["gauges"]
+    assert gauges["partition_engine"] == "xla"
 
 
 def test_process_tracer_holds_dataset_spans(trained):
@@ -478,32 +481,45 @@ def one_chip():
 
 
 def test_scopes_survive_the_tpu_compiler(one_chip):
-    """What the chip's compiler keeps of the names (ISSUE 25, 1a): the
-    kernel under its own name, every scope, the conditionals of both
-    switches under their word (which is what a bare copy inside a branch
-    inherits), and copies at the loop's own level bare."""
+    """What the chip's compiler keeps of the names (ISSUE 25, 1a; ISSUE
+    26): both kernels under their own names, `partition_rows` under
+    `partition` / `move`; every scope the TPU engine writes (all but
+    `invert`: there is no inverse permutation to scatter); the
+    conditionals of the histogram's switch and of the decision's window
+    switch under their words; no gather and no scatter under
+    `partition`, and no copy of the rows inside the decision's
+    branches."""
     core, shapes = builder(4 * 4096)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes]
     with fresh_compiles(), \
             mock.patch.object(jax, "default_backend", lambda: "tpu"):
         text = jax.jit(core).lower(*args).compile().as_text()
-    assert ALL_WORDS - words_in(text) == set()
+    assert ALL_WORDS - words_in(text) == {"invert"}
     kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert kernels
+    names = set()
     for ln in kernels:
-        assert re.match(r"\s*(ROOT )?%seg_hist[.\d]* = ", ln), ln[:120]
-        assert "/hist/" in ln and "/seg_hist/" in ln
+        name = re.match(r"\s*(ROOT )?%([a-z_]+)[.\d]* = ", ln).group(2)
+        names.add(name)
+        path = re.search(r'op_name="([^"]*)"', ln).group(1).split("/")
+        if name == "partition_rows":
+            assert path.index("partition") < path.index("move"), ln[:200]
+        else:
+            assert name == "seg_hist" and "hist" in path, ln[:200]
+            assert "seg_hist" in path
+    assert names == {"seg_hist", "partition_rows"}
+    assert names <= set(KERNEL_NAMES)
     conditionals = [ln for ln in text.splitlines()
                     if re.search(r" conditional\(", ln)]
     paths = [re.search(r'op_name="([^"]*)"', ln).group(1)
              for ln in conditionals]
-    assert any(p.endswith("/partition/cond") for p in paths), paths
     assert any(p.endswith("/hist/cond") for p in paths), paths
-    # XLA's own copies carry the path of the computation they were put
-    # in, with no primitive of their own: inside a branch that is a
-    # scoped conditional's, at the loop's level it is bare
-    bare = [ln for ln in text.splitlines()
-            if re.search(r" copy\(", ln)
-            and 'op_name="jit(core)/while/body/closed_call"' in ln]
-    assert bare
+    assert any(p.endswith("/partition/cond") for p in paths), paths
+    for ln in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', ln)
+        if m and "partition" in m.group(1).split("/"):
+            assert not re.search(r" (gather|scatter)\(", ln), ln[:200]
+            # the rows' two arrays (8 and 4 rows of n_pad) are never
+            # copied, transposed or not, for a window to be cut out
+            assert not re.search(r"= [sf]32\[[84],16384\]\S* copy\(", ln), \
+                ln[:200]

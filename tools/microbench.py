@@ -14,7 +14,12 @@ measured numbers instead of guesses:
   - argsort:     full-N int32 argsort (alternative partition route)
   - masked_hist: the shipped pallas masked histogram
   - segment_hist / partition: the partitioned builder's two hot ops at
-                 several segment sizes
+                 several segment sizes; `partition` times the off-TPU
+                 formulation (`_partition_segment`: slice, prefix sums,
+                 scatter, gathers, write-back), its `invert` + `move`
+                 alone on the same window, and on a TPU the kernel
+                 `partition_rows` (ops/partition.py) on the same segment,
+                 after checking the two bit-equal
   - fused_iter:  one full boosting iteration (gradients + whole tree +
                  score update) for BOTH builders at the bench config
 
@@ -27,7 +32,10 @@ Each line reports achieved GB/s against the chip's peak HBM bandwidth
 peak is looked up by `jax.devices()[0].device_kind`; a device that is
 not in the table is an error, not a default.
 
-Usage:  python tools/microbench.py [N] [K]
+Usage:  python tools/microbench.py [N] [K] [rows]
+        rows: comma-separated row groups to run (default all): stream,
+        take, cumsum, argsort, masked_hist, segment_hist, partition,
+        fused_iter
 """
 
 import os
@@ -96,22 +104,7 @@ def chain_time(fn, make_init, k, label, step_bytes=None):
     return ms
 
 
-def main():
-    n = int(sys.argv[1]) if len(sys.argv) > 1 else 1_000_000
-    k = int(sys.argv[2]) if len(sys.argv) > 2 else 20
-    f_words = 7  # 28 uint8 features packed 4-per-int32
-    rng = np.random.RandomState(0)
-
-    peak, kind = _peak_gbs()   # fails here, before any work, if unknown
-    print(f"backend={jax.default_backend()} device_kind={kind} "
-          f"peak_hbm={peak} GB/s n={n} k={k}", flush=True)
-
-    # device STREAM-style analog: a dependent elementwise add chain
-    # streams read+write of the buffer — the device-side copy peak
-    stream_v = jnp.asarray(rng.rand(n).astype(np.float32))
-    chain_time(lambda v: v + 1.0, lambda i: stream_v + np.float32(i), k,
-               f"stream_device add ({n},) f32", step_bytes=8 * n)
-
+def bench_take(n, k, f_words, rng):
     words = jnp.asarray(rng.randint(0, 2**31, size=(f_words, n), dtype=np.int32))
     perm_h = rng.permutation(n).astype(np.int32)
 
@@ -145,18 +138,6 @@ def main():
     chain_time(take_rows, lambda i: (words_r, perm_v(i)), k,
                f"take_rows ({n},7) i32", step_bytes=2 * words_b + 4 * n)
 
-    vec = jnp.asarray(rng.rand(n).astype(np.float32))
-    chain_time(lambda v: jnp.cumsum(v) * 1e-6,
-               lambda i: vec + np.float32(i), k,
-               f"cumsum ({n},) f32", step_bytes=8 * n)
-
-    keys = jnp.asarray(rng.randint(0, 4, size=n, dtype=np.int32))
-
-    def argsorted(c):
-        return jnp.argsort(c, stable=True).astype(jnp.int32) % 4
-
-    chain_time(argsorted, lambda i: (keys + i) % 4, k, f"argsort ({n},) i32")
-
     # one-per-row gather of f32 (ghc permutation, 3 stat rows)
     ghc = jnp.asarray(rng.rand(3, n).astype(np.float32))
 
@@ -167,12 +148,11 @@ def main():
     chain_time(take_ghc, lambda i: (ghc, perm_v(i)), k,
                f"take_cols (3,{n}) f32", step_bytes=2 * 12 * n + 4 * n)
 
+
+def bench_masked_hist(n_pad, k, f, ghc_t, rng):
     # baseline: shipped masked histogram at the bench shape
     from lightgbm_tpu.ops.pallas_hist import masked_histograms, HIST_CHUNK
-    f = 28
-    n_pad = ((n + HIST_CHUNK - 1) // HIST_CHUNK) * HIST_CHUNK
     bins = jnp.asarray(rng.randint(0, 255, size=(f, n_pad), dtype=np.uint8))
-    ghc_t = jnp.asarray(rng.rand(3, n_pad).astype(np.float32))
     row_leaf = jnp.zeros(n_pad, dtype=jnp.int32)
 
     def hist_step(carry):
@@ -185,11 +165,11 @@ def main():
                f"masked_hist ({f},{n_pad})x256",
                step_bytes=(f + 12) * n_pad)
 
+
+def bench_segment_hist(n_pad, k, words28, ghc_t):
     # the partitioned path's segment histogram at several leaf sizes
-    from lightgbm_tpu.ops.ordered_hist import (pack_feature_words,
-                                               segment_histograms)
-    words28 = jnp.asarray(pack_feature_words(
-        rng.randint(0, 255, size=(f, n_pad), dtype=np.uint8)))
+    from lightgbm_tpu.ops.ordered_hist import segment_histograms
+    from lightgbm_tpu.ops.pallas_hist import HIST_CHUNK
     for seg in [HIST_CHUNK, 16 * HIST_CHUNK, n_pad]:
         seg = min(seg, n_pad)
 
@@ -202,34 +182,88 @@ def main():
 
         chain_time(seg_step, lambda i: (jnp.int32(1 + (i % 2)),
                                         jnp.float32(i)), k,
-                   f"segment_hist seg={seg}", step_bytes=(f + 12) * seg)
+                   f"segment_hist seg={seg}", step_bytes=(28 + 12) * seg)
 
-    # the partition step at several segment sizes (the second hot op of
-    # the partitioned builder: slice + stable partition + write-back)
+
+def bench_partition(n_pad, k, words28, ghc_t):
+    """The partition step at several segment sizes, three ways: the whole
+    off-TPU formulation (slice + prefix sums + scatter + gathers +
+    write-back inside the bucketed switch), its `invert` + `move` alone
+    on the covering window, and on a TPU the kernel `partition_rows` on
+    the same segment, first checked bit-equal against the formulation."""
     from lightgbm_tpu.models.partitioned import _partition_segment
+    from lightgbm_tpu.ops.histogram import use_pallas
     from lightgbm_tpu.ops.ordered_hist import unpack_feature
+    from lightgbm_tpu.ops.pallas_hist import HIST_CHUNK
+    from lightgbm_tpu.ops.partition import (apply_partition,
+                                            invert_permutation, pack_rows,
+                                            partition_rows,
+                                            split_destinations)
 
+    f = 28
     perm0_h = np.arange(n_pad, dtype=np.int32)
-    for seg in [HIST_CHUNK, 16 * HIST_CHUNK, n_pad]:
-        seg = min(seg, n_pad)
+    feat, thr = jnp.int32(3), jnp.int32(100)
+    go_full = unpack_feature(words28, feat) <= thr
+    for seg in sorted({min(s, n_pad) for s in
+                       (HIST_CHUNK, 64 * HIST_CHUNK, n_pad)}):
+        seg_b = jnp.int32(0)
+        seg_c = jnp.int32(seg)
+        moved = 2 * (f + 12 + 4) * seg     # words + stats + perm, in + out
 
-        def part_step(carry, seg=seg):
+        def part_step(carry, seg_c=seg_c):
             w, g, p = carry
             # data dependency rides the threshold (doesn't change the
             # segment geometry, so the labeled bucket is what's timed)
             w2, g2, p2, nl = _partition_segment(
-                w, g, p, jnp.int32(0), jnp.int32(seg),
-                jnp.int32(3), jnp.int32(100) + (p[0] % 2),
+                w, g, p, seg_b, seg_c, feat, thr + (p[0] % 2),
                 jnp.asarray(False), unpack_feature)
             return (w2, g2, p2)
 
-        # ~2x (words+ghc) movement within the covering bucket + ranks
-        chain_time(part_step,
-                   lambda i: (words28, ghc_t,
-                              jnp.asarray(np.roll(perm0_h, i))), k,
-                   f"partition seg={seg}",
-                   step_bytes=2 * (f + 12) * seg + 12 * seg)
+        def init(i):
+            return words28, ghc_t, jnp.asarray(np.roll(perm0_h, i))
 
+        chain_time(part_step, init, k, f"partition xla seg={seg}",
+                   step_bytes=moved + 12 * seg)
+
+        # `invert` + `move` on the window alone, destinations given
+        dest, n_left = split_destinations(go_full[:seg], seg_b, seg_c)
+
+        def move_step(carry):
+            w, g, p = carry
+            # p >= 0: the shift is 0, and XLA cannot hoist the scatter
+            src = invert_permutation(dest + (p[0] >> 31))
+            return apply_partition(src, w, g, p)
+
+        chain_time(move_step,
+                   lambda i: tuple(a[..., :seg] for a in init(i)), k,
+                   f"partition xla invert+move seg={seg}", step_bytes=moved)
+
+        if not use_pallas():
+            continue
+        kernel = jax.jit(lambda ri, rf: partition_rows(
+            ri, rf, go_full, seg_b, seg_c, n_left))
+        ri0, rf0 = pack_rows(*init(0))
+        ref = jax.jit(lambda w, g, p: pack_rows(*_partition_segment(
+            w, g, p, seg_b, seg_c, feat, thr, jnp.asarray(False),
+            unpack_feature)[:3]))(*init(0))
+        got = kernel(ri0, rf0)
+        same = all(bool(jnp.array_equal(
+            jax.lax.bitcast_convert_type(a, jnp.int32),
+            jax.lax.bitcast_convert_type(b, jnp.int32)))
+            for a, b in zip(got, ref))
+        print(f"partition_rows seg={seg}: bit-equal to the xla "
+              f"formulation: {same}", flush=True)
+        if not same:
+            raise SystemExit("partition_rows differs from the xla "
+                             "formulation")
+
+        chain_time(lambda carry: partition_rows(
+            *carry, go_full, seg_b, seg_c, n_left),
+            lambda i: pack_rows(*init(i)), k,
+            f"partition_rows seg={seg}", step_bytes=moved)
+
+
+def bench_fused_iter(n_pad, k):
     # ---- the ACTUAL bench unit: one full fused boosting iteration
     # (gradients + whole partitioned tree + score update) at the bench
     # config
@@ -265,6 +299,60 @@ def main():
         RESULTS[f"fused_iter_{name}"] = {"ms": round(dt * 1e3, 2)}
         print(f"fused_iter {name} {n_real}x28x63l: {dt * 1e3:9.2f} ms/iter",
               flush=True)
+
+
+def main():
+    n = int(sys.argv[1]) if len(sys.argv) > 1 else 1_000_000
+    k = int(sys.argv[2]) if len(sys.argv) > 2 else 20
+    only = set(sys.argv[3].split(",")) if len(sys.argv) > 3 else None
+
+    def want(group):
+        return only is None or group in only
+
+    f_words = 7  # 28 uint8 features packed 4-per-int32
+    rng = np.random.RandomState(0)
+
+    peak, kind = _peak_gbs()   # fails here, before any work, if unknown
+    print(f"backend={jax.default_backend()} device_kind={kind} "
+          f"peak_hbm={peak} GB/s n={n} k={k}", flush=True)
+
+    # device STREAM-style analog: a dependent elementwise add chain
+    # streams read+write of the buffer — the device-side copy peak
+    if want("stream"):
+        stream_v = jnp.asarray(rng.rand(n).astype(np.float32))
+        chain_time(lambda v: v + 1.0, lambda i: stream_v + np.float32(i), k,
+                   f"stream_device add ({n},) f32", step_bytes=8 * n)
+    if want("take"):
+        bench_take(n, k, f_words, rng)
+    if want("cumsum"):
+        vec = jnp.asarray(rng.rand(n).astype(np.float32))
+        chain_time(lambda v: jnp.cumsum(v) * 1e-6,
+                   lambda i: vec + np.float32(i), k,
+                   f"cumsum ({n},) f32", step_bytes=8 * n)
+    if want("argsort"):
+        keys = jnp.asarray(rng.randint(0, 4, size=n, dtype=np.int32))
+
+        def argsorted(c):
+            return jnp.argsort(c, stable=True).astype(jnp.int32) % 4
+
+        chain_time(argsorted, lambda i: (keys + i) % 4, k,
+                   f"argsort ({n},) i32")
+
+    from lightgbm_tpu.ops.ordered_hist import pack_feature_words
+    from lightgbm_tpu.ops.pallas_hist import HIST_CHUNK
+    f = 28
+    n_pad = ((n + HIST_CHUNK - 1) // HIST_CHUNK) * HIST_CHUNK
+    ghc_t = jnp.asarray(rng.rand(3, n_pad).astype(np.float32))
+    if want("masked_hist"):
+        bench_masked_hist(n_pad, k, f, ghc_t, rng)
+    bins28 = rng.randint(0, 255, size=(f, n_pad), dtype=np.uint8)
+    words28 = jnp.asarray(pack_feature_words(bins28))
+    if want("segment_hist"):
+        bench_segment_hist(n_pad, k, words28, ghc_t)
+    if want("partition"):
+        bench_partition(n_pad, k, words28, ghc_t)
+    if want("fused_iter"):
+        bench_fused_iter(n_pad, k)
 
     # machine-readable summary (one line, BASELINE-quotable)
     import json
