@@ -88,7 +88,7 @@ def one_seed(task):
     config, traffic = cell["config"], cell["traffic"]
     cfg = train_params(config, traffic)
     data = dict(config["data"], **({"rows": rows} if rows else {}))
-    x, y = make_data(data, seed)
+    x, y, _ = make_data(data, seed)   # the binary reference takes no fields
     out = {}
     for mode in modes:
         trees, score = stand_in(x, y, cfg, int(traffic["block_iterations"]),

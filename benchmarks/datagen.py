@@ -1,6 +1,8 @@
 """The one generator: a cell's inputs from its configuration's `data`
 block, its traffic mix and `--seed`. Nothing here knows a cell by name:
-`data.kind` names the recipe, generators/<kind>.py `make(data, seed)`."""
+`data.kind` names the recipe, generators/<kind>.py `make(data, seed)`,
+which returns `(x, y)` or `(x, y, fields)`: `fields` is what
+`lgb.Dataset` takes beside the label (FIELDS)."""
 
 import importlib.util
 import os
@@ -19,8 +21,20 @@ def load_module(subdir, name):
     return mod
 
 
+FIELDS = ("group", "weight", "categorical_feature")
+
+
 def make_data(data, seed):
-    return load_module("generators", data["kind"]).make(data, seed)
+    """(x, y, fields) of the configuration's recipe; `fields` is empty
+    for a recipe that returns the matrix and the label alone. A key
+    `lgb.Dataset` would not take is an error, never dropped."""
+    x, y, *rest = load_module("generators", data["kind"]).make(data, seed)
+    fields = dict(*rest)
+    unknown = sorted(set(fields) - set(FIELDS))
+    if unknown:
+        raise ValueError(f"generator {data['kind']!r} returned unknown "
+                         f"field(s) {unknown}; lgb.Dataset takes {FIELDS}")
+    return x, y, fields
 
 
 def train_params(config, traffic):

@@ -264,6 +264,12 @@ def rows_visited(tree, n):
     return float(n + child_counts(tree).min(axis=1).sum())
 
 
+def rows_partitioned(tree):
+    """Rows a leaf-contiguous learner must move for this tree: at every
+    split, the rows of the segment it divides (the parent's count)."""
+    return float(np.sum(tree["internal_count"]))
+
+
 # --------------------------------------------------------------- comparison
 def prepare(x, cfg, pool):
     """Own bin bounds from the configuration's sample, then all rows binned."""

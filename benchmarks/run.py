@@ -8,12 +8,19 @@ data found by name: workloads/<cell>.json names configs/<config>.json and
 traffic/<mix>.json; BENCHMARK.json lists the metrics, and a per-layer
 metric <name> is read by metrics/<name>.py `read(ctx)`.
 
-Set-up (setup_s): matrix from --seed, lgb.Dataset (device binning),
-lgb.train of the first block (trace, lower, compile or cache load, run).
-Window: further blocks on the same booster through GBDT.train_many, whole
-blocks until --seconds have passed. With --trace 1 one block under
-jax.profiler instead. Then the program's state is dropped and the plain
-reference (reference.py) follows the first block's trees.
+Set-up (setup_s): matrix from --seed (and the fields the generator gives
+beside it: group, weight, categorical_feature), lgb.Dataset (device
+binning), lgb.train of the first block (trace, lower, compile or cache
+load, run). Window: further blocks on the same booster through
+GBDT.train_many, whole blocks until --seconds have passed. With --trace 1
+one block under jax.profiler instead. Then the program's state is dropped
+and the plain reference follows the first block's trees: reference.py
+(binary log-loss), or references/<name>.py where the configuration names
+one under `reference`. A block holds block_iterations x K trees, K what
+the booster grows an iteration (num_class).
+
+Every phase mark on stderr says the seconds of its phase, and the last
+one, `run total`, all of them: the driver stops a run at 360 s.
 
 Without a TPU the runner exits 2 and prints no result, unless --rehearse
 (the harness's own flag: CPU rehearsal at --rows, platform named, never
@@ -41,6 +48,23 @@ def mark(msg):
     """Timestamped phase mark on stderr: a run that is cut shows where."""
     print(f"[bench {time.strftime('%H:%M:%S')} +{time.perf_counter() - _T0:6.1f}s] "
           f"{msg}", file=sys.stderr, flush=True)
+
+
+class Phases:
+    """Where a run's seconds go: `seconds` maps each phase, in the order
+    the run went through them, to how long it lasted."""
+
+    def __init__(self, start):
+        self.seconds = {}
+        self._last = start
+
+    def __call__(self, name):
+        """Close the phase `name` and return its seconds: the time since
+        the last one closed (the first: since `start`)."""
+        now = time.perf_counter()
+        self.seconds[name] = now - self._last
+        self._last = now
+        return self.seconds[name]
 
 
 def load_json(*parts):
@@ -90,10 +114,38 @@ def tree_arrays(model):
 
 
 def train_score(gbdt, n):
+    """The (K, n) train score, K = gbdt.num_class."""
     import jax
     import numpy as np
     score = jax.block_until_ready(gbdt.train_score_updater.score)
-    return np.asarray(score).reshape(-1)[:n]
+    return np.asarray(score).reshape(gbdt.num_class, -1)[:, :n]
+
+
+def failed_iterations(trees, attempted, k):
+    """Iterations that left fewer than their k trees, or a tree without
+    a split, in the class-major model list."""
+    done = len(trees) // k
+    return max(attempted - done, 0) + sum(
+        any(len(t["split_feature"]) == 0 for t in trees[i * k:(i + 1) * k])
+        for i in range(done))
+
+
+def compare(config, params, x, y, fields, trees, score):
+    """The numbers `correct` is decided from. A configuration that names
+    a `reference` is compared by references/<name>.py, which gets the
+    fields, the merged parameters the program got and the whole (K, n)
+    score; one that names none by reference.py, the binary reference,
+    with the configuration's own `params` and the one class's score."""
+    if "reference" in config:
+        return load_module("references", config["reference"]).compare(
+            x, y, fields, params, trees, score)
+    if len(score) != 1 or fields:
+        raise ValueError(
+            f"configuration {config['name']!r} grows {len(score)} tree(s) an iteration "
+            f"with fields {sorted(fields)}: reference.py is the unweighted "
+            "binary reference, name another under `reference`")
+    import reference
+    return reference.compare(x, y, config["params"], trees, score[0])
 
 
 def memory_stats(jax, chips, label):
@@ -147,7 +199,6 @@ def main(argv=None):
     clock = CompileClock()
     jax.monitoring.register_event_duration_secs_listener(clock.listen)
     import lightgbm_tpu as lgb
-    import reference
 
     data = dict(config["data"], **({"rows": args.rows} if args.rows else {}))
     n, f = data["rows"], data["features"]
@@ -157,25 +208,29 @@ def main(argv=None):
     # ------------------------------------------------------------- set-up
     mark(f"set-up: {cell['name']} seed {args.seed}, {n} x {f}, block {block}, "
          f"platform {dev[0].platform}")
-    x, y = make_data(data, args.seed)
-    mark("data generated")
-    t = time.perf_counter()
-    ds = lgb.Dataset(x, label=y, params=dict(params),
-                     free_raw_data=False).construct()
-    dataset_s = time.perf_counter() - t
+    phase = Phases(_T0)
+    phase("import")
+    x, y, fields = make_data(data, args.seed)
+    mark(f"data generated in {phase('data'):.1f}s"
+         + (f", fields {sorted(fields)}" if fields else ""))
+    ds = lgb.Dataset(x, label=y, params=dict(params), free_raw_data=False,
+                     **fields).construct()
+    dataset_s = phase("dataset")
     mark(f"dataset constructed in {dataset_s:.1f}s (binned on device: "
          f"{ds._core.binned_on_device})")
     booster = lgb.train(dict(params), ds, num_boost_round=block)
     gbdt = booster.gbdt
+    k = int(gbdt.num_class)       # trees an iteration, class-major in models
     # a later block's iteration numbers start above 0, which costs the
     # program two scalar dispatches (convert, add) that block 0 never
     # makes: warm them here, as set-up warms every shape the window uses
     jax.block_until_ready(jax.numpy.arange(block, 2 * block, dtype="int32"))
     first_score = train_score(gbdt, n).copy()
-    first_trees = [tree_arrays(m) for m in gbdt.models[:block]]
+    first_trees = [tree_arrays(m) for m in gbdt.models[:block * k]]
     setup_s = time.perf_counter() - _T0
     compile_s, compiles_before = clock.seconds, clock.backend_compiles
-    mark(f"first block done: set-up {setup_s:.1f}s, of it compile events "
+    mark(f"first block done in {phase('first block'):.1f}s ({k} tree(s) an "
+         f"iteration): set-up {setup_s:.1f}s, of it compile events "
          f"{compile_s:.1f}s; cache hit {gbdt.last_compile_cache_hit}")
     memory_stats(jax, cell["chips"], "after set-up")
 
@@ -203,18 +258,18 @@ def main(argv=None):
                  f"{time.perf_counter() - t_win:.1f}s")
         window_s = time.perf_counter() - t_win
     window_compiles = clock.backend_compiles - compiles_before
+    phase("window")
     mark(f"window closed: {iterations} iterations in {window_s:.2f}s, "
          f"{window_compiles} compilations inside")
     peak = memory_stats(jax, cell["chips"], "after window")
-    window_trees = [tree_arrays(m) for m in gbdt.models[block:]]
+    window_trees = [tree_arrays(m) for m in gbdt.models[block * k:]]
     attempted = block + iterations
-    failed = (max(attempted - len(gbdt.models), 0)
-              + sum(len(t["split_feature"]) == 0
-                    for t in first_trees + window_trees))
+    failed = failed_iterations(first_trees + window_trees, attempted, k)
     device = {"platform": dev[0].platform, "kind": dev[0].device_kind,
               "count": len(dev), "memory_peak_bytes": int(peak)}
     del booster, gbdt, ds
     gc.collect()
+    phase("trees + free")
 
     # ------------------------------------------------------------ metrics
     result = {}
@@ -225,8 +280,9 @@ def main(argv=None):
         if on_tpu and dev[0].device_kind not in peaks:
             raise KeyError(f"no peaks for device kind {dev[0].device_kind!r}")
         ctx = {"trace": None, "block_iterations": block,
+               "trees_per_iteration": k, "params": params,
                "block_wall_s": window_s if on_tpu else None,
-               "trees": window_trees[-block:], "rows": n, "features": f,
+               "trees": window_trees[-block * k:], "rows": n, "features": f,
                "max_bin": config["params"]["max_bin"],
                "peak": peaks.get(dev[0].device_kind),
                "memory_peak_bytes": peak if on_tpu else None,
@@ -236,12 +292,14 @@ def main(argv=None):
             ctx["trace"] = tracereduce.reduce(*tracereduce.load(trace_dir))
             device["busy_s"] = ctx["trace"]["busy_s"]
             device["window_s"] = ctx["trace"]["window_s"]
-            breakdown = {k: ctx["trace"][k] for k in ("device_ops", "idle_gaps")}
-            mark("trace reduced")
+            breakdown = {key: ctx["trace"][key]
+                         for key in ("device_ops", "idle_gaps")}
         for m in per_layer:
             value = read_metric(m["name"], ctx)
             if value is not None:
                 result[m["name"]] = {"value": float(value), "unit": m["unit"]}
+        mark(f"trace reduced, {len(result)} per-layer metrics read in "
+             f"{phase('trace reduction'):.1f}s")
     elif on_tpu:
         own = {"train_s_per_iter": window_s / max(iterations, 1),
                "setup_s": setup_s}
@@ -252,14 +310,16 @@ def main(argv=None):
              f"s an iteration here says nothing about the chip")
 
     # -------------------------------------------------------- correctness
-    numbers = reference.compare(x, y, config["params"], first_trees,
-                                first_score)
+    numbers = compare(config, params, x, y, fields, first_trees, first_score)
     numbers["window_compiles"] = float(window_compiles)
     numbers["failed"] = float(failed)
     correct, checks = check(numbers, cell["limits"])
-    mark(f"reference followed {len(first_trees)} trees")
-    for k, r in checks.items():
-        print(f"  check {k}: {r['value']:.6g} (limit {r['limit']:.6g})",
+    mark(f"reference followed {len(first_trees)} trees in "
+         f"{phase('reference'):.1f}s")
+    mark(f"run total {time.perf_counter() - _T0:.1f}s: " + ", ".join(
+        f"{name} {secs:.1f}" for name, secs in phase.seconds.items()))
+    for name, r in checks.items():
+        print(f"  check {name}: {r['value']:.6g} (limit {r['limit']:.6g})",
               file=sys.stderr)
     print(f"  correct: {correct}", file=sys.stderr, flush=True)
     line = {"correct": bool(correct), "attempted": attempted,
