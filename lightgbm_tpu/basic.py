@@ -155,7 +155,7 @@ class Dataset:
                    else _coerce_2d(self.data))
             self._core = loader.construct_from_matrix(
                 mat, label=self.label, reference=ref_core,
-                categorical_features=categorical)
+                categorical_features=categorical, group=self.group)
         if self.feature_name is not None:
             self._core.feature_names = list(self.feature_name)
         self._apply_fields()

@@ -1153,14 +1153,18 @@ class DatasetLoader:
 
     # --------------------------------------------------------- from matrix
     def construct_from_matrix(self, data, label=None, reference=None,
-                              categorical_features=()) -> CoreDataset:
+                              categorical_features=(),
+                              group=None) -> CoreDataset:
         """In-memory path (c_api.cpp LGBM_DatasetCreateFromMat:268-315).
         `data` may also be a column source (CscColumns): sparse inputs
-        bin column-by-column, never densified (c_api.cpp:317-427)."""
+        bin column-by-column, never densified (c_api.cpp:317-427).
+        `group` (per-query document counts) is known to the metadata
+        before binning, so the `dataset` span can say `queries`."""
         if is_column_source(data):
             meta = Metadata(data.n)
             if label is not None:
                 meta.set_label(label)
+            meta.set_query(group)
             if reference is not None:
                 return self._bin_with_mappers(data, reference, meta)
             categorical = set(int(c) for c in categorical_features)
@@ -1171,6 +1175,7 @@ class DatasetLoader:
         meta = Metadata(data.shape[0])
         if label is not None:
             meta.set_label(label)
+        meta.set_query(group)
         if reference is not None:
             return self._bin_with_mappers(data, reference, meta)
         categorical = set(int(c) for c in categorical_features)
@@ -1296,8 +1301,9 @@ class DatasetLoader:
         src = feats if is_column_source(feats) else DenseColumns(feats)
         # a dataset precedes any Booster: its spans go to the process
         # tracer (telemetry/trace.py), under `dataset/...`
+        tags = {"queries": meta.num_queries} if meta.num_queries else {}
         with PROCESS_TRACER.span("dataset", rows=src.n,
-                                 features=src.num_total):
+                                 features=src.num_total, **tags):
             return self._construct_spanned(src, names, ignore, categorical,
                                            meta)
 

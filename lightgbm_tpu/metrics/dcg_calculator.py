@@ -7,12 +7,13 @@ Discount LUT 1/log2(2+i) for positions up to 10000; label gains 2^i - 1.
 import numpy as np
 
 K_MAX_POSITION = 10000
+DISCOUNT = 1.0 / np.log2(2.0 + np.arange(K_MAX_POSITION, dtype=np.float64))
 
 
 class DCGCalculator:
     def __init__(self, label_gain):
         self.label_gain = np.asarray(label_gain, dtype=np.float64)
-        self.discount = 1.0 / np.log2(2.0 + np.arange(K_MAX_POSITION, dtype=np.float64))
+        self.discount = DISCOUNT
 
     def cal_dcg_at_k(self, k, labels, scores):
         """DCG@k of `scores` ranking against relevance `labels`."""

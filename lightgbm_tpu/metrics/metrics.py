@@ -174,16 +174,18 @@ class NDCGMetric(Metric):
         self.query_boundaries = np.asarray(metadata.query_boundaries)
         self.num_queries = len(self.query_boundaries) - 1
         self.query_weights = metadata.query_weights
-        from ..objectives.rank_device import PaddedQueryLayout
-        self.layout = PaddedQueryLayout(self.query_boundaries, num_data)
+        from ..objectives.rank_device import BucketedQueryLayout
+        self.layout = BucketedQueryLayout(self.query_boundaries, num_data)
 
     def eval(self, score):
-        """Vectorized padded-query NDCG (one argsort for all queries)
-        instead of the reference's per-query loop (rank_metric.hpp)."""
-        from ..objectives.rank_device import ndcg_eval_padded
+        """Vectorized NDCG over the length-bucketed query layout (one
+        argsort a rung) instead of the reference's per-query loop
+        (rank_metric.hpp)."""
+        from ..objectives.rank_device import ndcg_eval_bucketed
         s = np.asarray(score, dtype=np.float64)[:self.num_data]
-        return ndcg_eval_padded(self.layout, self.label, self.dcg.label_gain,
-                                self.eval_at, s, self.query_weights)
+        return ndcg_eval_bucketed(self.layout, self.label,
+                                  self.dcg.label_gain, self.eval_at, s,
+                                  self.query_weights)
 
 
 def create_metric(name, config):

@@ -818,6 +818,7 @@ class GBDT:
             with self.tracer.phase("gradients"):
                 gradients, hessians = self.objective.get_gradients(
                     self._score_for_boosting())
+            self._note_rank_pairs(1)
         else:
             gradients = np.asarray(gradients, dtype=np.float32).reshape(
                 self.num_class, self.num_data)
@@ -1005,13 +1006,27 @@ class GBDT:
                 and (cfg.metric_freq <= 0 or not self.training_metrics
                      or ignore_train_metrics)
                 and self.early_stopping_round <= 0
-                and getattr(self.objective, "_grad", None) is not None
+                and getattr(self.objective, "_grad_pure", None) is not None
                 # linear leaves refit on host AFTER each structure, and
                 # the refit changes the residuals the next iteration
                 # sees — the scan cannot bake that in. train_many falls
                 # back to the per-iteration loop transparently.
                 and not bool(getattr(cfg, "linear_tree", False))
                 and type(self.tree_learner).__name__ == "SerialTreeLearner")
+
+    def _note_rank_pairs(self, iterations):
+        """A ranking objective's pairwise work, `iterations` times: the
+        registry counters `rank_pairs` (ordered pairs of two documents
+        of one query) and `rank_pair_slots` (slots of the pair tensors
+        that evaluate them, objectives/rank_device.py), and their ratio
+        as the gauge `rank_pair_fill` in /trainz."""
+        layout = getattr(self.objective, "layout", None)
+        if layout is not None and iterations > 0:
+            self.metrics.inc("rank_pairs", layout.pairs * iterations)
+            self.metrics.inc("rank_pair_slots",
+                             layout.pair_slots * iterations)
+            self.metrics.set("rank_pair_fill",
+                             layout.pairs / max(layout.pair_slots, 1))
 
     def _note_partition_engine(self):
         """Which engine the partitioned builder's partition step compiles
@@ -1043,18 +1058,17 @@ class GBDT:
         # entries and recompile. With the operands as arguments the
         # program bytes depend only on shapes/dtypes — one lowered
         # executable per (shape bucket, config) per machine.
-        grad_pure = getattr(self.objective, "_grad_pure", None)
+        # (every fused-eligible objective has the pure form,
+        # _fused_eligible)
+        grad_pure = self.objective._grad_pure
         data = {
             "bins": learner._bins,
             "nbpf": learner._num_bin_pf,
             "iscat": learner._is_cat,
             "inbag": jnp.concatenate([jnp.ones(n, jnp.float32),
                                       jnp.zeros(pad, jnp.float32)]),
+            "gops": self.objective._grad_ops,
         }
-        if grad_pure is not None:
-            data["gops"] = self.objective._grad_ops
-        else:
-            grad_fn = self.objective._grad  # closure fallback
 
         # the fused program embeds the learner's builder: resolve THIS
         # learner's hist_mode for the trace (a sibling Booster may have
@@ -1081,10 +1095,7 @@ class GBDT:
                 # device scopes (telemetry/trace.py DEVICE_SCOPES): the
                 # builder writes its own between these two
                 with scope("gradients"):
-                    if grad_pure is not None:
-                        g, h = grad_pure(d["gops"], score)
-                    else:
-                        g, h = grad_fn(score)
+                    g, h = grad_pure(d["gops"], score)
                     gp = jnp.pad(g, ((0, 0), (0, pad)))
                     hp = jnp.pad(h, ((0, 0), (0, pad)))
                     # per-iteration in-bag weights (GOSS); pad rows stay
@@ -1262,6 +1273,7 @@ class GBDT:
                             slice_at(t_eff, k),
                             shrink=self.shrinkage_rate))
         self.iter += t_eff
+        self._note_rank_pairs(t_eff)
         self.metrics.inc("fused_blocks")
         self.metrics.inc("tree_build_dispatches",
                          len(self.models) - n_before)

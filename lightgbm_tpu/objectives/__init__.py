@@ -6,10 +6,8 @@ src/objective/objective_function.cpp:9-20.
 
 Scores and gradients are (num_class, N) device arrays; the elementwise
 objectives are jitted jnp code. Lambdarank's per-query pairwise pass runs
-as padded-batch device code would in a later revision; v1 computes it on
-host with fully vectorized numpy per query (the reference is also a
-host-side O(n_q^2) loop; this is not the training bottleneck at the
-reference's query sizes).
+on the device over a length-bucketed query layout (rank_device.py); the
+float64 host loop stays as its accuracy reference. docs/Objectives.md.
 """
 
 from .objectives import (

@@ -166,11 +166,13 @@ class MulticlassLogloss(ObjectiveFunction):
 class LambdarankNDCG(ObjectiveFunction):
     """LambdaRank with NDCG weighting (rank_objective.hpp:19-227).
 
-    Gradients run ON DEVICE via the padded-query pairwise kernel
-    (rank_device.py) — `self._grad` is the jitted function, which also
-    makes lambdarank eligible for the fused multi-iteration trainer.
-    The float64 host path below is kept as the accuracy reference
-    (tests pin the two against each other).
+    Gradients run ON DEVICE over the length-bucketed query layout
+    (rank_device.py), installed as the pure `_grad_pure(ops, score)`
+    with the layout's device arrays as `_grad_ops`, like the other
+    objectives: the fused multi-iteration trainer takes them as runtime
+    arguments. The float64 host path below is kept as the accuracy
+    reference (tests pin the two against each other;
+    docs/Objectives.md has the tolerance).
     """
 
     name = "lambdarank"
@@ -192,16 +194,18 @@ class LambdarankNDCG(ObjectiveFunction):
             Log.fatal("Lambdarank tasks require query information")
         self.query_boundaries = np.asarray(metadata.query_boundaries)
         self.num_queries = len(self.query_boundaries) - 1
-        self.inverse_max_dcgs = np.zeros(self.num_queries)
-        for q in range(self.num_queries):
-            lo, hi = self.query_boundaries[q], self.query_boundaries[q + 1]
-            maxdcg = self.dcg.cal_maxdcg_at_k(self.optimize_pos_at, self.label[lo:hi])
-            self.inverse_max_dcgs[q] = 1.0 / maxdcg if maxdcg > 0 else 0.0
-        from .rank_device import PaddedQueryLayout, make_lambdarank_gradfn
-        self.layout = PaddedQueryLayout(self.query_boundaries, num_data)
-        self._grad = make_lambdarank_gradfn(
-            self.layout, self.label, self.label_gain, self.sigmoid,
-            self.optimize_pos_at, self.weights)
+        from ..telemetry.trace import PROCESS_TRACER
+        from .rank_device import (BucketedQueryLayout, lambdarank_grad,
+                                  lambdarank_ops)
+        with PROCESS_TRACER.span("rank_layout", queries=self.num_queries,
+                                 rows=num_data):
+            self.layout = BucketedQueryLayout(self.query_boundaries, num_data)
+            ops, self.inverse_max_dcgs = lambdarank_ops(
+                self.layout, self.label, self.label_gain,
+                self.optimize_pos_at)
+        sig = self.sigmoid
+        self._install_grad(
+            lambda ops, score: lambdarank_grad(ops, score, sig), ops)
 
     def get_gradients(self, score):
         return self._grad(jnp.asarray(score, dtype=jnp.float32).reshape(1, -1))

@@ -122,6 +122,14 @@ def window_start(c_first, bk, n_chunks):
     return jnp.clip(c_first, 0, n_chunks - bk) * HIST_CHUNK
 
 
+# Feature count from which the kernel body loops over word rows instead
+# of unrolling every feature. The unrolled body is what compiles
+# fastest to run at a few dozen features (28: 3 s a bucket branch); its
+# compile time grows with the feature count (136: 18 s a branch, eleven
+# branches a program, PERF.md PR 29), the rolled body's does not.
+ROLL_FEATURES = 64
+
+
 def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad):
     """One grid step = one HIST_CHUNK block of the sliced segment."""
     step = pl.program_id(0)
@@ -138,10 +146,26 @@ def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad):
     mask = (pos >= lohi_ref[0]) & (pos < lohi_ref[1])             # (C, 1)
     ghc_m = jnp.where(mask, ghc_ref[...], 0)                      # (C, 9)
     b_iota = jax.lax.broadcasted_iota(jnp.int32, (b_pad, c), 0)
-    for i in range(f):
-        word = words_ref[i >> 2, :]
-        bins_f = (word >> ((i & 3) * 8)) & 0xFF
-        out_ref[i, :, :] += onehot_dot(bins_f[None, :], b_iota, ghc_m)
+    if f < ROLL_FEATURES:
+        for i in range(f):
+            word = words_ref[i >> 2, :]
+            bins_f = (word >> ((i & 3) * 8)) & 0xFF
+            out_ref[i, :, :] += onehot_dot(bins_f[None, :], b_iota, ghc_m)
+        return
+
+    def word_row(wi, byte_lanes):
+        word = words_ref[pl.ds(wi, 1), :]                         # (1, C)
+        for b in range(byte_lanes):     # the four byte lanes stay static
+            i = wi * 4 + b
+            out_ref[i] += onehot_dot((word >> (b * 8)) & 0xFF, b_iota, ghc_m)
+
+    def body(wi, _):
+        word_row(wi, 4)
+        return 0
+
+    jax.lax.fori_loop(0, f // 4, body, 0)
+    if f % 4:
+        word_row(f // 4, f % 4)
 
 
 def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,

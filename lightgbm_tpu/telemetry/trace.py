@@ -61,6 +61,8 @@ RECENT_SPANS = 256
 DEVICE_SCOPES = ("gradients", "partition", "hist", "hist_reduce",
                  "split_scan", "tree_state", "score_update")
 DEVICE_SUBSCOPES = {
+    # the pairwise pass of a ranking objective (objectives/rank_device.py)
+    "gradients": ("rank_gather", "rank_sort", "rank_pairs", "rank_return"),
     "partition": ("window_in", "decide", "destinations", "invert", "move",
                   "write_back"),
     "hist": ("window", "seg_hist", "fold"),

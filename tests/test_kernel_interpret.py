@@ -222,3 +222,79 @@ def test_partition_kernel_interpret_matches_xla(case):
         np.testing.assert_array_equal(
             np.asarray(g).view(np.int32), np.asarray(w).view(np.int32),
             err_msg=f"{case}: {name}")
+
+
+# ----------------------- both kernels at 136 columns, 34 words a row (PR 29)
+def test_segment_kernel_interpret_136_columns():
+    """MSLR-WEB30K's geometry: 136 features in 34 packed words, 63 bins
+    in one padded 128-bin tile. From ROLL_FEATURES on the kernel body is
+    a loop over word rows with the four byte lanes static inside; the
+    sums are the unrolled body's. 135 leaves a word row partly filled."""
+    from lightgbm_tpu.ops.ordered_hist import ROLL_FEATURES
+    rng = np.random.RandomState(8)
+    n, b = 2 * HIST_CHUNK, 63
+    for f in (136, 135):
+        assert f >= ROLL_FEATURES
+        bins = rng.randint(0, b, size=(f, n), dtype=np.uint8)
+        words = jnp.asarray(pack_feature_words(bins))
+        stats = rng.randn(3, n).astype(np.float32)
+        stats[2] = 1.0                      # every row in the bag
+        ghc_t = jnp.asarray(stats)
+        begin, cnt = jnp.int32(HIST_CHUNK - 9), jnp.int32(HIST_CHUNK // 2)
+        got = segment_histograms(words, ghc_t, begin, cnt, b, f=f,
+                                 interpret_backend="tpu", interpret=True)
+        want = segment_histograms(words, ghc_t, begin, cnt, b, f=f,
+                                  interpret_backend="cpu")
+        assert got.shape == (f, b, 3)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-4)
+        # the count column is exact
+        np.testing.assert_array_equal(np.asarray(got)[..., 2].sum(axis=1),
+                                      np.full(f, HIST_CHUNK // 2))
+
+
+@pytest.mark.parametrize("case", ["begin_not_tile_aligned",
+                                  "across_a_chunk_edge", "whole_array"])
+def test_partition_kernel_interpret_40_word_rows(case):
+    """`partition_rows` at wp = 40 (34 words of 136 columns + 5 of
+    padding + perm: five tiles of rows a chunk, 176 byte planes a tile),
+    bit-equal to the XLA formulation as at wp = 8."""
+    from lightgbm_tpu.models.partitioned import _partition_segment_rows
+    from lightgbm_tpu.ops.ordered_hist import unpack_feature
+    from lightgbm_tpu.ops.partition import (apply_partition,
+                                            invert_permutation, pack_rows,
+                                            split_destinations, unpack_rows)
+    rng = np.random.RandomState(9)
+    n, f = _N, 136
+    bins = rng.randint(0, 63, size=(f, n), dtype=np.uint8)
+    words = pack_feature_words(bins)
+    words[33] = rng.randint(-2**31, 2**31 - 1, size=n,
+                            dtype=np.int64).astype(np.int32)
+    words, ghc, perm = (jnp.asarray(words),
+                        jnp.asarray(rng.randn(3, n).astype(np.float32)),
+                        jnp.asarray(rng.permutation(n).astype(np.int32)))
+    seg_b, seg_c = PARTITION_CASES[case][:2]
+    feat, thr = 77, 30
+    b, c = jnp.int32(seg_b), jnp.int32(seg_c)
+
+    @jax.jit
+    def kernel(b, c):
+        packed = pack_rows(words, ghc, perm)
+        assert packed[0].shape == (40, n)
+        rows_i, rows_f, n_left = _partition_segment_rows(
+            *packed, b, c, jnp.int32(feat), jnp.int32(thr),
+            jnp.asarray(False), unpack_feature, interpret=True)
+        return unpack_rows(rows_i, rows_f, words.shape[0]) + (n_left,)
+
+    @jax.jit
+    def formulation(b, c):
+        go_left = unpack_feature(words, jnp.int32(feat)) <= thr
+        dest, n_left = split_destinations(go_left, b, c)
+        return apply_partition(invert_permutation(dest), words, ghc,
+                               perm) + (n_left,)
+
+    for name, g, w in zip(("words", "ghc", "perm", "n_left"),
+                          kernel(b, c), formulation(b, c)):
+        np.testing.assert_array_equal(
+            np.asarray(g).view(np.int32), np.asarray(w).view(np.int32),
+            err_msg=f"{case}: {name}")
