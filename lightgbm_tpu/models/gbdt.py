@@ -843,7 +843,7 @@ class GBDT:
         multi_host = getattr(self.tree_learner, "n_proc", 1) > 1
         linear = bool(getattr(self.config, "linear_tree", False))
         new_leaves = 0
-        self._note_partition_engine()
+        self._note_builder_kernels()
         for k in range(self.num_class):
             with self.tracer.phase("build"):
                 out = self.tree_learner.train_device(
@@ -1028,14 +1028,20 @@ class GBDT:
             self.metrics.set("rank_pair_fill",
                              layout.pairs / max(layout.pair_slots, 1))
 
-    def _note_partition_engine(self):
-        """Which engine the partitioned builder's partition step compiles
-        to (ops/partition.py partition_engine: `pallas` on a TPU, `xla`
-        off it), as the registry gauge `partition_engine` beside
-        `tree_build_dispatches` in /trainz."""
+    def _note_builder_kernels(self):
+        """What the partitioned builder's kernels compile to, as registry
+        gauges beside `tree_build_dispatches` in /trainz:
+        `partition_engine` (ops/partition.py: `pallas` on a TPU, `xla`
+        off it), and the histogram kernel's one-hot operand
+        (ops/ordered_hist.py onehot_extent): `seg_hist_onehot_rows` a
+        feature and `seg_hist_features_per_dot`."""
         if getattr(self.tree_learner, "_use_partitioned", False):
+            from ..ops.ordered_hist import onehot_extent
             from ..ops.partition import partition_engine
             self.metrics.set("partition_engine", partition_engine())
+            rows, features = onehot_extent(self.tree_learner.max_bin)
+            self.metrics.set("seg_hist_onehot_rows", rows)
+            self.metrics.set("seg_hist_features_per_dot", features)
 
     def _get_fused_fn(self, num_iters):
         if not hasattr(self, "_fused_cache"):
@@ -1074,7 +1080,7 @@ class GBDT:
         # learner's hist_mode for the trace (a sibling Booster may have
         # moved the process global since learner init)
         learner.apply_hist_mode()
-        self._note_partition_engine()
+        self._note_builder_kernels()
         num_class = self.num_class
         # both the partitioned and the gather-compacted builders dispatch
         # histogram work through a bucketed lax.switch: vmapping them

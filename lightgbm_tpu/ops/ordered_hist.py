@@ -21,6 +21,13 @@ Bins are packed 4 features per int32 word (W = ceil(F/4), feature f in
 byte f%4 of word f//4): one permutation gather moves 4 features at
 once, and the kernel unpacks with a shift+mask (2 VPU ops per feature
 per chunk, far below the B x C one-hot compares).
+
+The kernel's time is its one-hot elements (rows x features x padded
+bins) through the MXU, so the padded bin extent follows the static
+`num_bins_total` (`onehot_extent`): 64 rows a feature up to 64 bins,
+multiples of 128 above. At 64 rows the four byte lanes of a packed word
+row are stacked into one 256-row one-hot and take one contraction, the
+operand shape a 255-bin feature has.
 """
 
 import functools
@@ -130,8 +137,23 @@ def window_start(c_first, bk, n_chunks):
 ROLL_FEATURES = 64
 
 
-def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad):
-    """One grid step = one HIST_CHUNK block of the sliced segment."""
+def onehot_extent(num_bins_total):
+    """(rows a feature's one-hot spans, features a contraction takes)
+    for a static histogram width: 64 rows and the four features of a
+    packed word row up to 64 bins, else whole 128-row tiles and one
+    feature. 64 is a whole number of bfloat16 (16) and float32 (8)
+    sublane tiles, so the stacked lanes need no relayout."""
+    if num_bins_total <= 64:
+        return 64, 4
+    return -(-num_bins_total // 128) * 128, 1
+
+
+def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad,
+                     lanes):
+    """One grid step = one HIST_CHUNK block of the sliced segment.
+    `out_ref` is (f, b_pad, 9) at `lanes` = 1 and (ceil(f / 4),
+    4 * b_pad, 9) at `lanes` = 4: rows [b_pad * k, b_pad * (k + 1)) of
+    word row w are feature 4 w + k."""
     step = pl.program_id(0)
 
     @pl.when(step == 0)
@@ -146,7 +168,7 @@ def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad):
     mask = (pos >= lohi_ref[0]) & (pos < lohi_ref[1])             # (C, 1)
     ghc_m = jnp.where(mask, ghc_ref[...], 0)                      # (C, 9)
     b_iota = jax.lax.broadcasted_iota(jnp.int32, (b_pad, c), 0)
-    if f < ROLL_FEATURES:
+    if lanes == 1 and f < ROLL_FEATURES:
         for i in range(f):
             word = words_ref[i >> 2, :]
             bins_f = (word >> ((i & 3) * 8)) & 0xFF
@@ -154,16 +176,30 @@ def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad):
         return
 
     def word_row(wi, byte_lanes):
+        # a partly filled last word keeps its unused lanes out: the
+        # packed padding bytes are 0 and would count in bin 0
         word = words_ref[pl.ds(wi, 1), :]                         # (1, C)
-        for b in range(byte_lanes):     # the four byte lanes stay static
-            i = wi * 4 + b
-            out_ref[i] += onehot_dot((word >> (b * 8)) & 0xFF, b_iota, ghc_m)
+        if lanes == 1:
+            for b in range(byte_lanes):  # the four byte lanes stay static
+                out_ref[wi * 4 + b] += onehot_dot((word >> (b * 8)) & 0xFF,
+                                                  b_iota, ghc_m)
+            return
+        onehot = jnp.concatenate(
+            [(((word >> (b * 8)) & 0xFF) == b_iota).astype(jnp.bfloat16)
+             for b in range(byte_lanes)], axis=0)
+        out_ref[wi, :byte_lanes * b_pad, :] += jax.lax.dot_general(
+            onehot, ghc_m, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    def body(wi, _):
-        word_row(wi, 4)
-        return 0
+    if f < ROLL_FEATURES:
+        for wi in range(f // 4):
+            word_row(wi, 4)
+    else:
+        def body(wi, _):
+            word_row(wi, 4)
+            return 0
 
-    jax.lax.fori_loop(0, f // 4, body, 0)
+        jax.lax.fori_loop(0, f // 4, body, 0)
     if f % 4:
         word_row(f // 4, f % 4)
 
@@ -174,8 +210,11 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
     runs the kernel body in pallas interpret mode (CPU) — used by tests
     to validate kernel semantics without TPU hardware."""
     w = words_sl.shape[0]
-    b_pad = max(((num_bins_total + 127) // 128) * 128, 128)
-    kernel = functools.partial(_seg_hist_kernel, f=f, b_pad=b_pad)
+    b_pad, lanes = onehot_extent(num_bins_total)
+    kernel = functools.partial(_seg_hist_kernel, f=f, b_pad=b_pad,
+                               lanes=lanes)
+    acc_shape = ((-(-f // 4), 4 * b_pad, STAT_TERMS) if lanes == 4
+                 else (f, b_pad, STAT_TERMS))
     with scope("window"):
         lohi = jnp.stack([lo, hi]).astype(jnp.int32)
         stats = split_stats(ghc_sl)
@@ -191,12 +230,13 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
             pl.BlockSpec((HIST_CHUNK, STAT_TERMS), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((f, b_pad, STAT_TERMS), lambda i: (0, 0, 0),
+        out_specs=pl.BlockSpec(acc_shape, lambda i: (0, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((f, b_pad, STAT_TERMS), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
     )(lohi, words_sl, stats)
     with scope("fold"):
-        return fold_stats(out[:, :num_bins_total, :])
+        out = out.reshape(-1, b_pad, STAT_TERMS)[:f, :num_bins_total, :]
+        return fold_stats(out)
 
 
 def _seg_hist_xla(words_sl, ghc_sl, lo, hi, f, num_bins_total):

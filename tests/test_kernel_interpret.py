@@ -253,6 +253,136 @@ def test_segment_kernel_interpret_136_columns():
                                       np.full(f, HIST_CHUNK // 2))
 
 
+# ------------- the one-hot spans the bins a configuration has (PR 30)
+def _seg_hist_128_rows(words_sl, ghc_sl, lo, hi, f, num_bins_total,
+                       n_blocks):
+    """The segment kernel as it was before PR 30, kept here as the
+    yardstick: every feature's one-hot padded to whole 128-row tiles,
+    one contraction a feature (unrolled under ROLL_FEATURES, else a
+    loop over word rows)."""
+    import functools
+    from jax.experimental import pallas as pl
+    from lightgbm_tpu.ops.ordered_hist import ROLL_FEATURES
+    from lightgbm_tpu.ops.pallas_hist import (STAT_TERMS, fold_stats,
+                                              onehot_dot, split_stats)
+    b_pad = max(-(-num_bins_total // 128) * 128, 128)
+
+    def kernel(lohi_ref, words_ref, ghc_ref, out_ref):
+        step = pl.program_id(0)
+
+        @pl.when(step == 0)
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        c = words_ref.shape[1]
+        pos = step * c + jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+        mask = (pos >= lohi_ref[0]) & (pos < lohi_ref[1])
+        ghc_m = jnp.where(mask, ghc_ref[...], 0)
+        b_iota = jax.lax.broadcasted_iota(jnp.int32, (b_pad, c), 0)
+        if f < ROLL_FEATURES:
+            for i in range(f):
+                bins_f = (words_ref[i >> 2, :] >> ((i & 3) * 8)) & 0xFF
+                out_ref[i, :, :] += onehot_dot(bins_f[None, :], b_iota,
+                                               ghc_m)
+            return
+
+        def word_row(wi, byte_lanes):
+            word = words_ref[pl.ds(wi, 1), :]
+            for k in range(byte_lanes):
+                out_ref[wi * 4 + k] += onehot_dot((word >> (k * 8)) & 0xFF,
+                                                  b_iota, ghc_m)
+
+        def body(wi, _):
+            word_row(wi, 4)
+            return 0
+
+        jax.lax.fori_loop(0, f // 4, body, 0)
+        if f % 4:
+            word_row(f // 4, f % 4)
+
+    w = words_sl.shape[0]
+    out = pl.pallas_call(
+        kernel, interpret=True, grid=(n_blocks,),
+        in_specs=[pl.BlockSpec((2,), lambda i: (0,)),
+                  pl.BlockSpec((w, HIST_CHUNK), lambda i: (0, i)),
+                  pl.BlockSpec((HIST_CHUNK, STAT_TERMS), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((f, b_pad, STAT_TERMS), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((f, b_pad, STAT_TERMS), jnp.float32),
+    )(jnp.stack([lo, hi]).astype(jnp.int32), words_sl, split_stats(ghc_sl))
+    return fold_stats(out[:, :num_bins_total, :])
+
+
+def _seg_hist_case(columns, f, b, seed):
+    rng = np.random.RandomState(seed)
+    n = 2 * HIST_CHUNK
+    bins = rng.randint(0, b, size=(columns, n), dtype=np.uint8)
+    words = jnp.asarray(pack_feature_words(bins))
+    stats = rng.randn(n, 3).astype(np.float32)
+    stats[:, 2] = 1.0                       # every row in the bag
+    lo, hi = jnp.int32(HIST_CHUNK - 9), jnp.int32(HIST_CHUNK + 2039)
+    return (words, jnp.asarray(stats), lo, hi, f, b, 2), bins
+
+
+def _kernel_out_shape(args):
+    from lightgbm_tpu.ops.ordered_hist import _seg_hist_tpu
+    jaxpr = jax.make_jaxpr(
+        lambda w, g, lo, hi: _seg_hist_tpu(w, g, lo, hi, *args[4:],
+                                           interpret=True))(*args[:4])
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    return call.outvars[0].aval.shape
+
+
+@pytest.mark.parametrize("b,rows_a_feature,features_a_dot", [
+    (63, 64, 4), (64, 64, 4), (65, 128, 1), (255, 256, 1)])
+def test_segment_kernel_onehot_extent(b, rows_a_feature, features_a_dot):
+    """Up to 64 bins a feature's one-hot is 64 rows and a packed word
+    row's four features share one 256-row contraction; above, whole
+    128-row tiles a feature, as before. Read off the kernel call's
+    output, which is the accumulator the one-hot's rows land in."""
+    from lightgbm_tpu.ops.ordered_hist import (_seg_hist_tpu,
+                                               _seg_hist_xla, onehot_extent)
+    args, bins = _seg_hist_case(8, 8, b, seed=b)
+    assert onehot_extent(b) == (rows_a_feature, features_a_dot)
+    want_shape = ((2, 256, 9) if features_a_dot == 4
+                  else (8, rows_a_feature, 9))
+    assert _kernel_out_shape(args) == want_shape
+    got = np.asarray(_seg_hist_tpu(*args, interpret=True))
+    assert got.shape == (8, b, 3)
+    np.testing.assert_array_equal(got, np.asarray(_seg_hist_128_rows(*args)))
+    np.testing.assert_allclose(got, np.asarray(_seg_hist_xla(*args[:6])),
+                               rtol=1e-5, atol=1e-4)
+    # the highest bin is counted: no row falls off the one-hot's end
+    in_range = bins[0, HIST_CHUNK - 9:HIST_CHUNK + 2039]
+    assert got[0, b - 1, 2] == np.sum(in_range == b - 1) > 0
+
+
+@pytest.mark.parametrize("columns,f", [(28, 28), (136, 136), (135, 135),
+                                       (6, 8), (32, 28)])
+def test_segment_kernel_63_bins_same_sums(columns, f):
+    """At 63 bins (cells `higgs10m-b63-l255.train`, 28 columns, the
+    unrolled body; `mslr-web30k-b63-l255.train`, 136, the rolled one;
+    135 leaves the last word row partly filled; 6 columns read as f = 8
+    count their two padding bytes in bin 0, as they always did; 28 of
+    32 columns are the builder's word rows padded to a tile, which the
+    accumulator leaves out) a histogram cell is the contraction of its
+    own one-hot row with the same nine terms, whatever other rows share
+    the operand: the sums are the 128-row body's bit for bit."""
+    from lightgbm_tpu.ops.ordered_hist import (ROLL_FEATURES, _seg_hist_tpu,
+                                               _seg_hist_xla)
+    assert (f >= ROLL_FEATURES) == (columns >= 135)
+    args, _ = _seg_hist_case(columns, f, 63, seed=columns)
+    assert _kernel_out_shape(args) == ((f + 3) // 4, 256, 9)
+    got = np.asarray(_seg_hist_tpu(*args, interpret=True))
+    assert got.shape == (f, 63, 3)
+    np.testing.assert_array_equal(got, np.asarray(_seg_hist_128_rows(*args)))
+    np.testing.assert_allclose(got, np.asarray(_seg_hist_xla(*args[:6])),
+                               rtol=1e-5, atol=1e-4)
+    # the count column is exact
+    np.testing.assert_array_equal(got[..., 2].sum(axis=1),
+                                  np.full(f, 2048))
+
+
 @pytest.mark.parametrize("case", ["begin_not_tile_aligned",
                                   "across_a_chunk_edge", "whole_array"])
 def test_partition_kernel_interpret_40_word_rows(case):

@@ -398,6 +398,26 @@ def test_fused_block_child_spans(trained):
     assert gauges["partition_engine"] == "xla"
 
 
+@pytest.mark.parametrize("max_bin,rows,features", [(63, 64, 4),
+                                                   (255, 256, 1)])
+def test_seg_hist_onehot_gauges(max_bin, rows, features):
+    """The histogram kernel's one-hot operand, in /trainz: rows a
+    feature and features a contraction, from the bins the booster's
+    train set has (64 / 4 up to 64 bins, whole 128-row tiles / 1 above)."""
+    rng = np.random.RandomState(11)
+    x = rng.randn(2000, 4).astype(np.float32)
+    y = (x[:, 0] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 2, "max_bin": max_bin,
+              "partitioned_build": "true", "verbose": -1, "metric": "none"}
+    booster = lgb.Booster(params=params,
+                          train_set=lgb.Dataset(x, label=y, params=params))
+    assert booster.gbdt.tree_learner.max_bin == max_bin
+    booster.gbdt.train_many(1)
+    gauges = booster.gbdt.metrics.snapshot()["gauges"]
+    assert gauges["seg_hist_onehot_rows"] == rows
+    assert gauges["seg_hist_features_per_dot"] == features
+
+
 def test_process_tracer_holds_dataset_spans(trained):
     paths = [s["path"] for s in trained["process_spans"]]
     first = paths[:paths.index("dataset") + 1]     # the train set's
@@ -533,3 +553,30 @@ def test_scopes_survive_the_tpu_compiler(one_chip):
             # copied, transposed or not, for a window to be cut out
             assert not re.search(r"= [sf]32\[[84],16384\]\S* copy\(", ln), \
                 ln[:200]
+
+
+@pytest.mark.parametrize("f,w,b,result", [
+    (28, 8, 63, "f32[7,256,9]"), (136, 40, 63, "f32[34,256,9]"),
+    (135, 40, 63, "f32[34,256,9]"), (28, 8, 255, "f32[28,256,9]")])
+def test_seg_hist_compiles_at_the_cells_widths(one_chip, f, w, b, result):
+    """The chip's compiler takes the histogram kernel at the columns,
+    word rows and bins of the benchmark's cells (interpret mode does not
+    see tiling or VMEM): one custom call named `seg_hist` whose result is
+    the accumulator, a packed word row's four 64-row one-hots stacked at
+    63 bins (the unrolled body at 28 columns, the rolled one at 136, a
+    partly filled last word row at 135), a feature's 256 rows at 255."""
+    from lightgbm_tpu.ops.ordered_hist import _seg_hist_tpu
+    from lightgbm_tpu.ops.pallas_hist import HIST_CHUNK
+    n_blocks = 8
+    n = n_blocks * HIST_CHUNK
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in [((w, n), jnp.int32), ((n, 3), jnp.float32),
+                         ((), jnp.int32), ((), jnp.int32)]]
+    with fresh_compiles():
+        text = jax.jit(
+            lambda words, ghc, lo, hi: _seg_hist_tpu(
+                words, ghc, lo, hi, f, b, n_blocks)
+        ).lower(*args).compile().as_text()
+    (kernel,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert re.match(rf"\s*(ROOT )?%seg_hist[.\d]* = {re.escape(result)}",
+                    kernel), kernel[:200]
