@@ -59,15 +59,7 @@ import time
 
 import numpy as np
 
-# 1-core runners: give the XLA CPU client a second virtual device so
-# the histogram engine's host callbacks always have a worker thread —
-# without it the fused/compacted bincount programs deadlock (see
-# lightgbm_tpu/utils/hostenv.py). Must run before the first jax use;
-# child processes re-run this at their own startup.
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from lightgbm_tpu.utils.hostenv import ensure_callback_worker_devices
-
-ensure_callback_worker_devices()
 
 # Reference CLI training-loop time at 1M x 28 x 100 iters x 63 leaves,
 # measured on an earlier container (single core, -O3, training AUC
@@ -493,17 +485,13 @@ def phase_probe(booster):
                                           learner.row_chunk)
             return hi + lo
     else:
-        from lightgbm_tpu.ops.histogram import callbacks_disabled
         from lightgbm_tpu.ops.pallas_hist import masked_histograms
 
         def hist_fn():
-            # the masked builder traces callback-free (the exact
-            # serial==parallel engine); probe what actually runs
-            with callbacks_disabled():
-                hi, lo = masked_histograms(learner._bins, ghc_t,
-                                           jnp.zeros(n_pad, jnp.int32),
-                                           jnp.int32(0), b,
-                                           learner.row_chunk)
+            hi, lo = masked_histograms(learner._bins, ghc_t,
+                                       jnp.zeros(n_pad, jnp.int32),
+                                       jnp.int32(0), b,
+                                       learner.row_chunk)
             return hi + lo
 
     hist3 = jnp.ones((f_pad, b, 3), dtype=jnp.float32)

@@ -1,12 +1,6 @@
 """CLI entry: `python -m lightgbm_tpu task=train config=train.conf ...`
 (the reference's `lightgbm` binary, src/main.cpp)."""
 
-# before any jax use: 1-core runners need a second virtual host device
-# or embedded host callbacks can deadlock the CPU client (utils/hostenv)
-from .utils.hostenv import ensure_callback_worker_devices
-
-ensure_callback_worker_devices()
-
 from .application import main
 
 main()

@@ -7,10 +7,6 @@ turns the hand-maintained ones into machine-checked rules
 (docs/Static-Analysis.md has the catalogue with each rule's
 provenance):
 
-- ``callback-in-mesh``      host callbacks reachable from shard_map
-                            programs without ``callbacks_disabled()`` /
-                            ``meshed_trace_guard()`` (the XLA-CPU
-                            deadlock caveat, ops/histogram.py:154)
 - ``unguarded-collective``  blocking device syncs in parallel paths
                             outside ``collective_guard`` (watchdog /
                             straggler attribution goes blind otherwise)

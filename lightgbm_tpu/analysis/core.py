@@ -73,7 +73,7 @@ def parse_pragmas(source):
 # -------------------------------------------------------- parsed files
 
 def dotted_name(node):
-    """Best-effort dotted name of an expression: ``jax.pure_callback``,
+    """Best-effort dotted name of an expression: ``jax.lax.psum``,
     ``heartbeat.collective_guard``, ``name``; '' when not a name
     chain. Call nodes resolve through their func (``super().train()``
     -> ``super.train``)."""
@@ -120,8 +120,7 @@ def node_source(pf, node):
 # Guard context-manager names rules care about. A ``with`` whose item is
 # a call (or attribute) whose dotted name ENDS with one of these marks
 # its body as guarded by that name.
-GUARD_NAMES = ("collective_guard", "meshed_trace_guard",
-               "callbacks_disabled", "armed")
+GUARD_NAMES = ("collective_guard", "armed")
 
 
 class ParsedFile:

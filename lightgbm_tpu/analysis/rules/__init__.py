@@ -3,6 +3,6 @@
 drop a module here, import it below, ship fixtures — see
 docs/Static-Analysis.md "Adding a rule"."""
 
-from . import (atomic_writes, callback_mesh, collectives, config_doc,
+from . import (atomic_writes, collectives, config_doc,
                determinism, journal_schema, precision,
                prom_naming, trace_context, unbounded_io)  # noqa: F401

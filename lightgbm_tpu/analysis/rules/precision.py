@@ -3,8 +3,7 @@
 Provenance: the histogram engine's serial==parallel bit-parity
 contract (Mitchell & Frank-style deterministic building,
 arXiv:1806.11248) rests on chunked *f32* Kahan-pair arithmetic on
-device (ops/histogram.py) with *f64* accumulation only inside the
-host bincount callbacks, and the prediction/serving reference path
+device (ops/histogram.py), and the prediction/serving reference path
 reduces leaf values in host f64 (models/gbdt.py, serving). Three ways
 code has tried to blur that line:
 

@@ -456,19 +456,6 @@ class Config:
     # whenever the masked builder runs; "false" restores the full-scan
     # O(N)-per-split path
     hist_compaction: str = "auto"
-    # histogram kernel formulation (ops/histogram.py): "auto" = the
-    # Pallas streaming kernels on TPU, the f64 np.bincount host
-    # callback on CPU, the one-hot einsum elsewhere;
-    # "pallas"/"einsum"/"segment"/"bincount" force one formulation
-    # (einsum/segment/bincount on TPU disable the Pallas kernels — the
-    # supported escape hatch). Resolved once per learner init; the
-    # LIGHTGBM_TPU_HIST_MODE env var seeds the process default.
-    hist_mode: str = "auto"
-    # multi-leaf frontier histogram batching (ops/histogram.py
-    # frontier_histograms): "auto"/"true" = the root/bagging re-init
-    # pass and the cache-less builder's both-children pass run the
-    # one-pass multi-leaf primitive; "false" = per-leaf passes only
-    hist_frontier: str = "auto"
     # canonicalize padded row counts to a 3-bit-mantissa grid
     # (ops/ordered_hist.py canonical_row_chunks) so nearby dataset sizes
     # share lowered executables through the persistent compile cache
@@ -654,9 +641,6 @@ class Config:
         check(self.block_cache_blocks >= 0,
               "block_cache_blocks should be >= 0")
         check(self.prefetch_depth >= 1, "prefetch_depth should be >= 1")
-        check(str(self.hist_mode).lower() in
-              ("auto", "pallas", "einsum", "segment", "bincount"),
-              "hist_mode must be auto|pallas|einsum|segment|bincount")
         from .utils.guardrails import POLICIES
         check(self.nonfinite_guard in POLICIES,
               "nonfinite_guard must be one of " + "|".join(POLICIES))

@@ -43,8 +43,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..ops.histogram import (callbacks_disabled, hist_pair_fold_block,
-                             hist_pair_fold_collapse, set_hist_mode)
+from ..ops.histogram import hist_pair_fold_block, hist_pair_fold_collapse
 from ..ops.split import K_MIN_SCORE, SplitParams, find_best_split
 from ..parallel.heartbeat import collective_guard
 from ..utils.log import Log
@@ -105,8 +104,6 @@ class OutOfCoreTreeLearner:
         self.num_features = train_set.num_features
         self.num_data = train_set.num_data
         self.max_bin = int(train_set.max_stored_bin)
-        self._hist_mode_cfg = getattr(cfg, "hist_mode", "auto")
-        set_hist_mode(self._hist_mode_cfg)
         if store.num_stored != self.num_features:
             Log.fatal("block store holds %d stored features but the "
                       "dataset maps %d", store.num_stored,
@@ -243,9 +240,6 @@ class OutOfCoreTreeLearner:
         return ev
 
     # ------------------------------------------------------- serial surface
-    def apply_hist_mode(self):
-        set_hist_mode(getattr(self, "_hist_mode_cfg", "auto"))
-
     def reset_config(self, config):
         self.config = config
         if self.train_set is not None:
@@ -287,15 +281,14 @@ class OutOfCoreTreeLearner:
         comp = jnp.zeros((f, b, 3), jnp.float32)
         lid = jnp.int32(leaf_id)
         t0 = time.perf_counter()
-        with callbacks_disabled():
-            for s, e, blk in self._prefetcher.stream():
-                acc, comp = self._fold(acc, comp, blk, ghc_dev[:, s:e],
-                                       rl_dev[s:e], lid)
-            # serial: collapse the local pair; gang: exchange partial
-            # pairs across ranks first (data/ooc_parallel.py) — either
-            # way the pass wall includes the sync, so overlap_pct keeps
-            # meaning 'share of the pass NOT stalled on IO'
-            hist = self._combine_pair(acc, comp)
+        for s, e, blk in self._prefetcher.stream():
+            acc, comp = self._fold(acc, comp, blk, ghc_dev[:, s:e],
+                                   rl_dev[s:e], lid)
+        # serial: collapse the local pair; gang: exchange partial
+        # pairs across ranks first (data/ooc_parallel.py) — either
+        # way the pass wall includes the sync, so overlap_pct keeps
+        # meaning 'share of the pass NOT stalled on IO'
+        hist = self._combine_pair(acc, comp)
         self._prefetcher.note_pass_wall(time.perf_counter() - t0)
         return hist
 
@@ -339,7 +332,6 @@ class OutOfCoreTreeLearner:
         Returns the builder-output dict (host numpy arrays; the GBDT
         layer consumes it exactly like the serial learner's device
         dict)."""
-        self.apply_hist_mode()
         n, n_pad = self.num_data, self.n_pad
         g = np.asarray(grad, dtype=F32)
         h = np.asarray(hess, dtype=F32)

@@ -15,7 +15,6 @@ window, identical between the fused scan and the per-iteration loop,
 and exact-count like the reference's.
 """
 
-import contextlib
 import functools
 import os
 
@@ -1076,10 +1075,6 @@ class GBDT:
             "gops": self.objective._grad_ops,
         }
 
-        # the fused program embeds the learner's builder: resolve THIS
-        # learner's hist_mode for the trace (a sibling Booster may have
-        # moved the process global since learner init)
-        learner.apply_hist_mode()
         self._note_builder_kernels()
         num_class = self.num_class
         # both the partitioned and the gather-compacted builders dispatch
@@ -1161,17 +1156,8 @@ class GBDT:
         # two labels whose wall seconds it keeps: `:lower` (trace +
         # lower, which no cache serves) and `:compile` (backend compile,
         # or the load from the persistent cache).
-        # 1-core/1-device runners deadlock embedded host callbacks
-        # (ops/histogram.py host_callbacks_hazardous; our entry points
-        # clear the hazard by forcing a second virtual device, see
-        # utils/hostenv) — trace on the segment kernel as a last
-        # resort so library users there terminate instead of hanging
-        from ..ops import histogram as hist_ops
-        guard = (hist_ops.callbacks_disabled
-                 if hist_ops.host_callbacks_hazardous()
-                 else contextlib.nullcontext)
         bucket = f"fused_scan_{num_iters}it"
-        with LEDGER.label(bucket + ":lower"), guard():
+        with LEDGER.label(bucket + ":lower"):
             lowered = jax.jit(fused).lower(score, fmasks, iters, data)
         with LEDGER.label(bucket + ":compile"):
             compiled = lowered.compile()

@@ -40,11 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import contextlib
-
-from ..ops.histogram import (callbacks_disabled, compacted_histograms,
-                             frontier_histograms, host_callbacks_hazardous,
-                             set_hist_mode)
+from ..ops.histogram import compacted_histograms, frontier_histograms
 from ..ops.ordered_hist import canonical_row_chunks
 from ..ops.pallas_hist import masked_histograms, HIST_CHUNK
 from ..ops.split import SplitParams, find_best_split, K_MIN_SCORE
@@ -191,7 +187,7 @@ def build_tree_device(bins, grad, hess, inbag, feature_mask,
                       hist_psum_fn=_collapse_pair, sum_psum_fn=_identity,
                       evaluate_fn=None, split_col_fn=None,
                       expand_fn=_identity, cache_hists=True,
-                      compact_hist=False, use_frontier=True):
+                      compact_hist=False):
     """Grow one leaf-wise tree on device. All shapes static.
 
     Args:
@@ -245,15 +241,13 @@ def build_tree_device(bins, grad, hess, inbag, feature_mask,
         the whole array). Works under every collective hook: the pair
         contract is unchanged and the bucketed lax.switch holds no
         collectives, so hist_psum_fn still meets shards in lockstep.
-      use_frontier: route the root/bagging re-init pass through the
-        multi-leaf frontier primitive (ops/histogram.py
-        frontier_histograms), and — in cache-less (memory-bounded)
-        mode on the masked path — build BOTH children of a split in
-        one data pass instead of two, halving that mode's full-matrix
-        streams. Per-leaf values are bitwise identical to the
-        single-leaf kernels (same chunk decomposition and accumulation
-        order), so this changes pass count, not numerics. The
-        hist_frontier config tri-state maps here ("auto" = on).
+
+    The root/bagging re-init pass runs through the multi-leaf frontier
+    primitive (ops/histogram.py frontier_histograms), and the cache-less
+    masked path builds BOTH children of a split in one data pass
+    instead of two. Per-leaf values are what the single-leaf kernels
+    give (same chunk decomposition and accumulation order), so this
+    changes pass count, not numerics.
 
     Returns a dict of tree arrays + the final row->leaf partition.
     """
@@ -279,25 +273,6 @@ def build_tree_device(bins, grad, hess, inbag, feature_mask,
     # packed per-row stats, stats-major for the masked histogram kernel
     ghc_t = jnp.stack([g_in, h_in, inbag], axis=0)  # (3, N_pad)
 
-    # The masked (non-compacted) configuration is THE engine carrying
-    # the exact serial == data-parallel contract: its chunk kernels
-    # must resolve identically in the serial and meshed learners, and
-    # the meshed learners trace under callbacks_disabled (host
-    # callbacks deadlock multi-device shard_map CPU programs) — so the
-    # serial masked trace disables them too. The compacted engine
-    # (documented ~1e-6 vs masked, opt-in on row shards) keeps the
-    # bincount callback kernel.
-    hist_guard = (contextlib.nullcontext if compact_hist
-                  else callbacks_disabled)
-
-    def full_scan_histogram(row_leaf, leaf_id):
-        """Full-bandwidth streaming pass selecting `leaf_id`'s rows by
-        mask (ops/pallas_hist.py) — the TPU replacement for the
-        reference's ordered-gather ConstructHistogram."""
-        with hist_guard():
-            return masked_histograms(bins, ghc_t, row_leaf, leaf_id, b,
-                                     row_chunk)
-
     if compact_hist:
         def leaf_histogram(row_leaf, leaf_id):
             """Gather-compacted smaller-child pass: stream only the
@@ -305,21 +280,20 @@ def build_tree_device(bins, grad, hess, inbag, feature_mask,
             return compacted_histograms(bins, ghc_t, row_leaf, leaf_id,
                                         b, row_chunk)
     else:
-        leaf_histogram = full_scan_histogram
+        def leaf_histogram(row_leaf, leaf_id):
+            """Full-bandwidth streaming pass selecting `leaf_id`'s rows
+            by mask (ops/pallas_hist.py) — the TPU replacement for the
+            reference's ordered-gather ConstructHistogram."""
+            return masked_histograms(bins, ghc_t, row_leaf, leaf_id, b,
+                                     row_chunk)
 
     # ---- root ----------------------------------------------------------
     # (re)built at every tree under bagging/GOSS: the in-bag weights
     # rode in through ghc_t, so this full pass IS the bagging re-init
     row_leaf0 = jnp.zeros(n_pad, dtype=jnp.int32)
-    if use_frontier:
-        with hist_guard():
-            root_pair = frontier_histograms(bins, ghc_t, row_leaf0,
-                                            jnp.zeros(1, jnp.int32), b,
-                                            row_chunk)
-        hist_root = hist_psum_fn(root_pair)[0]
-    else:
-        hist_root = hist_psum_fn(full_scan_histogram(row_leaf0,
-                                                     jnp.int32(0)))
+    root_pair = frontier_histograms(bins, ghc_t, row_leaf0,
+                                    jnp.zeros(1, jnp.int32), b, row_chunk)
+    hist_root = hist_psum_fn(root_pair)[0]
     # root sums from the reduced histogram: feature 0's bins partition
     # the rows, so its bin sums ARE the leaf totals — this keeps parent
     # sums bit-consistent with the histogram across serial/parallel
@@ -376,21 +350,19 @@ def build_tree_device(bins, grad, hess, inbag, feature_mask,
                 st["hist_cache"] = (st["hist_cache"]
                                     .at[best_leaf].set(hist_left)
                                     .at[right_id].set(hist_right))
-            elif use_frontier and not compact_hist:
+            elif not compact_hist:
                 # memory-bounded mode, frontier-batched: BOTH children
-                # from ONE streamed pass (leaf-indexed accumulator /
-                # combined leaf x bin key) — half the full-matrix
-                # streams of the two-pass recompute below
+                # from ONE streamed pass (leaf-indexed accumulator) —
+                # half the full-matrix streams of the two-pass
+                # recompute below
                 leaf_vec = jnp.stack([best_leaf,
                                       right_id]).astype(jnp.int32)
-                with hist_guard():
-                    both_pair = frontier_histograms(
-                        bins, ghc_t, st["row_leaf"], leaf_vec, b,
-                        row_chunk)
-                both = hist_psum_fn(both_pair)
+                both = hist_psum_fn(frontier_histograms(
+                    bins, ghc_t, st["row_leaf"], leaf_vec, b, row_chunk))
                 hist_left, hist_right = both[0], both[1]
             else:
-                # memory-bounded mode: both children recomputed
+                # memory-bounded mode over compacted rows: both
+                # children recomputed, each at its own row count
                 hist_left = hist_psum_fn(
                     leaf_histogram(st["row_leaf"], best_leaf))
                 hist_right = hist_psum_fn(
@@ -495,19 +467,8 @@ class SerialTreeLearner:
         # several features' bin ranges; io/bundling.py)
         self.max_bin = int(train_set.max_stored_bin)
         self._bundle = train_set.bundle_plan
-        # histogram formulation knob (config wins over the env default;
-        # ops/histogram.py set_hist_mode) — must land before any
-        # builder jit so the resolved mode is baked consistently. The
-        # mode is re-asserted before every build/trace (apply_hist_mode)
-        # so two Boosters with different hist_mode in one process
-        # cannot cross-contaminate a later retrace (new shape bucket).
-        self._hist_mode_cfg = getattr(cfg, "hist_mode", "auto")
-        set_hist_mode(self._hist_mode_cfg)
         self._use_partitioned = self._partitioned_enabled(cfg)
         self._use_compact = self._compaction_enabled(cfg)
-        self._use_frontier = _tristate(
-            getattr(cfg, "hist_frontier", "auto"),
-            "hist_frontier") != "false"
         self._use_shape_bucketing = _tristate(
             getattr(cfg, "shape_bucketing", "auto"),
             "shape_bucketing") != "false"
@@ -797,7 +758,6 @@ class SerialTreeLearner:
             row_chunk=chunk,
             cache_hists=cache_hists,
             compact_hist=self._use_compact,
-            use_frontier=self._use_frontier,
         )
         if getattr(self, "_bundle", None) is None:
             return base
@@ -829,13 +789,6 @@ class SerialTreeLearner:
                 [mask, np.zeros(self.f_pad - self.num_features, bool)])
         return mask
 
-    def apply_hist_mode(self):
-        """Re-assert THIS learner's configured hist_mode on the process
-        global before a build call or fused-program trace (a jit retrace
-        on a new shape bucket resolves the mode at that moment, and a
-        sibling Booster may have moved it since init)."""
-        set_hist_mode(getattr(self, "_hist_mode_cfg", "auto"))
-
     def train_device(self, grad, hess, inbag=None):
         """Grow one tree entirely on device; NO host synchronization.
 
@@ -844,7 +797,6 @@ class SerialTreeLearner:
         (and whether) to pull anything to host — see models/gbdt.py
         LazyTree.
         """
-        self.apply_hist_mode()
         n, n_pad = self.num_data, self.n_pad
         grad = jnp.asarray(grad, dtype=jnp.float32)
         hess = jnp.asarray(hess, dtype=jnp.float32)
@@ -860,17 +812,8 @@ class SerialTreeLearner:
         hess = self._place_rows(hess)
         inbag = self._place_rows(inbag)
         fmask = self._place_rep(self._sample_features())
-        # 1-core, 1-device runners deadlock the bincount callbacks on
-        # this async-dispatched program (ops/histogram.py
-        # host_callbacks_hazardous) — trace with callbacks disabled so
-        # the builder resolves the segment kernel there. The guard only
-        # matters on the first trace per shape bucket; the hazard is
-        # process-stable so later cache hits see the same program.
-        guard = (callbacks_disabled if host_callbacks_hazardous()
-                 else contextlib.nullcontext)
-        with guard():
-            return self._build(self._bins, grad, hess, inbag, fmask,
-                               self._num_bin_pf, self._is_cat)
+        return self._build(self._bins, grad, hess, inbag, fmask,
+                           self._num_bin_pf, self._is_cat)
 
     def train(self, grad, hess, inbag=None):
         """Grow one tree. grad/hess: (N,) device or host float32.

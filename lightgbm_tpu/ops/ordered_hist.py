@@ -60,27 +60,11 @@ def unpack_feature(words, feat):
     return (word >> ((feat & 3) * 8)) & 0xFF
 
 
-def _parse_bucket_growth():
-    import os
-    raw = os.environ.get("LIGHTGBM_TPU_BUCKET_GROWTH", "2")
-    try:
-        growth = int(raw)
-    except ValueError:
-        raise ValueError(
-            f"LIGHTGBM_TPU_BUCKET_GROWTH must be an integer >= 2, got {raw!r}")
-    if growth < 2:
-        raise ValueError(
-            f"LIGHTGBM_TPU_BUCKET_GROWTH must be >= 2, got {raw!r}")
-    return growth
-
-
-# Geometric growth factor of the segment buckets, read ONCE at import
-# (consistent for the process lifetime — jitted programs bake it in).
-# 2 (default) minimizes streaming waste (<2x per segment) at
-# ~log2(n_chunks) compiled kernel variants; LIGHTGBM_TPU_BUCKET_GROWTH=4
-# halves the variant count (faster compile) at <4x worst-case waste — a
-# knob for tuning compile-time vs throughput on real hardware.
-BUCKET_GROWTH = _parse_bucket_growth()
+# Geometric growth factor of the segment buckets. 2 minimizes streaming
+# waste (<2x per segment) at ~log2(n_chunks) compiled kernel variants;
+# 4 would halve the variant count (faster compile) at <4x worst-case
+# waste. Jitted programs bake it in.
+BUCKET_GROWTH = 2
 
 
 def bucket_sizes(n_chunks):
@@ -241,7 +225,7 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
 
 def _seg_hist_xla(words_sl, ghc_sl, lo, hi, f, num_bins_total):
     """XLA fallback (CPU tests / non-TPU): unpack + positional mask +
-    the chunked one-hot einsum of ops/histogram.py."""
+    the platform's chunk formulation of ops/histogram.py."""
     from .histogram import build_histograms
     w, n = words_sl.shape
     shifts = jnp.arange(4, dtype=jnp.int32) * 8
@@ -283,10 +267,9 @@ def segment_histograms(words, ghc_t, begin, cnt, num_bins_total, f,
     idx, c_first = cover_index(begin, cnt, n_chunks)
 
     if interpret_backend is None:
-        # same dispatch as ops/pallas_hist.py masked_histograms: TPU
-        # with hist_mode auto/pallas runs the kernel; einsum/segment/
-        # bincount take the XLA path; an explicit interpret_backend
-        # wins
+        # same dispatch as ops/pallas_hist.py masked_histograms: a
+        # TPU backend runs the kernel, every other the XLA
+        # formulation; an explicit interpret_backend wins
         from .histogram import use_pallas
         on_tpu = use_pallas()
     else:

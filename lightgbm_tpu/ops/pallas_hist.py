@@ -36,10 +36,10 @@ The stats enter the kernel as three bfloat16 terms each (split_stats),
 so the single-pass bfloat16 contraction gives f32-exact products and
 f32 accumulation; the count column comes out exactly integral.
 
-Dispatch: masked_histograms/frontier select the Pallas path via
-ops/histogram.py use_pallas() — TPU backend with hist_mode auto/pallas
-(config knob or LIGHTGBM_TPU_HIST_MODE). hist_mode=einsum/segment/
-bincount takes the XLA path on TPU instead.
+Dispatch: masked_histograms/frontier take the Pallas path where
+ops/histogram.py use_pallas() says so — on a TPU backend, always; no
+option turns the kernels off. Every other backend runs the XLA chunk
+formulation of ops/histogram.py chunk_mode().
 """
 
 import functools
@@ -270,16 +270,16 @@ def frontier_histograms_tpu(bins, ghc_t, row_leaf, leaf_ids, num_bins_total,
 
 
 def masked_histograms_xla(bins, ghc_t, row_leaf, leaf_id, num_bins_total,
-                          row_chunk=HIST_CHUNK):
+                          row_chunk=HIST_CHUNK, mode=None):
     """Reference XLA implementation (CPU tests / non-TPU backends): the
-    chunked histogram kernel of ops/histogram.py (bincount callback on
-    CPU, one-hot einsum elsewhere — chunk_mode) with the leaf mask
-    folded into the stats. Returns a compensated (value, residual)
-    pair."""
+    chunked histogram kernel of ops/histogram.py (scatter-add on the
+    CPU, one-hot einsum elsewhere — chunk_mode; `mode` lets a test name
+    one) with the leaf mask folded into the stats. Returns a
+    compensated (value, residual) pair."""
     from .histogram import build_histograms_pair
     mask = (row_leaf == leaf_id).astype(jnp.float32)
     ghc = (ghc_t * mask[None, :]).T
-    return build_histograms_pair(bins, ghc, num_bins_total, row_chunk)
+    return build_histograms_pair(bins, ghc, num_bins_total, row_chunk, mode)
 
 
 def masked_histograms(bins, ghc_t, row_leaf, leaf_id, num_bins_total,

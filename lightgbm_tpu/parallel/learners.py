@@ -60,8 +60,8 @@ from .heartbeat import collective_guard
 from .mesh import (AXIS, COLLECTIVE_KINDS, CommPlan,  # noqa: F401
                    MeshTopology, allgather_recv_bytes, alltoall_recv_bytes,
                    compressed_allreduce, compressed_psum,
-                   compressed_reduce_scatter, make_mesh, meshed_trace_guard,
-                   pair_allreduce, pair_reduce_scatter, psum_recv_bytes,
+                   compressed_reduce_scatter, make_mesh, pair_allreduce,
+                   pair_reduce_scatter, psum_recv_bytes,
                    resolve_hist_exchange, shard_map)
 
 _TREE_OUT_KEYS = (
@@ -241,13 +241,7 @@ class _MeshedTreeLearner(SerialTreeLearner):
     # (parallel/heartbeat.py; armed only when `collective_timeout_s`
     # is set, zero overhead otherwise).
     def train_device(self, grad, hess, inbag=None):
-        # meshed_trace_guard: the first call traces the jitted builder,
-        # and host-callback kernels inside multi-device shard_map
-        # programs deadlock this image's XLA CPU runtime — meshed
-        # builders bake the pure-XLA segment kernel instead
-        # (parallel/mesh.py, ops/histogram.py chunk_mode)
-        with collective_guard(f"{self.name}:tree_build"), \
-                meshed_trace_guard():
+        with collective_guard(f"{self.name}:tree_build"):
             return super().train_device(grad, hess, inbag)
 
     def local_row_leaf(self, out, n_local):
@@ -445,7 +439,6 @@ class DataParallelTreeLearner(_MeshedTreeLearner):
                     max_depth=max_depth, row_chunk=chunk,
                     hist_psum_fn=exchange_fn,
                     compact_hist=self._use_compact,
-                    use_frontier=self._use_frontier,
                     **self._bundle_kwargs(bins, num_bin_pf))
 
             return self._row_sharded_map(dp_fn)
@@ -525,8 +518,7 @@ class DataParallelTreeLearner(_MeshedTreeLearner):
                 max_depth=max_depth, row_chunk=chunk,
                 hist_psum_fn=exchange_fn, sum_psum_fn=sum_bcast,
                 evaluate_fn=evaluate,
-                compact_hist=self._use_compact,
-                use_frontier=self._use_frontier)
+                compact_hist=self._use_compact)
 
         return self._row_sharded_map(dp_rs_fn)
 
@@ -620,7 +612,6 @@ class FeatureParallelTreeLearner(_MeshedTreeLearner):
         max_depth = int(cfg.max_depth)
         f_loc = self.f_pad // self.n_shards
         compact = self._use_compact
-        use_frontier = self._use_frontier
         w = self.n_shards
         self._comm_plan = plan = CommPlan()
 
@@ -717,7 +708,7 @@ class FeatureParallelTreeLearner(_MeshedTreeLearner):
                 sum_psum_fn=sum_bcast,
                 evaluate_fn=evaluate, split_col_fn=split_col,
                 expand_fn=expand if bundled else (lambda h: h),
-                compact_hist=compact, use_frontier=use_frontier)
+                compact_hist=compact)
 
         def wrapped7(bins, grad, hess, inbag, fmask, num_bin_pf, is_cat):
             inner = shard_map(
@@ -860,7 +851,6 @@ class VotingParallelTreeLearner(_MeshedTreeLearner):
                 sum_psum_fn=psum,
                 evaluate_fn=make_evaluate(fmask, num_bin_pf, is_cat),
                 compact_hist=self._use_compact,
-                use_frontier=self._use_frontier,
                 **self._bundle_kwargs(bins, num_bin_pf))
 
         return self._row_sharded_map(voting_fn)

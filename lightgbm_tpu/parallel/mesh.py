@@ -66,22 +66,6 @@ def shard_map(fn, mesh, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
-def meshed_trace_guard():
-    """The guard every meshed builder must trace under.
-
-    Host-callback kernels embedded in MULTI-DEVICE shard_map programs
-    deadlock the XLA CPU runtime: the dispatching thread
-    blocks in a sharded execute while the callback worker threads park
-    on the GIL it holds (observed as a hang in the data-parallel
-    compacted build; single-device programs are unaffected). Inside
-    this context ops/histogram.py resolves "bincount" to the pure-XLA
-    segment kernel instead, so the traced program holds no callbacks.
-    Lives here, next to shard_map, so every mesh user picks up the
-    caveat with it."""
-    from ..ops.histogram import callbacks_disabled
-    return callbacks_disabled()
-
-
 def make_mesh(config) -> Mesh:
     """1-D device mesh.
 

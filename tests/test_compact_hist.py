@@ -115,18 +115,6 @@ def test_compacted_shard_reduction_matches_serial():
     serial_full = jax.jit(lambda rl, l: masked_histograms_xla(
         bins, ghc_t, rl, l, b))
 
-    # trace the multi-device programs under callbacks_disabled like the
-    # meshed learners do: compacted_histograms' CPU-default bincount
-    # formulation is a host callback, and host callbacks inside
-    # multi-device shard_map programs can deadlock the XLA CPU runtime
-    # (ops/histogram.py:154; the chunk kernels are bit-identical across
-    # formulations, so the parity being tested is unchanged)
-    from lightgbm_tpu.ops.histogram import callbacks_disabled
-    with callbacks_disabled():
-        # leaf is a traced operand, so one call traces each program
-        sharded_c(bins, ghc_t, row_leaf, jnp.int32(0))
-        sharded_m(bins, ghc_t, row_leaf, jnp.int32(0))
-
     for leaf in range(leaves):
         hd = np.asarray(sharded_c(bins, ghc_t, row_leaf, jnp.int32(leaf)))
         ms, mc = sharded_m(bins, ghc_t, row_leaf, jnp.int32(leaf))
@@ -230,14 +218,12 @@ from lightgbm_tpu.objectives import create_objective
 rng = np.random.RandomState(0)
 x = rng.rand(600, 4).astype(np.float32)
 y = (x[:, 0] > 0.5).astype(np.float32)
-# hist_mode=segment pins the PURE-XLA fused program: the CPU-default
-# bincount mode embeds host callbacks whose custom-call targets are
-# process-local, so that program can never be served across processes
-# (its cold compile is ~10x cheaper instead — the scatter/switch
-# graphs are gone; test_bincount_fused_compile_is_cheap below)
+# the default CPU program is pure XLA (no host callback, whose
+# custom-call target would be process-local), so it IS served across
+# processes
 cfg = Config.from_params({"objective": "binary", "num_leaves": 7,
                           "min_data_in_leaf": 5, "metric_freq": 0,
-                          "hist_mode": "segment", "verbose": -1})
+                          "verbose": -1})
 ds = DatasetLoader(cfg).construct_from_matrix(x, label=y)
 obj = create_objective(cfg.objective, cfg)
 obj.init(ds.metadata, ds.num_data)
@@ -284,36 +270,3 @@ def test_persistent_cache_skips_lowering_in_fresh_process(tmp_path):
     # still pays trace time, so assert a solid drop rather than zero
     assert second["compile_s"] < max(0.75 * first["compile_s"], 2.0), \
         (first, second)
-
-
-def test_bincount_fused_compile_is_cheap():
-    """The CPU-default bincount mode trades persistent-cache
-    serviceability of the fused program (host-callback custom-call
-    targets are process-local) for a fused compile that is cheap
-    enough not to need it: the scatter/switch graphs are gone from
-    the HLO. Pin that the whole warm-up stays well under the old
-    ~10 s cold compiles."""
-    import time
-
-    import numpy as np
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.io.dataset import DatasetLoader
-    from lightgbm_tpu.models.gbdt import GBDT
-    from lightgbm_tpu.objectives import create_objective
-
-    rng = np.random.RandomState(1)
-    x = rng.rand(600, 4).astype(np.float32)
-    y = (x[:, 0] > 0.5).astype(np.float32)
-    cfg = Config.from_params({"objective": "binary", "num_leaves": 7,
-                              "min_data_in_leaf": 5, "metric_freq": 0,
-                              "hist_mode": "bincount", "verbose": -1})
-    ds = DatasetLoader(cfg).construct_from_matrix(x, label=y)
-    obj = create_objective(cfg.objective, cfg)
-    obj.init(ds.metadata, ds.num_data)
-    g = GBDT()
-    g.init(cfg, ds, obj, [])
-    t0 = time.time()
-    assert g.warm_up_fused(2)
-    assert time.time() - t0 < 8.0  # cold, single-core CI margin
-    g.train_many(2)
-    assert len(g.models) == 2

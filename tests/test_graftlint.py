@@ -66,10 +66,9 @@ def test_every_rule_ships_bad_and_good_fixtures():
 
 
 def test_issue_rule_set_complete():
-    expected = {"callback-in-mesh", "unguarded-collective",
-                "non-atomic-shared-write", "precision-contract",
-                "nondeterminism", "journal-schema", "prometheus-naming",
-                "config-doc-drift"}
+    expected = {"unguarded-collective", "non-atomic-shared-write",
+                "precision-contract", "nondeterminism", "journal-schema",
+                "prometheus-naming", "config-doc-drift"}
     assert expected <= set(REGISTRY)
 
 
@@ -406,27 +405,6 @@ def test_partial_rule_run_does_not_report_other_rules_unused(tmp_path):
     result = lint_project(root)
     assert [e["rule"] for e in result.baseline_unused] == \
         ["nondeterminism"]
-
-
-def test_ambiguous_traced_fn_is_skipped(tmp_path):
-    """Two same-named candidate functions: callback-in-mesh must skip
-    rather than attribute an arbitrary one's reachability."""
-    cb = ("import jax\n"
-          "def build(x):\n"
-          "    return jax.pure_callback(lambda a: a, x, x)\n")
-    pure = "def build(x):\n    return x + 1\n"
-    user = ("from jax.experimental.shard_map import shard_map\n"
-            "def train(mesh, bins):\n"
-            "    fn = shard_map(build, mesh=mesh, in_specs=None,\n"
-            "                   out_specs=None)\n"
-            "    return fn(bins)\n")
-    root = write_project(tmp_path, {
-        "lightgbm_tpu/ops/a.py": cb,
-        "lightgbm_tpu/ops/b.py": pure,
-        "lightgbm_tpu/parallel/user.py": user})
-    result = lint_project(root, rule_names=["callback-in-mesh"],
-                          use_baseline=False)
-    assert result.violations == []
 
 
 def test_cli_unknown_rule_is_usage_error(tmp_path):
