@@ -29,7 +29,7 @@ from ..telemetry.registry import MetricsRegistry
 from ..telemetry.trace import SpanTracer, scope
 from ..utils import common, faults, guardrails
 from ..utils.log import Log
-from .score_updater import ScoreUpdater
+from .score_updater import ScoreUpdater, leaf_lookup, lookup_form
 from .tree import Tree
 from .tree_learner import create_tree_learner
 
@@ -1033,11 +1033,17 @@ class GBDT:
         `partition_engine` (ops/partition.py: `pallas` on a TPU, `xla`
         off it), and the histogram kernel's one-hot operand
         (ops/ordered_hist.py onehot_extent): `seg_hist_onehot_rows` a
-        feature and `seg_hist_features_per_dot`."""
+        feature and `seg_hist_features_per_dot`; and `score_update_form`,
+        the end-of-tree un-permute and leaf-value lookup that were traced
+        (ops/partition.py unpermute, one key-value sort, + score_updater.py
+        lookup_form: `sort_kv+split64` at 255 leaves)."""
         if getattr(self.tree_learner, "_use_partitioned", False):
             from ..ops.ordered_hist import onehot_extent
             from ..ops.partition import partition_engine
             self.metrics.set("partition_engine", partition_engine())
+            self.metrics.set(
+                "score_update_form", "sort_kv+"
+                + lookup_form(int(self.tree_learner.config.num_leaves)))
             rows, features = onehot_extent(self.tree_learner.max_bin)
             self.metrics.set("seg_hist_onehot_rows", rows)
             self.metrics.set("seg_hist_features_per_dot", features)
@@ -1107,8 +1113,8 @@ class GBDT:
                     out = core(bins, gp[0], hp[0], ib, fmask[0], nbpf,
                                iscat)
                     with scope("score_update"):
-                        upd = jnp.take(out["leaf_value"],
-                                       out["row_leaf"][:n])[None, :]
+                        upd = leaf_lookup(out["leaf_value"] * shrink,
+                                          out["row_leaf"][:n])[None, :]
                 elif not use_switch_core:
                     # one device program for ALL classes: vmap the
                     # whole-tree builder over the class axis (SURVEY M2;
@@ -1118,9 +1124,11 @@ class GBDT:
                         lambda gg, hh, fm: core(bins, gg, hh, ib, fm,
                                                 nbpf, iscat))(gp, hp, fmask)
                     with scope("score_update"):
-                        upd = jax.vmap(
-                            lambda lv, rl: jnp.take(lv, rl[:n]))(
-                                out["leaf_value"], out["row_leaf"])
+                        # a class at a time: batched over K the lookup
+                        # compiles to the generic gather again
+                        upd = jax.lax.map(
+                            lambda o: leaf_lookup(o[0] * shrink, o[1][:n]),
+                            (out["leaf_value"], out["row_leaf"]))
                 else:
                     # partitioned/compacted builder: scan the class axis
                     # instead of vmap — vmapping the bucketed lax.switch
@@ -1132,14 +1140,14 @@ class GBDT:
                         gg, hh, fm = gh
                         o = core(bins, gg, hh, ib, fm, nbpf, iscat)
                         with scope("score_update"):
-                            u = jnp.take(o["leaf_value"],
-                                         o["row_leaf"][:n])
+                            u = leaf_lookup(o["leaf_value"] * shrink,
+                                            o["row_leaf"][:n])
                         return None, (o, u)
 
                     _, (out, upd) = jax.lax.scan(class_step, None,
                                                  (gp, hp, fmask))
                 with scope("score_update"):
-                    score = score + upd * shrink
+                    score = score + upd
                 del out["row_leaf"]  # keep the ys O(iter * num_leaves)
                 return score, out
 

@@ -56,7 +56,7 @@ from ..ops.ordered_hist import (bucket_sizes, cover_index,
 from ..ops.pallas_hist import HIST_CHUNK
 from ..ops.partition import (apply_partition, invert_permutation,
                              pack_rows, partition_engine, partition_rows,
-                             split_destinations)
+                             split_destinations, unpermute)
 from ..ops.split import SplitParams, find_best_split, K_MIN_SCORE
 from ..telemetry.trace import scope
 from .tree_learner import apply_tree_split, init_split_state, write_candidate
@@ -414,11 +414,10 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
         return jax.lax.cond(do, do_split, no_split, st)
 
     state = jax.lax.fori_loop(0, l - 1, body, state)
-    # original-order row->leaf map: one scatter at tree end
+    # original-order row->leaf map: each row moved once at tree end
     with scope("score_update"):
         perm = state["words"][-1] if kernel_engine else state["perm"]
-        row_leaf = (jnp.zeros(n_pad, dtype=jnp.int32)
-                    .at[perm].set(state["pos_leaf"]))
+        row_leaf = unpermute(perm, state["pos_leaf"])
     return {
         "n_splits": state["n_splits"],
         "row_leaf": row_leaf,

@@ -19,6 +19,46 @@ import jax.numpy as jnp
 import numpy as np
 
 
+# XLA's TPU compiler turns a gather from a table of up to 64 entries
+# into a chain of `select(index >= k, table[k], acc)` inside the
+# consumer's loop fusion, which runs near the speed of memory (0.9 ms
+# for 10.5M rows at 63 entries); from 65 entries on it emits a real
+# gather in a fusion of its own, about 120M indices a second (86 ms at
+# 255 entries; PERF.md section 6, PR 32).
+LOOKUP_PIECE = 64
+
+
+def lookup_form(num_leaves):
+    """The gauge's word for what `leaf_lookup` traces at that length."""
+    return "take" if num_leaves <= LOOKUP_PIECE else f"split{LOOKUP_PIECE}"
+
+
+def leaf_lookup(leaf_value, leaf_index):
+    """leaf_value[leaf_index] for a (L,) table and indices in [0, L): the
+    same element, so the same bits. The table is cut into pieces of
+    LOOKUP_PIECE entries, each looked up by its own `take`, and a select
+    between them; a table of one piece is that `take` alone.
+
+    `take` in its default mode (NaN for an index outside the piece,
+    which the select between pieces never picks): its select stands
+    between the table's entry and the score's add. A caller that scales
+    the values scales the TABLE (the fused step: `leaf_value * shrink`,
+    L products of the same two floats a row would multiply); the CPU
+    compiler computes such a product again a row, and with nothing
+    between it and the add contracts the two into a fused multiply-add
+    that rounds once where the per-iteration loop and the reference
+    round twice (`mode="clip"` and `promise_in_bounds` do that)."""
+    out = None
+    for lo in range(0, leaf_value.shape[0], LOOKUP_PIECE):
+        sub = jnp.take(leaf_value[lo:lo + LOOKUP_PIECE], leaf_index - lo)
+        out = sub if out is None else jnp.where(leaf_index >= lo, sub, out)
+    return out
+
+
+# the per-iteration loop's call: one program, not a dispatch a piece
+_leaf_lookup_jit = jax.jit(leaf_lookup)
+
+
 def _traverse_add(score_row, bins_dev, is_cat, split_feature, threshold_bin,
                   left_child, right_child, leaf_value, n_splits, scale,
                   feat_slot, feat_off, feat_nb):
@@ -101,8 +141,9 @@ class ScoreUpdater:
             self.score = jnp.zeros((self.num_class, n), dtype=jnp.float32)
 
     def add_score_by_partition(self, leaf_values, row_leaf, curr_class):
-        """score += leaf_values[row_leaf] (device gather)."""
-        upd = jnp.take(jnp.asarray(leaf_values, dtype=jnp.float32), row_leaf)
+        """score += leaf_values[row_leaf] (device lookup)."""
+        upd = _leaf_lookup_jit(
+            jnp.asarray(leaf_values, dtype=jnp.float32), row_leaf)
         self.score = self.score.at[curr_class].add(upd)
 
     def add_score_by_values(self, values, curr_class):

@@ -122,6 +122,20 @@ def partition_engine():
     return "pallas" if use_pallas() else "xla"
 
 
+def unpermute(perm, values):
+    """out[perm[i]] = values[i], for a `perm` that holds every position
+    once (`arange` moved only by the partition step): the values sorted
+    by such a `perm` ARE the result, one key-value sort on every
+    platform, so every test runs the form the chip runs.
+
+    The v5e compiler's scatter is this sort (of indices and updates)
+    plus a scatter of the sorted indices; told `unique_indices` its
+    optimized HLO differs by that attribute alone: 80.0 ms either way
+    at 11.5M rows against the sort's 18.5 (PERF.md section 6, PR 32).
+    The keys are unique, so the unstable sort has one answer."""
+    return jax.lax.sort((perm, values), num_keys=1, is_stable=False)[1]
+
+
 PART_CHUNK = 2048   # lanes a DMA moves; divides HIST_CHUNK
 PART_TILE = 128     # rows one permutation matrix compacts
 _COPY_DEPTH = 4     # chunk copies of the right stream kept in flight

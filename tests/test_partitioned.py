@@ -121,13 +121,18 @@ def test_partition_segment_matches_full_array():
         np.testing.assert_array_equal(np.asarray(p2), np.asarray(p_ref))
 
 
-def _train(x, y, params, n_iter=8):
+def _booster(x, y, params):
     cfg = Config.from_params(params)
     ds = DatasetLoader(cfg).construct_from_matrix(x, label=y)
     objective = create_objective(cfg.objective, cfg)
     objective.init(ds.metadata, ds.num_data)
     booster = GBDT()
     booster.init(cfg, ds, objective, [])
+    return booster
+
+
+def _train(x, y, params, n_iter=8):
+    booster = _booster(x, y, params)
     booster.train_many(n_iter)
     return booster
 
@@ -400,3 +405,119 @@ def test_builder_engines_grow_the_same_tree(monkeypatch):
     for key in want:
         np.testing.assert_array_equal(np.asarray(got[key]),
                                       np.asarray(want[key]), err_msg=key)
+
+
+# (num_leaves, extra params, classes): both sides of the table length at
+# which `leaf_lookup` cuts the table into pieces, that length itself,
+# and the piece boundaries above it
+SCORE_UPDATE_CASES = {
+    "l2": (2, {}, 1),
+    "l63": (63, {}, 1),
+    "l64": (64, {}, 1),
+    "l128": (128, {}, 1),
+    "l129": (129, {}, 1),
+    "l255": (255, {}, 1),
+    "bagging": (129, {"bagging_fraction": 0.5, "bagging_freq": 1}, 1),
+    "stops_early": (255, {"min_data_in_leaf": 400}, 1),
+    "class_step": (129, {}, 3),
+    # the masked builder: one class, then vmapped over three with a
+    # lookup a class
+    "masked": (15, {"partitioned_build": "false",
+                    "hist_compaction": "false"}, 1),
+    "masked_classes": (129, {"partitioned_build": "false",
+                             "hist_compaction": "false"}, 3),
+}
+
+
+@pytest.mark.parametrize("case", list(SCORE_UPDATE_CASES))
+def test_fused_score_update_bit_exact(case):
+    """What the fused step adds to the score is leaf_value[row_leaf] *
+    shrink to the bit (numpy float32: one multiply, one add a row), for
+    every row, in-bag or not, with pad rows behind them, whichever form
+    the lookup takes for the table's length; and `row_leaf` as the
+    per-iteration loop and linear leaves receive it is each row's leaf by
+    a host traversal of the tree."""
+    from lightgbm_tpu.models.score_updater import LOOKUP_PIECE, lookup_form
+    leaves, extra, k = SCORE_UPDATE_CASES[case]
+    assert LOOKUP_PIECE == 64
+    assert lookup_form(leaves) == ("take" if leaves <= 64 else "split64")
+    rng = np.random.RandomState(5)
+    n, f = 5000, 4
+    x = rng.rand(n, f).astype(np.float32)
+    if k == 1:
+        y = (x[:, 0] + 0.5 * x[:, 1] * x[:, 2]
+             + 0.2 * rng.randn(n) > 0.7).astype(np.float32)
+        params = {"objective": "binary"}
+    else:
+        y = ((x[:, 0] * 3 + x[:, 1] * 2).astype(np.int32) % k).astype(
+            np.float32)
+        params = {"objective": "multiclass", "num_class": k}
+    params.update({"num_leaves": leaves, "max_bin": 32, "metric_freq": 0,
+                   "min_data_in_leaf": 1, "min_sum_hessian_in_leaf": 1e-3,
+                   "learning_rate": 0.1, "partitioned_build": "true"})
+    params.update(extra)
+
+    fused = _booster(x, y, params)
+    learner = fused.tree_learner
+    assert learner._use_partitioned == (not case.startswith("masked"))
+    assert not learner._use_compact and fused._fused_eligible()
+    assert (learner.n_pad > n) == learner._use_partitioned
+    score0 = np.asarray(fused.train_score_updater.score)
+    fused.train_many(1)
+    got = np.asarray(fused.train_score_updater.score)
+    assert got.shape == (k, n)
+
+    # the same trees through the builder the per-iteration loop calls
+    loop = _booster(x, y, params)
+    grad, hess = loop.objective.get_gradients(loop._score_for_boosting())
+    grad = np.asarray(grad).reshape(k, n)
+    hess = np.asarray(hess).reshape(k, n)
+    bag = loop._bagging_device_fn()
+    inbag = None if bag is None else np.asarray(bag(jnp.int32(0)))[:n]
+    assert (inbag is None) == (case != "bagging")
+    shrink = np.float32(loop.shrinkage_rate)
+    bins = loop.train_data.traversal_bins()
+    for c in range(k):
+        out = loop.tree_learner.train_device(grad[c], hess[c], inbag)
+        n_splits = int(out["n_splits"])
+        assert (n_splits < leaves - 1) == (case == "stops_early"), n_splits
+        row_leaf = np.asarray(loop.tree_learner.local_row_leaf(out, n))
+        np.testing.assert_array_equal(
+            row_leaf, fused.models[c].get_leaf_by_bins(bins))
+        pad_leaf = np.asarray(out["row_leaf"])[n:]
+        assert pad_leaf.size == learner.n_pad - n
+        assert np.all((pad_leaf >= 0) & (pad_leaf <= n_splits))
+        leaf_value = np.asarray(out["leaf_value"])
+        assert leaf_value.dtype == np.float32 and leaf_value.shape == (leaves,)
+        want = score0[c] + (leaf_value * shrink)[row_leaf]
+        assert want.dtype == np.float32
+        np.testing.assert_array_equal(got[c].view(np.int32),
+                                      want.view(np.int32))
+        if inbag is not None:      # out-of-bag rows are updated too
+            oob = inbag == 0
+            assert 0.4 * n < oob.sum() < 0.6 * n
+            assert np.mean(got[c][oob] != score0[c][oob]) > 0.9
+
+
+@pytest.mark.parametrize("classes", [0, 3])
+def test_unpermute_moves_each_value_to_its_row(classes):
+    """The end-of-tree un-permute gives out[perm[i]] = values[i] by one
+    key-value sort and no scatter, alone and batched (a class or a shard
+    at a time, each with a permutation of its own)."""
+    from lightgbm_tpu.ops.partition import unpermute
+    rng = np.random.RandomState(17)
+    n = 3 * 4096
+    perm = np.stack([rng.permutation(n) for _ in range(max(classes, 1))]
+                    ).astype(np.int32)
+    values = rng.randint(0, 255, size=perm.shape).astype(np.int32)
+    want = np.zeros_like(values)
+    for p, v, w in zip(perm, values, want):
+        w[p] = v
+    fn = jax.vmap(unpermute) if classes else unpermute
+    if not classes:
+        perm, values, want = perm[0], values[0], want[0]
+    prims = {e.primitive.name for e in jax.make_jaxpr(unpermute)(
+        perm.reshape(-1, n)[0], values.reshape(-1, n)[0]).eqns}
+    assert "sort" in prims and not prims & {"scatter", "scatter-add"}
+    got = jax.jit(fn)(jnp.asarray(perm), jnp.asarray(values))
+    np.testing.assert_array_equal(np.asarray(got), want)

@@ -193,6 +193,91 @@ def test_vocabulary_avoids_primitive_names():
                 gradients=rank_reader.SUBSCOPES) == DEVICE_SUBSCOPES
 
 
+def walk_eqns(jaxpr, prefix=()):
+    """(primitive name, name-stack words outermost first, equation) of
+    every equation, inner jaxprs included: an inner equation's stack is
+    relative to the equation that holds its jaxpr."""
+    for eqn in jaxpr.eqns:
+        stack = prefix + tuple(
+            w for w in str(eqn.source_info.name_stack).split("/") if w)
+        yield eqn.primitive.name, stack, eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from walk_eqns(inner, stack)
+
+
+def test_score_update_moves_each_row_once(monkeypatch):
+    """The traced fused step of a partitioned learner above the lookup's
+    switch length: no N-index gather from a table longer than a piece,
+    one N-sized permutation (a key-value sort; no scatter), and everything the un-permute and the lookup trace
+    stands under `score_update` -- with the vocabulary as it was."""
+    from lightgbm_tpu.models import gbdt as gbdt_mod, partitioned
+    from lightgbm_tpu.models.score_updater import LOOKUP_PIECE
+    assert DEVICE_SCOPES == ("gradients", "partition", "hist", "hist_reduce",
+                             "split_scan", "tree_state", "score_update")
+    assert {k: len(v) for k, v in DEVICE_SUBSCOPES.items()} == {
+        "gradients": 4, "partition": 6, "hist": 3, "tree_state": 2}
+    leaves = 2 * LOOKUP_PIECE + 1
+    rng = np.random.RandomState(3)
+    x = rng.randn(ROWS, 4).astype(np.float32)
+    y = (x[:, 0] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": leaves, "max_bin": 15,
+              "partitioned_build": "true", "verbose": -1, "metric": "none"}
+    booster = lgb.Booster(params=params,
+                          train_set=lgb.Dataset(x, label=y, params=params))
+    learner = booster.gbdt.tree_learner
+    assert learner._use_partitioned and learner.n_pad > ROWS
+
+    def probed(fn):       # marks what the two helpers trace
+        def inner(*a, **k):
+            with jax.named_scope("probe"):
+                return fn(*a, **k)
+        return inner
+
+    monkeypatch.setattr(gbdt_mod, "leaf_lookup",
+                        probed(gbdt_mod.leaf_lookup))
+    monkeypatch.setattr(partitioned, "unpermute",
+                        probed(partitioned.unpermute))
+    traced = {}
+    jit = jax.jit
+
+    class Recorder:
+        def __init__(self, fn):
+            self.fn = fn
+
+        def lower(self, *args):
+            traced["jaxpr"] = jax.make_jaxpr(self.fn)(*args)
+            return jit(self.fn).lower(*args)
+
+    monkeypatch.setattr(
+        gbdt_mod.jax, "jit",
+        lambda fn, *a, **k: (Recorder(fn) if fn.__name__ == "fused"
+                             else jit(fn, *a, **k)))
+    booster.gbdt._get_fused_fn(1)
+    monkeypatch.undo()
+    eqns = list(walk_eqns(traced["jaxpr"].jaxpr))
+    size = lambda eqn: max(int(np.prod(v.aval.shape)) for v in eqn.outvars)
+    new_code = [(name, stack, eqn) for name, stack, eqn in eqns
+                if "probe" in stack]
+    assert {"gather", "select_n"} <= {name for name, _, _ in new_code}
+    for name, stack, eqn in new_code:
+        assert "score_update" in stack[:stack.index("probe")], (name, stack)
+    lookups = [eqn for name, stack, eqn in eqns
+               if name == "gather" and "score_update" in stack
+               and size(eqn) >= ROWS]
+    assert len(lookups) == -(-leaves // LOOKUP_PIECE)
+    for eqn in lookups:
+        assert eqn.invars[0].aval.shape[0] <= LOOKUP_PIECE
+    moves = [(name, eqn) for name, stack, eqn in eqns
+             if name in ("scatter", "scatter-add", "sort")
+             and "score_update" in stack and size(eqn) >= learner.n_pad]
+    assert [name for name, _ in moves] == ["sort"]
+    assert moves[0][1].params["num_keys"] == 1 and len(moves[0][1].invars) == 2
+
+
 # -------------------------------------- 2. scopereduce on a built XSpace
 def varint(n):
     out = bytearray()
@@ -396,6 +481,8 @@ def test_fused_block_child_spans(trained):
     # the engine the learner's partition step compiled to, beside it
     gauges = trained["gbdt"].metrics.snapshot()["gauges"]
     assert gauges["partition_engine"] == "xla"
+    # and the end-of-tree un-permute + leaf-value lookup it traced
+    assert gauges["score_update_form"] == "sort_kv+take"
 
 
 @pytest.mark.parametrize("max_bin,rows,features", [(63, 64, 4),
@@ -553,6 +640,44 @@ def test_scopes_survive_the_tpu_compiler(one_chip):
             # copied, transposed or not, for a window to be cut out
             assert not re.search(r"= [sf]32\[[84],16384\]\S* copy\(", ln), \
                 ln[:200]
+    # the end of the tree: `row_leaf` is `pos_leaf` sorted by `perm`, one
+    # two-operand sort and no scatter behind it
+    tail = [ln for ln in text.splitlines()
+            if re.search(r'op_name="[^"]*/score_update/', ln)]
+    assert not any(re.search(r" scatter\(", ln) for ln in tail)
+    sorts = [ln for ln in tail if re.search(r" sort\(", ln)]
+    assert len(sorts) == 1 and "s32[16384]" in sorts[0], sorts
+
+
+@pytest.mark.parametrize("leaves,classes", [(63, 0), (64, 0), (255, 0),
+                                            (255, 3)])
+def test_leaf_lookup_compiles_to_selects(one_chip, leaves, classes):
+    """The chip's compiler turns every piece of `leaf_lookup` into the
+    select chain it keeps for a small table, inside the add's loop
+    fusion: no `gather` is left at 63, 64 or 255 leaves, nor for K
+    classes looked up a class at a time (`lax.map`, as the fused step's
+    vmapped builder does: batched by `vmap` the same lookup compiles to
+    gathers again)."""
+    from lightgbm_tpu.models.score_updater import leaf_lookup
+    n = 4 * 4096
+    shrink = jnp.float32(0.1)
+
+    def one(score, table, index):
+        return score + leaf_lookup(table * shrink, index[:n])
+
+    def per_class(score, table, index):
+        return score + jax.lax.map(
+            lambda o: leaf_lookup(o[0] * shrink, o[1][:n]), (table, index))
+
+    k = (classes,) if classes else ()
+    args = [jax.ShapeDtypeStruct(k + s, d, sharding=one_chip)
+            for s, d in [((n,), jnp.float32), ((leaves,), jnp.float32),
+                         ((n + 4096,), jnp.int32)]]
+    with fresh_compiles():
+        text = jax.jit(per_class if classes else one).lower(
+            *args).compile().as_text()
+    assert not re.search(r" gather\(", text)
+    assert len(re.findall(r" select\(", text)) >= leaves - 1
 
 
 @pytest.mark.parametrize("f,w,b,result", [

@@ -20,6 +20,8 @@ measured numbers instead of guesses:
                  alone on the same window, and on a TPU the kernel
                  `partition_rows` (ops/partition.py) on the same segment,
                  after checking the two bit-equal
+  - score_update: the end of a tree, generic scatter + gather against
+                 the program's un-permute + leaf-value lookup, bit-equal
   - fused_iter:  one full boosting iteration (gradients + whole tree +
                  score update) for BOTH builders at the bench config
 
@@ -35,7 +37,7 @@ not in the table is an error, not a default.
 Usage:  python tools/microbench.py [N] [K] [rows]
         rows: comma-separated row groups to run (default all): stream,
         take, cumsum, argsort, masked_hist, segment_hist, partition,
-        fused_iter
+        score_update, fused_iter
 """
 
 import os
@@ -263,6 +265,70 @@ def bench_partition(n_pad, k, words28, ghc_t):
             f"partition_rows seg={seg}", step_bytes=moved)
 
 
+def bench_score_update(n_pad, k, rng):
+    """The end of a tree (scope `score_update`): a permutation's worth of
+    leaf indices back to row order, then each row's leaf value added to
+    the score, at 63 and 255 leaves. The generic forms (a scatter that
+    does not say its indices are unique; `jnp.take` from the whole
+    table, scaled a row at a time) against the program's
+    (`ops/partition.py unpermute`, `models/score_updater.py
+    leaf_lookup` on the scaled table), first checked bit-equal."""
+    from lightgbm_tpu.models.score_updater import leaf_lookup, lookup_form
+    from lightgbm_tpu.ops.partition import unpermute
+
+    n = n_pad - n_pad // 11            # pad rows behind the real ones
+    perm = jnp.asarray(rng.permutation(n_pad).astype(np.int32))
+    shrink = jnp.float32(0.1)
+
+    def scatter(p, v):
+        return jnp.zeros(n_pad, jnp.int32).at[p].set(v)
+
+    def scatter_unique(p, v):
+        return jnp.zeros(n_pad, jnp.int32).at[p].set(
+            v, unique_indices=True, indices_are_sorted=False)
+
+    moves = {"scatter": scatter, "scatter_unique": scatter_unique,
+             "sort_kv": unpermute}
+    pos = jnp.asarray(rng.randint(0, 255, n_pad).astype(np.int32))
+    for name, move in moves.items():
+        chain_time(lambda c, move=move: (c[0], move(c[0], c[1])),
+                   lambda i: (perm, jnp.roll(pos, i)), k,
+                   f"un-permute {name}", step_bytes=12 * n_pad)
+
+    for leaves in (63, 255):
+        pos = jnp.asarray(rng.randint(0, leaves, n_pad).astype(np.int32))
+        table = jnp.asarray((rng.randn(leaves) * 0.3).astype(np.float32))
+        score = jnp.asarray(rng.randn(n).astype(np.float32))
+        forms = {
+            "scatter+take": (scatter, lambda t, i: jnp.take(t, i) * shrink),
+            f"sort_kv+{lookup_form(leaves)}":
+                (unpermute, lambda t, i: leaf_lookup(t * shrink, i)),
+        }
+        want = None
+        for name, (move, value) in forms.items():
+            # the update alone: next to the add, the CPU's compiler may
+            # contract the generic form's multiply into an FMA
+            got = np.asarray(jax.jit(lambda move=move, value=value: value(
+                table, move(perm, pos)[:n]))()).view(np.int32)
+            want = got if want is None else want
+            if not np.array_equal(got, want):
+                raise SystemExit(f"score_update {name}: bits differ from "
+                                 "the generic forms'")
+
+            def whole(carry, move=move, value=value):
+                s, v = carry
+                # the table rides the carry, and the next step's leaves
+                # are this step's in row order, so XLA can hoist
+                # neither the lookup nor the un-permute out of the chain
+                t = table + s[:1] * jnp.float32(1e-30)
+                row_leaf = move(perm, v)
+                return s + value(t, row_leaf[:n]), row_leaf
+
+            chain_time(whole, lambda i: (score + np.float32(i), pos), k,
+                       f"score_update {name} l={leaves}",
+                       step_bytes=12 * n_pad + 12 * n)
+
+
 def bench_fused_iter(n_pad, k):
     # ---- the ACTUAL bench unit: one full fused boosting iteration
     # (gradients + whole partitioned tree + score update) at the bench
@@ -351,6 +417,8 @@ def main():
         bench_segment_hist(n_pad, k, words28, ghc_t)
     if want("partition"):
         bench_partition(n_pad, k, words28, ghc_t)
+    if want("score_update"):
+        bench_score_update(n_pad, k, rng)
     if want("fused_iter"):
         bench_fused_iter(n_pad, k)
 
