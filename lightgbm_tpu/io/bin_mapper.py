@@ -43,24 +43,7 @@ class BinMapper:
         values = np.sort(np.asarray(sample_values, dtype=np.float64))
         zero_cnt = int(total_sample_cnt - len(values))
 
-        # build (distinct_values, counts) with the zero block inserted in order
-        distinct_values, counts = [], []
-        if len(values) == 0 or (values[0] > 0.0 and zero_cnt > 0):
-            distinct_values.append(0.0)
-            counts.append(zero_cnt)
-        if len(values) > 0:
-            uniq, cnt = np.unique(values, return_counts=True)
-            for i, (v, c) in enumerate(zip(uniq.tolist(), cnt.tolist())):
-                if i > 0 and uniq[i - 1] < 0.0 and v > 0.0:
-                    distinct_values.append(0.0)
-                    counts.append(zero_cnt)
-                distinct_values.append(v)
-                counts.append(int(c))
-                if v == 0.0:
-                    counts[-1] += zero_cnt
-            if uniq[-1] < 0.0 and zero_cnt > 0:
-                distinct_values.append(0.0)
-                counts.append(zero_cnt)
+        distinct_values, counts = _distinct_with_zero(values, zero_cnt)
 
         num_values = len(distinct_values)
         sample_size = float(total_sample_cnt)
@@ -73,21 +56,19 @@ class BinMapper:
                     self.bin_upper_bound = np.asarray([np.inf])
                 else:
                     ub = np.empty(num_values)
-                    dv = np.asarray(distinct_values)
-                    ub[:-1] = (dv[:-1] + dv[1:]) / 2.0
+                    ub[:-1] = (distinct_values[:-1] + distinct_values[1:]) / 2.0
                     ub[-1] = np.inf
                     self.bin_upper_bound = ub
-                    cnt_in_bin0 = counts[0]
+                    cnt_in_bin0 = int(counts[0])
             else:
-                ub, cnt_in_bin0 = _greedy_bounds(
-                    np.asarray(distinct_values), np.asarray(counts, dtype=np.int64),
-                    sample_size, max_bin)
+                ub, cnt_in_bin0 = _greedy_bounds(distinct_values, counts,
+                                                 sample_size, max_bin)
                 self.bin_upper_bound = ub
                 self.num_bin = len(ub)
         else:
             dv_int = []
             cnt_int = []
-            for v, c in zip(distinct_values, counts):
+            for v, c in zip(distinct_values.tolist(), counts.tolist()):
                 iv = int(v)
                 if dv_int and iv == dv_int[-1]:
                     cnt_int[-1] += c
@@ -167,42 +148,69 @@ class BinMapper:
         return np.array_equal(self.bin_2_categorical, other.bin_2_categorical)
 
 
+def _distinct_with_zero(values, zero_cnt):
+    """(distinct values, counts) of the sorted non-zero sample `values`
+    with the implied zeros in their place (bin.cpp:52-86): 0.0 carries
+    `zero_cnt`, and is a distinct value wherever the sample changes
+    sign, even with no zero in it."""
+    if len(values) == 0:
+        return np.zeros(1), np.asarray([zero_cnt], dtype=np.int64)
+    uniq, cnt = np.unique(values, return_counts=True)
+    cnt = cnt.astype(np.int64)
+    k = int(np.searchsorted(uniq, 0.0))       # first value >= 0
+    if k < len(uniq) and uniq[k] == 0.0:
+        cnt[k] += zero_cnt
+    elif 0 < k < len(uniq) or zero_cnt > 0:   # a sign change, or zeros at an end
+        uniq, cnt = np.insert(uniq, k, 0.0), np.insert(cnt, k, zero_cnt)
+    return uniq, cnt
+
+
 def _greedy_bounds(distinct_values, counts, sample_size, max_bin):
-    """Greedy equal-frequency bound finding (bin.cpp:100-153)."""
+    """Greedy equal-frequency bound finding (bin.cpp:100-153): walking
+    the distinct values, a bin closes at a value that holds a mean bin's
+    share on its own ("big"), where the bin's count reaches the mean of
+    what remains, or before a big value once it holds half that mean.
+    Between two closings the mean does not change, so the next closing
+    is found by a search over the cumulated counts and the next big
+    value: a step a bin, not a step a distinct value (50,000 of them a
+    continuous column, times thousands of columns)."""
     num_values = len(distinct_values)
     mean_bin_size = sample_size / max_bin
     rest_bin_cnt = max_bin
-    rest_sample_cnt = int(sample_size)
     is_big = counts >= mean_bin_size
     rest_bin_cnt -= int(np.sum(is_big))
-    rest_sample_cnt -= int(np.sum(counts[is_big]))
+    rest_sample_cnt = int(sample_size) - int(np.sum(counts[is_big]))
     mean_bin_size = rest_sample_cnt / rest_bin_cnt if rest_bin_cnt > 0 else np.inf
 
-    upper_bounds = np.full(max_bin, np.inf)
-    lower_bounds = np.full(max_bin, np.inf)
-    bin_cnt = 0
-    lower_bounds[0] = distinct_values[0]
-    cur_cnt_inbin = 0
-    cnt_in_bin0 = 0
-    for i in range(num_values - 1):
+    cum = np.cumsum(counts)                          # counts through i
+    cum_small = np.cumsum(np.where(is_big, 0, counts))
+    big_at = np.flatnonzero(is_big)
+    closes = []                    # the last value of every closed bin
+    start, before, cnt_in_bin0 = 0, 0, 0
+    while start < num_values - 1 and len(closes) < max_bin - 1:
+        # the first big value at or after `start`: it closes its bin,
+        # or the value before it does (with half a mean bin in it)
+        nxt = int(np.searchsorted(big_at, start))
+        i = int(big_at[nxt]) if nxt < len(big_at) else num_values
+        if i > start and cum[i - 1] - before >= max(1.0, mean_bin_size * 0.5):
+            i -= 1
+        if np.isfinite(mean_bin_size):
+            # integer counts: cur >= mean <=> cur >= ceil(mean)
+            full = int(np.searchsorted(
+                cum, before + int(np.ceil(mean_bin_size)), side="left"))
+            i = min(i, max(full, start))
+        if i > num_values - 2:
+            break
+        if not closes:
+            cnt_in_bin0 = int(cum[i]) - before
+        closes.append(i)
         if not is_big[i]:
-            rest_sample_cnt -= counts[i]
-        cur_cnt_inbin += counts[i]
-        if (is_big[i] or cur_cnt_inbin >= mean_bin_size or
-                (is_big[i + 1] and cur_cnt_inbin >= max(1.0, mean_bin_size * 0.5))):
-            upper_bounds[bin_cnt] = distinct_values[i]
-            if bin_cnt == 0:
-                cnt_in_bin0 = cur_cnt_inbin
-            bin_cnt += 1
-            lower_bounds[bin_cnt] = distinct_values[i + 1]
-            if bin_cnt >= max_bin - 1:
-                break
-            cur_cnt_inbin = 0
-            if not is_big[i]:
-                rest_bin_cnt -= 1
-                mean_bin_size = rest_sample_cnt / rest_bin_cnt if rest_bin_cnt > 0 else np.inf
-    bin_cnt += 1
-    ub = np.empty(bin_cnt)
-    ub[:-1] = (upper_bounds[:bin_cnt - 1] + lower_bounds[1:bin_cnt]) / 2.0
-    ub[-1] = np.inf
-    return ub, int(cnt_in_bin0)
+            rest_bin_cnt -= 1
+            rest_now = rest_sample_cnt - int(cum_small[i])
+            mean_bin_size = (rest_now / rest_bin_cnt if rest_bin_cnt > 0
+                             else np.inf)
+        start, before = i + 1, int(cum[i])
+    closes = np.asarray(closes, dtype=np.intp)
+    ub = np.append((distinct_values[closes] + distinct_values[closes + 1]) / 2.0,
+                   np.inf)
+    return ub, cnt_in_bin0

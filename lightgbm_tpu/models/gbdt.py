@@ -1033,13 +1033,19 @@ class GBDT:
         `partition_engine` (ops/partition.py: `pallas` on a TPU, `xla`
         off it), and the histogram kernel's one-hot operand
         (ops/ordered_hist.py onehot_extent): `seg_hist_onehot_rows` a
-        feature and `seg_hist_features_per_dot`; and `score_update_form`,
-        the end-of-tree un-permute and leaf-value lookup that were traced
-        (ops/partition.py unpermute, one key-value sort, + score_updater.py
-        lookup_form: `sort_kv+split64` at 255 leaves)."""
+        feature and `seg_hist_features_per_dot`; the grid's feature axis
+        (feature_blocks): `seg_hist_feature_blocks` a call (1: no such
+        axis) and `seg_hist_block_features`; what the partition kernel
+        was sized to (pack_rows, chunk_lanes): `partition_rows_words` a
+        row and `partition_rows_chunk_lanes` a DMA; and
+        `score_update_form`, the end-of-tree un-permute and leaf-value
+        lookup that were traced (ops/partition.py unpermute, one
+        key-value sort, + score_updater.py lookup_form:
+        `sort_kv+split64` at 255 leaves)."""
         if getattr(self.tree_learner, "_use_partitioned", False):
-            from ..ops.ordered_hist import onehot_extent
-            from ..ops.partition import partition_engine
+            from ..ops.ordered_hist import feature_blocks, onehot_extent
+            from ..ops.partition import (chunk_lanes, packed_word_rows,
+                                         partition_engine)
             self.metrics.set("partition_engine", partition_engine())
             self.metrics.set(
                 "score_update_form", "sort_kv+"
@@ -1047,6 +1053,14 @@ class GBDT:
             rows, features = onehot_extent(self.tree_learner.max_bin)
             self.metrics.set("seg_hist_onehot_rows", rows)
             self.metrics.set("seg_hist_features_per_dot", features)
+            words = int(self.tree_learner._bins.shape[0])
+            blocks, block_features = feature_blocks(
+                4 * words, self.tree_learner.max_bin)
+            self.metrics.set("seg_hist_feature_blocks", blocks)
+            self.metrics.set("seg_hist_block_features", block_features)
+            wp = packed_word_rows(words)
+            self.metrics.set("partition_rows_words", wp)
+            self.metrics.set("partition_rows_chunk_lanes", chunk_lanes(wp))
 
     def _get_fused_fn(self, num_iters):
         if not hasattr(self, "_fused_cache"):

@@ -132,13 +132,40 @@ def onehot_extent(num_bins_total):
     return -(-num_bins_total // 128) * 128, 1
 
 
+# 136 columns at 63 bins (34 word rows of 256: 4.5 MB, written back
+# through a second buffer) are what a v5e's 16 MB of scoped VMEM were
+# seen to take (PERF.md PR 29); a block is the power of two below.
+ONE_BLOCK_ROWS = 34 * 256
+ACC_BLOCK_ROWS = 32 * 256
+
+
+def feature_blocks(f, num_bins_total):
+    """(feature blocks a call, features a block) of the kernel's grid.
+    The accumulator's last dimension, nine statistics, pads to 128 lanes
+    in VMEM, so what a block may hold beside its double-buffered words
+    and statistics is counted in accumulator rows of 128 lanes: up to
+    ONE_BLOCK_ROWS of them the whole accumulator is one block and the
+    grid has no feature axis; above, a block holds ACC_BLOCK_ROWS (32
+    word rows at 63 bins, 32 features at 255: whole (8, 128) tiles of
+    words either way)."""
+    b_pad, _ = onehot_extent(num_bins_total)
+    if f * b_pad <= ONE_BLOCK_ROWS:
+        return 1, f
+    fb = ACC_BLOCK_ROWS // b_pad
+    return -(-f // fb), fb
+
+
 def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad,
-                     lanes):
-    """One grid step = one HIST_CHUNK block of the sliced segment.
+                     lanes, f_total=None):
+    """One grid step = one HIST_CHUNK block of the sliced segment (of
+    one block of `f` features where the grid has a feature axis: then
+    `f_total` is the call's feature count, the feature block is the
+    outer grid axis and the row block the inner one, so an accumulator
+    block is zeroed at its first row step and written back once).
     `out_ref` is (f, b_pad, 9) at `lanes` = 1 and (ceil(f / 4),
     4 * b_pad, 9) at `lanes` = 4: rows [b_pad * k, b_pad * (k + 1)) of
     word row w are feature 4 w + k."""
-    step = pl.program_id(0)
+    step = pl.program_id(0 if f_total is None else 1)
 
     @pl.when(step == 0)
     def _():
@@ -175,6 +202,19 @@ def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad,
             onehot, ghc_m, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    if f_total is not None:
+        # the last block's word rows end with the call's; the unused
+        # lanes of a partly filled last word land in accumulator rows
+        # past `f_total`, which the block has and the result has not
+        rows_here = jnp.minimum(
+            f // 4, -(-f_total // 4) - pl.program_id(0) * (f // 4))
+
+        def block_body(wi, _):
+            word_row(wi, 4)
+            return 0
+
+        jax.lax.fori_loop(0, rows_here, block_body, 0)
+        return
     if f < ROLL_FEATURES:
         for wi in range(f // 4):
             word_row(wi, 4)
@@ -195,27 +235,45 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
     to validate kernel semantics without TPU hardware."""
     w = words_sl.shape[0]
     b_pad, lanes = onehot_extent(num_bins_total)
-    kernel = functools.partial(_seg_hist_kernel, f=f, b_pad=b_pad,
-                               lanes=lanes)
+    n_fb, fb = feature_blocks(f, num_bins_total)
     acc_shape = ((-(-f // 4), 4 * b_pad, STAT_TERMS) if lanes == 4
                  else (f, b_pad, STAT_TERMS))
     with scope("window"):
         lohi = jnp.stack([lo, hi]).astype(jnp.int32)
         stats = split_stats(ghc_sl)
+    if n_fb == 1:
+        kernel = functools.partial(_seg_hist_kernel, f=f, b_pad=b_pad,
+                                   lanes=lanes)
+        grid = (n_blocks,)
+        words_spec = pl.BlockSpec((w, HIST_CHUNK), lambda i: (0, i),
+                                  memory_space=pltpu.VMEM)
+        stats_spec = pl.BlockSpec((HIST_CHUNK, STAT_TERMS), lambda i: (i, 0),
+                                  memory_space=pltpu.VMEM)
+        out_spec = pl.BlockSpec(acc_shape, lambda i: (0, 0, 0),
+                                memory_space=pltpu.VMEM)
+    else:
+        kernel = functools.partial(_seg_hist_kernel, f=fb, b_pad=b_pad,
+                                   lanes=lanes, f_total=f)
+        grid = (n_fb, n_blocks)
+        words_spec = pl.BlockSpec((fb // 4, HIST_CHUNK), lambda j, i: (j, i),
+                                  memory_space=pltpu.VMEM)
+        stats_spec = pl.BlockSpec((HIST_CHUNK, STAT_TERMS),
+                                  lambda j, i: (i, 0),
+                                  memory_space=pltpu.VMEM)
+        out_spec = pl.BlockSpec((fb // lanes,) + acc_shape[1:],
+                                lambda j, i: (j, 0, 0),
+                                memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         kernel,
         name="seg_hist",  # the kernel's name in a trace, and its scope
         interpret=interpret,
-        grid=(n_blocks,),
+        grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # (2,) lo/hi
-            pl.BlockSpec((w, HIST_CHUNK), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((HIST_CHUNK, STAT_TERMS), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
+            words_spec,
+            stats_spec,
         ],
-        out_specs=pl.BlockSpec(acc_shape, lambda i: (0, 0, 0),
-                               memory_space=pltpu.VMEM),
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
     )(lohi, words_sl, stats)
     with scope("fold"):

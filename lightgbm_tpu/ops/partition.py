@@ -136,17 +136,38 @@ def unpermute(perm, values):
     return jax.lax.sort((perm, values), num_keys=1, is_stable=False)[1]
 
 
-PART_CHUNK = 2048   # lanes a DMA moves; divides HIST_CHUNK
+PART_CHUNK = 2048   # lanes a DMA moves at most; divides HIST_CHUNK
 PART_TILE = 128     # rows one permutation matrix compacts
 _COPY_DEPTH = 4     # chunk copies of the right stream kept in flight
+# VMEM the two chunks read and the two rings (six chunks of `wp` word
+# rows in all) may take of a v5e's 16 MB of scoped VMEM, beside the
+# byte planes of a tile and what the compiler keeps of them
+_CHUNK_VMEM = 8 << 20
+
+
+def chunk_lanes(wp):
+    """Lanes (rows of the data) a DMA of the partition kernel moves: the
+    largest power of two that keeps six chunks of `wp` int32 word rows
+    inside `_CHUNK_VMEM`, PART_CHUNK at most (up to 170 word rows: every
+    row of a few hundred columns) and a tile at least. 512 at the 504
+    word rows of 2,000 columns."""
+    fit = _CHUNK_VMEM // (6 * 4 * wp)
+    return max(min(PART_CHUNK, 1 << (max(fit, 1).bit_length() - 1)),
+               PART_TILE)
+
+
+def packed_word_rows(w):
+    """Rows of the kernel's int32 array for `w` word rows: `perm` rides
+    as one more, in whole (8, 128) tiles."""
+    return -(-(w + 1) // 8) * 8
 
 
 def pack_rows(words, ghc, perm):
     """(W, N) words, (3, N) stats, (N,) perm -> the kernel's arrays:
-    (WP, N) int32 [words; zeros; perm] with WP = 8 * ceil((W + 1) / 8),
+    (WP, N) int32 [words; zeros; perm] with WP = packed_word_rows(W),
     and (4, N) float32 [stats; zeros]."""
     w, n = words.shape
-    wp = -(-(w + 1) // 8) * 8
+    wp = packed_word_rows(w)
     rows_i = jnp.concatenate(
         [words, jnp.zeros((wp - w - 1, n), jnp.int32), perm[None, :]],
         axis=0)
@@ -161,12 +182,13 @@ def unpack_rows(rows_i, rows_f, w):
 
 def _partition_rows_kernel(sc, ri_in, rf_in, go_hbm, ri, rf, si, sf,
                            ibuf, fbuf, gbuf, li, lf, rgi, rgf, xs, ys,
-                           tri, sem_in, sem_fl, sem_cp, *, wp):
+                           tri, sem_in, sem_fl, sem_cp, *, wp, c):
     """sc = [seg_b, seg_c, n_left]. ri/rf are the arrays, in place
     (ri_in/rf_in alias them and are not touched); si/sf the HBM scratch
-    of the right stream; go_hbm the (1, N) 0/1 decision vector."""
+    of the right stream; go_hbm the (1, N) 0/1 decision vector; `c` the
+    lanes of a chunk (chunk_lanes)."""
     del ri_in, rf_in
-    c, t = PART_CHUNK, PART_TILE
+    t = PART_TILE
     i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
     seg_b, seg_c, n_left = sc[0], sc[1], sc[2]
     seg_e = seg_b + seg_c
@@ -357,7 +379,7 @@ def partition_rows(rows_i, rows_f, go_left, seg_b, seg_c, n_left,
 
     Args:
       rows_i: (WP, N) int32, rows_f: (4, N) float32, N a multiple of
-        PART_CHUNK.
+        PART_CHUNK (of `chunk_lanes(WP)`, which divides it).
       go_left: (N,) the decision of every position (only the segment's
         values matter).
       seg_b, seg_c: traced int32 segment bounds.
@@ -368,7 +390,7 @@ def partition_rows(rows_i, rows_f, go_left, seg_b, seg_c, n_left,
     runs the kernel body in pallas interpret mode (CPU tests).
     """
     wp, n = rows_i.shape
-    c, t = PART_CHUNK, PART_TILE
+    c, t = chunk_lanes(wp), PART_TILE
     if n % c or wp % 8 or rows_f.shape != (4, n):
         raise ValueError(f"partition_rows: bad shapes {rows_i.shape} "
                          f"{rows_f.shape} (N a multiple of {c})")
@@ -377,7 +399,7 @@ def partition_rows(rows_i, rows_f, go_left, seg_b, seg_c, n_left,
     planes = 4 * wp + 16
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
-        functools.partial(_partition_rows_kernel, wp=wp),
+        functools.partial(_partition_rows_kernel, wp=wp, c=c),
         name="partition_rows",   # the kernel's name in a trace
         interpret=interpret,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -1,6 +1,7 @@
 """BinMapper semantics (reference src/io/bin.cpp:44-268)."""
 
 import numpy as np
+import pytest
 
 from lightgbm_tpu.io.bin_mapper import BinMapper, CATEGORICAL
 
@@ -116,3 +117,94 @@ def test_device_binning_matches_host(monkeypatch):
     for mh, md in zip(host.bin_mappers, dev.bin_mappers):
         np.testing.assert_array_equal(mh.bin_upper_bound,
                                       md.bin_upper_bound)
+
+
+def _find_bin_by_loop(values, total, max_bin):
+    """Numerical find_bin as it was written before PR 33: a Python step
+    a distinct value, twice (bin.cpp:52-153 line for line). Kept here as
+    the oracle of the search-based form."""
+    values = np.sort(np.asarray(values, dtype=np.float64))
+    zero_cnt = int(total - len(values))
+    dv, cn = [], []
+    if len(values) == 0 or (values[0] > 0.0 and zero_cnt > 0):
+        dv.append(0.0)
+        cn.append(zero_cnt)
+    if len(values) > 0:
+        uniq, cnt = np.unique(values, return_counts=True)
+        for i, (v, c) in enumerate(zip(uniq.tolist(), cnt.tolist())):
+            if i > 0 and uniq[i - 1] < 0.0 and v > 0.0:
+                dv.append(0.0)
+                cn.append(zero_cnt)
+            dv.append(v)
+            cn.append(int(c))
+            if v == 0.0:
+                cn[-1] += zero_cnt
+        if uniq[-1] < 0.0 and zero_cnt > 0:
+            dv.append(0.0)
+            cn.append(zero_cnt)
+    dv, cn = np.asarray(dv), np.asarray(cn, dtype=np.int64)
+    if len(dv) <= max_bin:
+        return np.append((dv[:-1] + dv[1:]) / 2.0, np.inf), int(cn[0])
+    mean = total / max_bin
+    rest_bins, rest_cnt = max_bin, int(total)
+    big = cn >= mean
+    rest_bins -= int(big.sum())
+    rest_cnt -= int(cn[big].sum())
+    mean = rest_cnt / rest_bins if rest_bins > 0 else np.inf
+    upper, lower = np.full(max_bin, np.inf), np.full(max_bin, np.inf)
+    k, cur, first = 0, 0, 0
+    lower[0] = dv[0]
+    for i in range(len(dv) - 1):
+        if not big[i]:
+            rest_cnt -= cn[i]
+        cur += cn[i]
+        if (big[i] or cur >= mean
+                or (big[i + 1] and cur >= max(1.0, mean * 0.5))):
+            upper[k] = dv[i]
+            if k == 0:
+                first = cur
+            k += 1
+            lower[k] = dv[i + 1]
+            if k >= max_bin - 1:
+                break
+            cur = 0
+            if not big[i]:
+                rest_bins -= 1
+                mean = rest_cnt / rest_bins if rest_bins > 0 else np.inf
+    k += 1
+    return np.append((upper[:k - 1] + lower[1:k]) / 2.0, np.inf), int(first)
+
+
+@pytest.mark.parametrize("kind", ["continuous", "rounded", "positive",
+                                  "negative", "small_integers", "one_big_value",
+                                  "tenths", "integers_and_noise"])
+def test_find_bin_is_the_loop_it_replaced(kind):
+    """The greedy bounds are found a bin at a time (a search over the
+    cumulated counts and the next big value), not a distinct value at a
+    time: the same bounds, bin-0 count and all, as the loop, over sparse
+    and dense samples, big values, sign changes and every `max_bin`."""
+    rng = np.random.RandomState(len(kind))
+    for trial in range(120):
+        n = int(rng.randint(1, 400))
+        v = {
+            "continuous": lambda: rng.randn(n),
+            "rounded": lambda: np.round(rng.randn(n) * 3),
+            "positive": lambda: np.abs(rng.randn(n)),
+            "negative": lambda: -np.abs(rng.randn(n)),
+            "small_integers": lambda: rng.randint(-3, 4, n).astype(float),
+            "one_big_value": lambda: np.concatenate(
+                [np.full(n // 2 + 1, 2.5), rng.randn(n)]),
+            "tenths": lambda: np.round(rng.randn(n), 1),
+            "integers_and_noise": lambda: np.concatenate(
+                [rng.randint(0, 3, n).astype(float), rng.randn(n // 3)]),
+        }[kind]()
+        v = v[np.abs(v) > 1e-10]
+        total = len(v) + int(rng.randint(0, 3)) * int(rng.randint(0, 200))
+        if total == 0:
+            continue
+        for max_bin in (2, 3, 5, 16, 63, 255):
+            m = BinMapper().find_bin(v, total, max_bin)
+            bounds, first = _find_bin_by_loop(v, total, max_bin)
+            np.testing.assert_array_equal(m.bin_upper_bound, bounds)
+            assert m.num_bin == len(bounds)
+            assert m.sparse_rate == first / float(total)

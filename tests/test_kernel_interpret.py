@@ -383,6 +383,83 @@ def test_segment_kernel_63_bins_same_sums(columns, f):
                                   np.full(f, 2048))
 
 
+# ------------------- a feature axis in the histogram kernel's grid (PR 33)
+@pytest.mark.parametrize("f,b,blocks,block_features", [
+    (28, 63, 1, 28), (136, 63, 1, 136), (140, 63, 2, 128),
+    (2000, 63, 16, 128), (28, 255, 1, 28), (34, 255, 1, 34),
+    (35, 255, 2, 32), (2000, 255, 63, 32), (68, 100, 1, 68)])
+def test_feature_blocks_follow_the_accumulator(f, b, blocks, block_features):
+    """One block up to 34 x 256 accumulator rows of 128 lanes (the cells
+    the benchmark had before PR 33: 28 and 136 columns at 63 bins, 28 at
+    255), blocks of 32 x 256 rows above: 128 features at up to 64 bins,
+    64 at up to 128, 32 at 255."""
+    from lightgbm_tpu.ops.ordered_hist import feature_blocks
+    assert feature_blocks(f, b) == (blocks, block_features)
+
+
+@pytest.mark.parametrize("f,b,blocks,block_features", [
+    (130, 63, 1, 130), (150, 63, 2, 128), (515, 63, 5, 128),
+    (130, 255, 5, 32), (515, 255, 17, 32), (69, 100, 2, 64)])
+def test_segment_kernel_feature_blocks(f, b, blocks, block_features):
+    """Past 136 columns at 63 bins (34 at 255) the accumulator no longer
+    fits VMEM whole, and the grid gets a feature axis: blocks of 32 word
+    rows (128 features) at 63 bins, of 32 features at 255. More than one
+    block, a partly filled last word row (130, 150, 515 columns) and a
+    partly filled last block: counts equal to the XLA formulation's to
+    the bit, sums to float32 rounding; the accumulator keeps its shape
+    and a block's shape is what `feature_blocks` says."""
+    from lightgbm_tpu.ops.ordered_hist import (_seg_hist_tpu, _seg_hist_xla,
+                                               feature_blocks, onehot_extent)
+    assert feature_blocks(f, b) == (blocks, block_features)
+    args, _ = _seg_hist_case(f, f, b, seed=f + b)
+    rows, lanes = onehot_extent(b)
+    jaxpr = jax.make_jaxpr(
+        lambda w, g, lo, hi: _seg_hist_tpu(w, g, lo, hi, *args[4:],
+                                           interpret=True))(*args[:4])
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"].grid
+    assert grid == ((blocks, 2) if blocks > 1 else (2,))
+    assert call.outvars[0].aval.shape == (
+        (-(-f // 4), 256, 9) if lanes == 4 else (f, rows, 9))
+    got = np.asarray(_seg_hist_tpu(*args, interpret=True))
+    want = np.asarray(_seg_hist_xla(*args[:6]))
+    assert got.shape == (f, b, 3)
+    np.testing.assert_array_equal(got[..., 2], want[..., 2])
+    np.testing.assert_array_equal(got[..., 2].sum(axis=1), np.full(f, 2048))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("f,w,b", [(28, 8, 63), (136, 40, 63), (135, 40, 63),
+                                   (28, 8, 255)])
+def test_segment_kernel_one_block_has_no_feature_axis(f, w, b):
+    """At the columns of the cells the benchmark had before PR 33 the
+    whole accumulator is one block: the kernel call has the one grid
+    axis (row blocks) and the block shapes it always had, and its body
+    reads `program_id(0)` alone."""
+    from lightgbm_tpu.ops.ordered_hist import _seg_hist_tpu, onehot_extent
+    n_blocks = 4
+    n = n_blocks * HIST_CHUNK
+    jaxpr = jax.make_jaxpr(
+        lambda words, ghc, lo, hi: _seg_hist_tpu(words, ghc, lo, hi, f, b,
+                                                 n_blocks))(
+        jax.ShapeDtypeStruct((w, n), jnp.int32),
+        jax.ShapeDtypeStruct((n, 3), jnp.float32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32))
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (n_blocks,)
+    rows, lanes = onehot_extent(b)
+    acc = (-(-f // 4), 256, 9) if lanes == 4 else (f, rows, 9)
+    shapes = [tuple(getattr(d, "block_size", d) for d in bm.block_shape)
+              for bm in mapping.block_mappings]
+    assert shapes == [(2,), (w, HIST_CHUNK), (HIST_CHUNK, 9), acc]
+    body = str(call.params["jaxpr"])
+    assert "program_id[axis=0]" in body and "program_id[axis=1]" not in body
+
+
 @pytest.mark.parametrize("case", ["begin_not_tile_aligned",
                                   "across_a_chunk_edge", "whole_array"])
 def test_partition_kernel_interpret_40_word_rows(case):
@@ -428,3 +505,46 @@ def test_partition_kernel_interpret_40_word_rows(case):
         np.testing.assert_array_equal(
             np.asarray(g).view(np.int32), np.asarray(w).view(np.int32),
             err_msg=f"{case}: {name}")
+
+
+# --------------------- the partition kernel at 2 KB rows (PR 33)
+@pytest.mark.parametrize("wp,lanes", [(8, 2048), (40, 2048), (136, 2048),
+                                      (176, 1024), (504, 512)])
+def test_partition_chunk_follows_the_row(wp, lanes):
+    """The chunk a DMA of `partition_rows` moves is sized so that the
+    two chunks read and the two rings fit VMEM: 2,048 lanes up to 170
+    word rows (the cells the benchmark had: 8 and 40), 512 at the 504 of
+    2,000 columns."""
+    from lightgbm_tpu.ops.partition import chunk_lanes
+    assert chunk_lanes(wp) == lanes
+
+
+@pytest.mark.parametrize("wp,case", [
+    (136, "begin_not_tile_aligned"), (136, "whole_array"),
+    (504, "begin_not_tile_aligned"), (504, "across_a_chunk_edge"),
+    (504, "whole_array"), (504, "one_row")])
+def test_partition_kernel_interpret_wide_rows(wp, case):
+    """`partition_rows` at wp = 136 and wp = 504 (2,000 columns: 500
+    words + 3 of padding + perm; chunks of 512 lanes, 2,032 byte planes
+    a tile) against the numpy stable partition: every word, statistic
+    and perm entry in its place, exactly."""
+    from lightgbm_tpu.ops.partition import chunk_lanes, partition_rows
+    rng = np.random.RandomState(wp)
+    n = _N
+    rows_i = rng.randint(-2**31, 2**31 - 1, size=(wp, n),
+                         dtype=np.int64).astype(np.int32)
+    rows_f = rng.randn(4, n).astype(np.float32)
+    go = rng.rand(n) < 0.37
+    seg_b, seg_c = PARTITION_CASES[case][:2]
+    assert n % chunk_lanes(wp) == 0
+    seg = slice(seg_b, seg_b + seg_c)
+    order = np.arange(n)
+    order[seg] = np.concatenate([order[seg][go[seg]], order[seg][~go[seg]]])
+    got_i, got_f = jax.jit(
+        lambda ri, rf, g, b, c, nl: partition_rows(ri, rf, g, b, c, nl,
+                                                   interpret=True))(
+        jnp.asarray(rows_i), jnp.asarray(rows_f), jnp.asarray(go),
+        jnp.int32(seg_b), jnp.int32(seg_c), jnp.int32(go[seg].sum()))
+    np.testing.assert_array_equal(np.asarray(got_i), rows_i[:, order])
+    np.testing.assert_array_equal(np.asarray(got_f).view(np.int32),
+                                  rows_f[:, order].view(np.int32))

@@ -365,6 +365,43 @@ def test_partitioned_bundled_fused_matches_per_iter():
                                       tf.threshold_in_bin)
 
 
+@pytest.mark.parametrize("max_bin", [63, 255])
+def test_fused_matches_per_iteration_at_300_columns(max_bin):
+    """Wide dense rows (PR 33): 1,000 x 300, 75 packed words a row. The
+    fused scan and the per-iteration loop grow the same trees, and the
+    gauges say what the kernels would be sized to (an accumulator in
+    feature blocks, a partition chunk that follows the row)."""
+    rng = np.random.RandomState(33)
+    n, f = 1000, 300
+    x = rng.randn(n, f).astype(np.float32)
+    x /= np.linalg.norm(x, axis=1, keepdims=True)
+    w = rng.randn(f) / np.sqrt(np.arange(1, f + 1))
+    y = (x @ w + 0.02 * rng.randn(n) > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 15, "max_bin": max_bin,
+              "min_data_in_leaf": 1, "min_sum_hessian_in_leaf": 5.0,
+              "metric_freq": 0, "partitioned_build": "true"}
+    b_seq = _booster(x, y, params)
+    assert b_seq.tree_learner._use_partitioned
+    assert b_seq.tree_learner._bins.shape[0] == 75
+    for _ in range(3):
+        b_seq.train_one_iter(is_eval=False)
+    b_fused = _booster(x, y, params)
+    assert b_fused._fused_eligible()
+    b_fused.train_many(3)
+    assert len(b_seq.models) == len(b_fused.models) == 3
+    for ts, tf in zip(b_seq.models, b_fused.models):
+        assert len(ts.split_feature) == 14
+        np.testing.assert_array_equal(ts.split_feature, tf.split_feature)
+        np.testing.assert_array_equal(ts.threshold_in_bin,
+                                      tf.threshold_in_bin)
+        np.testing.assert_array_equal(ts.leaf_value, tf.leaf_value)
+    gauges = b_fused.metrics.snapshot()["gauges"]
+    assert gauges["seg_hist_feature_blocks"] == (3 if max_bin == 63 else 10)
+    assert gauges["seg_hist_block_features"] == (128 if max_bin == 63 else 32)
+    assert gauges["partition_rows_words"] == 80
+    assert gauges["partition_rows_chunk_lanes"] == 2048
+
+
 def test_builder_engines_grow_the_same_tree(monkeypatch):
     """The TPU engine of the partition step (ops/partition.py
     partition_rows, here through the Pallas interpreter, with the

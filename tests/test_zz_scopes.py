@@ -503,6 +503,12 @@ def test_seg_hist_onehot_gauges(max_bin, rows, features):
     gauges = booster.gbdt.metrics.snapshot()["gauges"]
     assert gauges["seg_hist_onehot_rows"] == rows
     assert gauges["seg_hist_features_per_dot"] == features
+    # four columns: one word row, the whole accumulator one block, the
+    # partition kernel's row one tile of words and a full chunk
+    assert gauges["seg_hist_feature_blocks"] == 1
+    assert gauges["seg_hist_block_features"] == 4
+    assert gauges["partition_rows_words"] == 8
+    assert gauges["partition_rows_chunk_lanes"] == 2048
 
 
 def test_process_tracer_holds_dataset_spans(trained):
@@ -705,3 +711,36 @@ def test_seg_hist_compiles_at_the_cells_widths(one_chip, f, w, b, result):
     (kernel,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert re.match(rf"\s*(ROOT )?%seg_hist[.\d]* = {re.escape(result)}",
                     kernel), kernel[:200]
+
+
+@pytest.mark.parametrize("b,blocks", [(63, 16), (255, 63)])
+def test_builder_compiles_at_2000_columns(one_chip, b, blocks):
+    """The chip's compiler takes the tree builder at Epsilon's 2,000
+    columns (500 words a row, 504 in the partition kernel's array): the
+    histogram kernel with its feature axis (16 blocks of 128 features at
+    63 bins, 63 of 32 at 255) and the partition kernel at 512-lane
+    chunks, each under its own name and scope."""
+    from lightgbm_tpu.ops.ordered_hist import feature_blocks
+    from lightgbm_tpu.ops.partition import chunk_lanes, packed_word_rows
+    assert feature_blocks(2000, b)[0] == blocks
+    assert chunk_lanes(packed_word_rows(500)) == 512
+    core, shapes = builder(2 * 4096, w=500, b=b, l=3)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    with fresh_compiles(), \
+            mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(core).lower(*args).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    names = set()
+    for ln in kernels:
+        name = re.match(r"\s*(ROOT )?%([a-z_]+)[.\d]* = ", ln).group(2)
+        names.add(name)
+        path = re.search(r'op_name="([^"]*)"', ln).group(1).split("/")
+        if name == "partition_rows":
+            assert "s32[504,8192]" in ln, ln[:200]
+            assert path.index("partition") < path.index("move"), ln[:200]
+        else:
+            acc = "f32[500,256,9]" if b == 63 else "f32[2000,256,9]"
+            assert acc in ln, ln[:200]
+            assert path.index("hist") < path.index("seg_hist"), ln[:200]
+    assert names == {"seg_hist", "partition_rows"}
