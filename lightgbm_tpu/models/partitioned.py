@@ -59,7 +59,8 @@ from ..ops.partition import (apply_partition, invert_permutation,
                              split_destinations, unpermute)
 from ..ops.split import SplitParams, find_best_split, K_MIN_SCORE
 from ..telemetry.trace import scope
-from .tree_learner import apply_tree_split, init_split_state, write_candidate
+from .tree_learner import (apply_tree_split, init_split_state,
+                           split_hist_cache, write_candidate)
 
 
 def _partition_segment_rows(rows_i, rows_f, seg_b, seg_c, feat, thr, cat,
@@ -370,14 +371,10 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
                 hist_small = leaf_histogram(st["words"], st["ghc"],
                                             small_b, small_c)
                 with scope("tree_state"), scope("hist_cache"):
-                    hist_large = st["hist_cache"][best_leaf] - hist_small
-                    hist_left = jnp.where(left_is_small, hist_small,
-                                          hist_large)
-                    hist_right = jnp.where(left_is_small, hist_large,
-                                           hist_small)
-                    st["hist_cache"] = (st["hist_cache"]
-                                        .at[best_leaf].set(hist_left)
-                                        .at[right_id].set(hist_right))
+                    st["hist_cache"], hist_left, hist_right = (
+                        split_hist_cache(st["hist_cache"], best_leaf,
+                                         right_id, hist_small,
+                                         left_is_small))
             else:
                 # memory-bounded mode: both children's segments scanned
                 hist_left = leaf_histogram(st["words"], st["ghc"],

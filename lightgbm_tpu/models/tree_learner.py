@@ -173,6 +173,28 @@ def write_candidate(st, leaf_id, sp, gain_v):
     return st
 
 
+def split_hist_cache(cache, best_leaf, right_id, hist_small,
+                     left_is_small):
+    """A split's update of the per-leaf histogram cache: the larger
+    child by parent subtraction, then the parent's row takes the left
+    child and row `right_id` the right one. Returns (cache, hist_left,
+    hist_right).
+
+    The parent's row is read once, before either write: both children
+    pass an optimization barrier as finished values, so the compiler
+    cannot fuse the subtraction a second time into the second write.
+    A read of the old cache placed after a write keeps the old buffer
+    alive, and the loop then copies the whole (L, F, B, 3) carry twice
+    a split, where the writes are two rows in place."""
+    hist_large = cache[best_leaf] - hist_small
+    hist_left = jnp.where(left_is_small, hist_small, hist_large)
+    hist_right = jnp.where(left_is_small, hist_large, hist_small)
+    hist_left, hist_right = jax.lax.optimization_barrier(
+        (hist_left, hist_right))
+    cache = cache.at[best_leaf].set(hist_left).at[right_id].set(hist_right)
+    return cache, hist_left, hist_right
+
+
 def _collapse_pair(pair):
     """Default hist reduction hook: no shards, just collapse the
     compensated (value, residual) pair."""
@@ -344,12 +366,9 @@ def build_tree_device(bins, grad, hess, inbag, feature_mask,
                 small_leaf = jnp.where(left_is_small, best_leaf, right_id)
                 hist_small = hist_psum_fn(leaf_histogram(
                     st["row_leaf"], small_leaf.astype(jnp.int32)))
-                hist_large = st["hist_cache"][best_leaf] - hist_small
-                hist_left = jnp.where(left_is_small, hist_small, hist_large)
-                hist_right = jnp.where(left_is_small, hist_large, hist_small)
-                st["hist_cache"] = (st["hist_cache"]
-                                    .at[best_leaf].set(hist_left)
-                                    .at[right_id].set(hist_right))
+                st["hist_cache"], hist_left, hist_right = split_hist_cache(
+                    st["hist_cache"], best_leaf, right_id, hist_small,
+                    left_is_small)
             elif not compact_hist:
                 # memory-bounded mode, frontier-batched: BOTH children
                 # from ONE streamed pass (leaf-indexed accumulator) —

@@ -558,3 +558,65 @@ def test_unpermute_moves_each_value_to_its_row(classes):
     assert "sort" in prims and not prims & {"scatter", "scatter-add"}
     got = jax.jit(fn)(jnp.asarray(perm), jnp.asarray(values))
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("classes", [0, 3])
+@pytest.mark.parametrize("best_leaf,right_id", [(0, 6), (3, 4), (5, 1)])
+@pytest.mark.parametrize("left_small", [True, False])
+def test_split_hist_cache_is_the_five_lines(left_small, best_leaf, right_id,
+                                            classes):
+    """The builders' one cache update against the lines it replaced,
+    written out: the same float32 subtraction and the same rows written
+    (bit-equal cache and children), the smaller child on either side,
+    the first leaf as parent and the last as right child, as the
+    builders call it (a `cond` inside a `fori_loop` under `jit`) and
+    batched over a class axis, where the row index is batched too."""
+    from lightgbm_tpu.models.tree_learner import split_hist_cache
+    l, f, b = 7, 5, 9
+    rng = np.random.RandomState(best_leaf * 10 + right_id)
+    k = max(classes, 1)
+    cache = rng.randn(k, l, f, b, 3).astype(np.float32) * 1e3
+    small = rng.randn(k, f, b, 3).astype(np.float32)
+    leaves = (np.int32(best_leaf) + np.arange(k, dtype=np.int32)) % l
+    rights = (np.int32(right_id) + np.arange(k, dtype=np.int32)) % l
+
+    def written_out(cache, leaf, right, small):
+        hist_large = cache[leaf] - small
+        hist_left = jnp.where(left_small, small, hist_large)
+        hist_right = jnp.where(left_small, hist_large, small)
+        cache = cache.at[leaf].set(hist_left).at[right].set(hist_right)
+        return cache, hist_left, hist_right
+
+    def in_the_loop(update):
+        def run(cache, leaf, right, small):
+            zeros = jnp.zeros_like(small)
+
+            def body(i, carry):
+                return jax.lax.cond(
+                    i == 1,
+                    lambda c: update(c[0], leaf, right, small),
+                    lambda c: c, carry)
+            return jax.lax.fori_loop(0, 3, body, (cache, zeros, zeros))
+        return jax.jit(jax.vmap(run) if classes else run)
+
+    args = (cache, leaves, rights, small)
+    if not classes:
+        args = tuple(a[0] for a in args)
+    want = in_the_loop(written_out)(*args)
+    got = in_the_loop(
+        lambda c, leaf, right, s: split_hist_cache(
+            c, leaf, right, s, jnp.asarray(left_small)))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g).view(np.int32),
+                                      np.asarray(w).view(np.int32))
+    # and against numpy, so that the two jitted forms cannot be wrong
+    # together: class 0's children and the rows no split touches
+    new_cache, hist_left, hist_right = (
+        np.asarray(a)[0] if classes else np.asarray(a) for a in got)
+    large = cache[0, best_leaf] - small[0]
+    np.testing.assert_array_equal(hist_left, small[0] if left_small else large)
+    np.testing.assert_array_equal(hist_right, large if left_small else small[0])
+    np.testing.assert_array_equal(new_cache[best_leaf], hist_left)
+    np.testing.assert_array_equal(new_cache[right_id], hist_right)
+    rest = [i for i in range(l) if i not in (best_leaf, right_id)]
+    np.testing.assert_array_equal(new_cache[rest], cache[0, rest])

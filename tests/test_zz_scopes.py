@@ -744,3 +744,77 @@ def test_builder_compiles_at_2000_columns(one_chip, b, blocks):
             assert acc in ln, ln[:200]
             assert path.index("hist") < path.index("seg_hist"), ln[:200]
     assert names == {"seg_hist", "partition_rows"}
+
+
+def hlo_computations(text):
+    """{computation: {instruction: its line}} of a compiled module's
+    text, both in the text's order."""
+    comps, current = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", ln)
+        if head:
+            current = comps.setdefault(head.group(1), {})
+        inst = re.match(r"\s+(?:ROOT )?%(\S+) = ", ln)
+        if inst and current is not None:
+            current[inst.group(1)] = ln
+    return comps
+
+
+@pytest.mark.parametrize("which,leaves,columns,bins", [
+    ("partitioned", 255, 136, 63), ("partitioned", 63, 28, 255),
+    ("masked", 63, 28, 300)])
+def test_split_writes_two_rows_of_the_cache_in_place(one_chip, which, leaves,
+                                                     columns, bins):
+    """A split updates the histogram cache where it lies (ISSUE 34): the
+    chip's compiler leaves no `copy` (nor `copy-start`) of the cache's
+    shape anywhere in the builder, and inside the loop exactly two
+    instructions give a result of that shape: fusions of a
+    `dynamic-update-slice` on their own first parameter, the first on
+    the split branch's own parameter (the carried buffer), the second
+    on the first's result. At the parent the parent's row was read
+    again after the first write, and the same text held two whole-array
+    copies a split (`copy.215`, `copy.300` at Epsilon's shapes). How to
+    look: docs/Observability.md, "A carried array copied whole"."""
+    n_pad = 8 * 4096
+    core, shapes = builder(n_pad, w=columns // 4, b=bins, l=leaves)
+    if which == "masked":
+        from lightgbm_tpu.models.tree_learner import build_tree_device
+        shapes[0] = ((columns, n_pad), jnp.int32)
+
+        def core(rows, g, h, ib, fm, nb, ic):
+            return build_tree_device(
+                rows, g, h, ib, fm, nb, ic, num_leaves=leaves, max_bin=bins,
+                params=SplitParams(1.0, 1e-3, 0.0, 0.0, 0.0), max_depth=-1,
+                row_chunk=4096)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    with fresh_compiles(), \
+            mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(core).lower(*args).compile().as_text()
+    cache = rf"f32\[{leaves},{columns},{bins},3\]"
+    assert re.search(cache, text)
+    copies = [ln.strip()[:200] for ln in text.splitlines()
+              if re.search(rf" = \(?{cache}[^=]*? copy(-start)?\(", ln)]
+    assert not copies, copies
+    comps = hlo_computations(text)
+    writes = [(comp, name, ln) for comp, body in comps.items()
+              if not comp.startswith("fused_computation")
+              for name, ln in body.items()
+              if re.search(rf" = {cache}\S* (?!get-tuple-element)\S+\(", ln)
+              and "/while/body/" in ln]
+    assert len(writes) == 2, [ln.strip()[:200] for _, _, ln in writes]
+    for _, _, ln in writes:
+        if which == "partitioned":
+            assert re.search(r'/tree_state/hist_cache/[^/"]*"', ln), ln[:300]
+        called = comps[re.search(r"fusion\(.*calls=%([^\s,]+)",
+                                 ln).group(1)]
+        (root,) = [r for r in called.values() if " ROOT " in r]
+        target = re.search(r" dynamic-update-slice\(%([^\s,)]+)",
+                           root).group(1)
+        assert re.search(r" parameter\(0\)", called[target]), root[:300]
+    (branch, first, first_ln), (_, _, second_ln) = writes
+    operand = re.search(r" fusion\(%([^\s,)]+)", first_ln).group(1)
+    source = comps[branch][operand]
+    carried = re.search(r" get-tuple-element\(%([^\s,)]+)\)", source).group(1)
+    assert re.search(r" parameter\(0\)", comps[branch][carried]), source
+    assert re.search(rf" fusion\(%{re.escape(first)},", second_ln)
