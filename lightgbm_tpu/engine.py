@@ -110,9 +110,9 @@ def _train_blockwise(booster, callbacks_after_iter, init_iteration,
             # and keeps calling it — evals repeat, and per-iteration
             # sampling (or multiclass gradient coupling) can resume
             # real splitting. First replay the stop iteration's
-            # callbacks (its partial-class trees are already applied to
-            # the scores), then hand the remaining rounds to the true
-            # per-iteration loop.
+            # callbacks (it kept no tree: the scores are those of the
+            # iteration before), then hand the remaining rounds to the
+            # true per-iteration loop.
             i += t_eff
             if i < end and run_callbacks(i):
                 return

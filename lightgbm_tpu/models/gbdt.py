@@ -218,12 +218,10 @@ class _BlockSnapshots:
     CHUNK_BYTES = 64 << 20
 
     def __init__(self, gbdt, stacked, base_train, base_valids, t_eff,
-                 n_before, k_stop, natural_stop):
+                 natural_stop):
         self._gbdt = gbdt
         self._stacked = stacked
         self._t_eff = t_eff
-        self._n_before = n_before
-        self._k_stop = k_stop
         self._natural_stop = natural_stop
         self._scan_final_train = gbdt.train_score_updater.score
         self._states = [self._new_state(gbdt.train_score_updater,
@@ -271,14 +269,10 @@ class _BlockSnapshots:
     def drop_tail_to(self, t):
         """Early-stop break at in-block iteration t: drop every tree
         past iteration t WITHOUT score adjustment (the caller has set
-        all scores to the t snapshot). Accounts for the k_stop
-        partial-class trees a natural-stop block appends beyond its
-        t_eff full iterations — a plain per-iteration count would leave
-        them behind and break the class-major model layout."""
+        all scores to the t snapshot). A block appends whole iterations
+        only, so the count is per iteration."""
         gb = self._gbdt
         n_drop = (self._t_eff - (t + 1)) * gb.num_class
-        if self._natural_stop:
-            n_drop += self._k_stop
         if n_drop > 0:
             del gb.models[-n_drop:]
         dropped = self._t_eff - (t + 1)
@@ -305,7 +299,7 @@ class _BlockSnapshots:
         """After a COMPLETED walk (no early-stop break): restore the
         train score to the scan's final value, or — after a natural
         stop (an empty tree mid-block) — rebuild exact state for the
-        kept trees, including partial-class trees the walk never saw."""
+        kept trees (the walk's last snapshot is every valid set's)."""
         gb = self._gbdt
         if not self._natural_stop:
             gb.train_score_updater.score = self._scan_final_train
@@ -316,15 +310,6 @@ class _BlockSnapshots:
             gb.train_score_updater.score = self._scan_final_train
         else:
             gb._rebuild_train_score_from_models()
-        if self._k_stop > 0:
-            # the stop iteration kept classes [0, k_stop) whose deltas
-            # the per-full-iteration walk never applied
-            new_trees = gb.models[self._n_before:]
-            for st in self._states[1:]:
-                st["updater"].score = st["base"]
-                if new_trees:
-                    st["updater"].add_score_by_trees(new_trees,
-                                                     gb.num_class)
         return True
 
 
@@ -847,7 +832,7 @@ class GBDT:
             with self.tracer.phase("build"):
                 out = self.tree_learner.train_device(
                     gradients[k], hessians[k], inbag)
-            self.metrics.inc("tree_build_dispatches")
+            self._count_trees(1)
             if linear:
                 # the split search fixed the STRUCTURE; now refit every
                 # eligible leaf as a ridge model over its path features
@@ -904,6 +889,11 @@ class GBDT:
             if stopped:
                 Log.info("Stopped training because there are no more leafs "
                          "that meet the split requirements.")
+                # an iteration is K trees or none: the model list stays
+                # iter * num_class long, class-major (callers may go on
+                # calling after a stop, and every reader indexes it by
+                # class), so the earlier classes' trees are taken back
+                self._take_back_trees(k)
                 return True
             new_leaves += tree.num_leaves
             self.models.append(tree)
@@ -1027,9 +1017,33 @@ class GBDT:
             self.metrics.set("rank_pair_fill",
                              layout.pairs / max(layout.pair_slots, 1))
 
-    def _note_builder_kernels(self):
-        """What the partitioned builder's kernels compile to, as registry
-        gauges beside `tree_build_dispatches` in /trainz:
+    def _class_axis_form(self):
+        """How the fused step runs the K trees of an iteration: `single`
+        (K = 1), `scan` (the leaf-contiguous and the gather-compacted
+        builders dispatch histogram work through a bucketed lax.switch:
+        vmapped over the class axis it would execute EVERY bucket branch
+        per split, so those scan the classes), `vmap` (the masked
+        builder)."""
+        if self.num_class == 1:
+            return "single"
+        learner = self.tree_learner
+        return ("scan" if getattr(learner, "_use_partitioned", False)
+                or getattr(learner, "_use_compact", False) else "vmap")
+
+    def _count_trees(self, grown):
+        """Trees handed to the builder: `tree_build_dispatches`, and of
+        them `class_trees`, those of an iteration that grows one a
+        class."""
+        self.metrics.inc("tree_build_dispatches", grown)
+        if self.num_class > 1:
+            self.metrics.inc("class_trees", grown)
+
+    def _note_builder_kernels(self, fused=False):
+        """The class axis, as registry gauges in /trainz:
+        `trees_per_iteration` (K) and `class_axis_form` (`single`; in the
+        fused step `scan` or `vmap`, _class_axis_form; `loop`, a host
+        loop, in the per-iteration path). And what the partitioned
+        builder's kernels compile to, beside `tree_build_dispatches`:
         `partition_engine` (ops/partition.py: `pallas` on a TPU, `xla`
         off it), and the histogram kernel's one-hot operand
         (ops/ordered_hist.py onehot_extent): `seg_hist_onehot_rows` a
@@ -1042,6 +1056,10 @@ class GBDT:
         lookup that were traced (ops/partition.py unpermute, one
         key-value sort, + score_updater.py lookup_form:
         `sort_kv+split64` at 255 leaves)."""
+        self.metrics.set("trees_per_iteration", int(self.num_class))
+        self.metrics.set(
+            "class_axis_form", self._class_axis_form()
+            if fused or self.num_class == 1 else "loop")
         if getattr(self.tree_learner, "_use_partitioned", False):
             from ..ops.ordered_hist import feature_blocks, onehot_extent
             from ..ops.partition import (chunk_lanes, packed_word_rows,
@@ -1095,14 +1113,8 @@ class GBDT:
             "gops": self.objective._grad_ops,
         }
 
-        self._note_builder_kernels()
-        num_class = self.num_class
-        # both the partitioned and the gather-compacted builders dispatch
-        # histogram work through a bucketed lax.switch: vmapping them
-        # over the class axis would execute EVERY bucket branch per
-        # split, so those cores scan classes instead
-        use_switch_core = (getattr(learner, "_use_partitioned", False)
-                           or getattr(learner, "_use_compact", False))
+        self._note_builder_kernels(fused=True)
+        class_axis = self._class_axis_form()
         inbag_fn = self._fused_inbag_fn()
 
         def fused(score, fmasks, iters, d):
@@ -1123,13 +1135,13 @@ class GBDT:
                     # zero
                     ib = (inbag if inbag_fn is None
                           else inbag_fn(it, gp, hp) * inbag)
-                if num_class == 1:
+                if class_axis == "single":
                     out = core(bins, gp[0], hp[0], ib, fmask[0], nbpf,
                                iscat)
                     with scope("score_update"):
                         upd = leaf_lookup(out["leaf_value"] * shrink,
                                           out["row_leaf"][:n])[None, :]
-                elif not use_switch_core:
+                elif class_axis == "vmap":
                     # one device program for ALL classes: vmap the
                     # whole-tree builder over the class axis (SURVEY M2;
                     # the reference loops classes serially,
@@ -1144,31 +1156,36 @@ class GBDT:
                             lambda o: leaf_lookup(o[0] * shrink, o[1][:n]),
                             (out["leaf_value"], out["row_leaf"]))
                 else:
-                    # partitioned/compacted builder: scan the class axis
-                    # instead of vmap — vmapping the bucketed lax.switch
-                    # would execute EVERY bucket branch per split; scan
-                    # keeps one branch per class (still a single
-                    # compiled program, matching the reference's
-                    # sequential class loop)
+                    # scan the class axis (_class_axis_form has why):
+                    # one compiled program, the reference's sequential
+                    # class loop. Every class's tree grows from the
+                    # iteration's one gradient pass above.
                     def class_step(_, gh):
                         gg, hh, fm = gh
                         o = core(bins, gg, hh, ib, fm, nbpf, iscat)
+                        # the step's own update is all that reads the
+                        # row -> leaf map: it never joins the stacked ys
+                        row_leaf = o.pop("row_leaf")
                         with scope("score_update"):
                             u = leaf_lookup(o["leaf_value"] * shrink,
-                                            o["row_leaf"][:n])
+                                            row_leaf[:n])
                         return None, (o, u)
 
-                    _, (out, upd) = jax.lax.scan(class_step, None,
-                                                 (gp, hp, fmask))
+                    # a path component, no scope of its own
+                    # (telemetry/trace.py DEVICE_PATH_WORDS)
+                    with scope("class_scan"):
+                        _, (out, upd) = jax.lax.scan(class_step, None,
+                                                     (gp, hp, fmask))
                 with scope("score_update"):
                     score = score + upd
-                del out["row_leaf"]  # keep the ys O(iter * num_leaves)
+                out.pop("row_leaf", None)  # keep the ys O(iter * num_leaves)
                 return score, out
 
             return jax.lax.scan(step, score, (fmasks, iters))
 
         score = self.train_score_updater.score
-        fmasks = jnp.ones((num_iters, num_class, learner.f_pad), dtype=bool)
+        fmasks = jnp.ones((num_iters, self.num_class, learner.f_pad),
+                          dtype=bool)
         iters = jnp.arange(num_iters, dtype=jnp.int32)
         from ..config import compile_cache_hits
         from ..telemetry.ledger import LEDGER
@@ -1207,13 +1224,15 @@ class GBDT:
 
     def _run_fused_block(self, num_iters):
         """Run ONE fused scan of `num_iters` iterations and append the
-        materialized trees. Returns (stacked_device, t_eff, k_stop,
-        n_before): the block's stacked tree arrays still on device (for
-        snapshot traversal), the number of full iterations kept, the
-        partial-class count at a natural stop, and the model-list length
-        before the block. The train score is set to the scan's final
-        score (which, at a natural stop, still includes discarded
-        trees — callers fix that up)."""
+        materialized trees. Returns (stacked_device, t_eff, n_before):
+        the block's stacked tree arrays still on device (for snapshot
+        traversal), the number of full iterations kept, and the
+        model-list length before the block. An iteration in which a
+        class grew no tree ends the block and is dropped whole, its
+        earlier classes' trees with it (train_one_iter does the same):
+        the model list stays iter * num_class long. The train score is
+        set to the scan's final score (which, at a natural stop, still
+        includes discarded trees — callers fix that up)."""
         # a fused block is ONE device program: a preemption anywhere
         # inside it loses the whole block, which is exactly what
         # crashing at its launch models (utils/faults.py)
@@ -1223,7 +1242,8 @@ class GBDT:
         heartbeat.WATCHDOG.set_iteration(self.iter)
         fn = self._get_fused_fn(num_iters)
         learner = self.tree_learner
-        tags = {"iterations": num_iters, "first_iter": self.iter}
+        tags = {"iterations": num_iters, "classes": self.num_class,
+                "first_iter": self.iter}
         span = self.tracer.span
         # the whole block is one device program; its host-side waits
         # (score pull, stacked-tree transfer) are THE block-boundary
@@ -1263,12 +1283,6 @@ class GBDT:
             empty = (nsp == 0).any(axis=1)       # nsp: (T, K)
             t_eff = (int(np.argmax(empty)) if bool(empty.any())
                      else num_iters)
-            # classes BEFORE the first empty one in the stopping
-            # iteration are kept, matching the sequential path
-            # (gbdt.cpp:222-236 push_back each class tree until the
-            # empty one)
-            k_stop = (int(np.argmax(nsp[t_eff] == 0))
-                      if t_eff < num_iters else 0)
 
             def slice_at(t, k):
                 if self.num_class == 1:
@@ -1281,16 +1295,10 @@ class GBDT:
                     for k in range(self.num_class):
                         self.models.append(learner.host_out_to_tree(
                             slice_at(t, k), shrink=self.shrinkage_rate))
-                if t_eff < num_iters:
-                    for k in range(k_stop):
-                        self.models.append(learner.host_out_to_tree(
-                            slice_at(t_eff, k),
-                            shrink=self.shrinkage_rate))
         self.iter += t_eff
         self._note_rank_pairs(t_eff)
         self.metrics.inc("fused_blocks")
-        self.metrics.inc("tree_build_dispatches",
-                         len(self.models) - n_before)
+        self._count_trees(len(self.models) - n_before)
         self.metrics.inc("transfer_bytes",
                          sum(np.asarray(v).nbytes for v in host.values()))
         self.metrics.set("iteration", self.iter)
@@ -1301,7 +1309,7 @@ class GBDT:
                 block=int(t_eff), fused=True,
                 compile_cache_hit=bool(self.last_compile_cache_hit))
         self._journal_quality()
-        return stacked, t_eff, k_stop, n_before
+        return stacked, t_eff, n_before
 
     def _natural_stop_score_exact(self):
         """At a natural stop (an empty tree mid-block), whether the
@@ -1338,13 +1346,12 @@ class GBDT:
                 if self.train_one_iter():
                     return True
             return False
-        _, t_eff, _, n_before = self._run_fused_block(num_iters)
+        _, t_eff, n_before = self._run_fused_block(num_iters)
         # valid scores stay in sync with the model list no matter who
         # called (the scan only carries TRAIN scores): one batched
         # update per valid set for the whole block
         if self.valid_score_updaters and len(self.models) > n_before:
-            # n_before is a multiple of num_class (partial-class appends
-            # only happen when training ends), so the slice is class-major
+            # whole iterations only: the slice is class-major
             new_trees = self.models[n_before:]
             with self.tracer.span("valid_update", iterations=t_eff,
                                   first_iter=self.iter - t_eff):
@@ -1355,7 +1362,8 @@ class GBDT:
                      "that meet the split requirements.")
             if self._natural_stop_score_exact():
                 return True
-            # multiclass (classes after k_stop kept learning) or
+            # multiclass (the stop iteration's other classes and the
+            # scan's later iterations kept learning) or
             # per-iteration bag/feature sampling (a later sample can
             # split again): the scan's score includes discarded trees —
             # rebuild from the kept trees so booster state matches the
@@ -1382,25 +1390,31 @@ class GBDT:
         Requires _fused_eligible(ignore_train_metrics=True)."""
         base_train = self.train_score_updater.score
         base_valids = [u.score for u in self.valid_score_updaters]
-        stacked, t_eff, k_stop, n_before = self._run_fused_block(num_iters)
+        stacked, t_eff, _ = self._run_fused_block(num_iters)
         snap = _BlockSnapshots(self, stacked, base_train, base_valids,
-                               t_eff, n_before, k_stop,
-                               natural_stop=t_eff < num_iters)
+                               t_eff, natural_stop=t_eff < num_iters)
         return t_eff, snap
 
+
+    def _take_back_trees(self, count):
+        """Drop the last `count` trees, those of classes 0..count-1 of
+        one iteration, and subtract what each added to its class's
+        train and valid scores."""
+        if count <= 0:
+            return
+        for k, tree in enumerate(self.models[len(self.models) - count:]):
+            tree.shrinkage(-1.0)
+            self.train_score_updater.add_score_by_tree(tree, k)
+            for updater in self.valid_score_updaters:
+                updater.add_score_by_tree(tree, k)
+        del self.models[len(self.models) - count:]
 
     def rollback_one_iter(self):
         """gbdt.cpp:247-264. Indexes from the end of the model list so it
         stays valid after early-stopping truncation."""
         if self.iter == 0 or len(self.models) < self.num_class:
             return
-        for k in range(self.num_class):
-            tree = self.models[-self.num_class + k]
-            tree.shrinkage(-1.0)
-            self.train_score_updater.add_score_by_tree(tree, k)
-            for updater in self.valid_score_updaters:
-                updater.add_score_by_tree(tree, k)
-        del self.models[-self.num_class:]
+        self._take_back_trees(self.num_class)
         self.iter -= 1
         if self.quality is not None:
             # snap the split ledger to the surviving trees NOW: a

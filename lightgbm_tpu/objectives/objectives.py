@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.trace import scope
 from ..utils.log import Log
 
 K_MIN_SCORE = -np.inf
@@ -147,13 +148,18 @@ class MulticlassLogloss(ObjectiveFunction):
                       self.num_class, int(label_int.min() if label_int.min() < 0
                                           else label_int.max()))
         def _grad_pure(ops, score):
-            p = jax.nn.softmax(score, axis=0)  # (K, N)
-            g = p - ops["onehot"]
-            h = 2.0 * p * (1.0 - p)
-            weights = ops.get("weights")
-            if weights is not None:
-                g = g * weights[None, :]
-                h = h * weights[None, :]
+            # one pass for all K trees of the iteration, from the score
+            # at its start (gbdt.cpp:210-245 computes gradients once,
+            # then loops the classes); device sub-scope `softmax`
+            # (under `gradients` in the fused step)
+            with scope("softmax"):
+                p = jax.nn.softmax(score, axis=0)  # (K, N)
+                g = p - ops["onehot"]
+                h = 2.0 * p * (1.0 - p)
+                weights = ops.get("weights")
+                if weights is not None:
+                    g = g * weights[None, :]
+                    h = h * weights[None, :]
             return g, h
 
         self._install_grad(_grad_pure, {"onehot": jnp.asarray(

@@ -61,19 +61,31 @@ RECENT_SPANS = 256
 DEVICE_SCOPES = ("gradients", "partition", "hist", "hist_reduce",
                  "split_scan", "tree_state", "score_update")
 DEVICE_SUBSCOPES = {
-    # the pairwise pass of a ranking objective (objectives/rank_device.py)
-    "gradients": ("rank_gather", "rank_sort", "rank_pairs", "rank_return"),
+    # the pairwise pass of a ranking objective (objectives/rank_device.py),
+    # and the softmax gradient of a multiclass one (objectives.py)
+    "gradients": ("rank_gather", "rank_sort", "rank_pairs", "rank_return",
+                  "softmax"),
     "partition": ("window_in", "decide", "destinations", "invert", "move",
                   "write_back"),
     "hist": ("window", "seg_hist", "fold"),
     "tree_state": ("hist_cache", "pos_leaf"),
 }
+# Path components that are no scope of their own: an operation that
+# carries one still belongs to the first of DEVICE_SCOPES on its path.
+# `class_scan` stands round the fused step's scan over the class axis
+# (models/gbdt.py). What carries it, no top-level word, and nothing
+# after it but the scan's own `while/body` and one primitive is that
+# axis' own cost: the slices of the (K, N) gradients a class, the
+# stacking of the K trees and score updates. Deeper paths without a
+# word are the builder's own unscoped operations.
+DEVICE_PATH_WORDS = ("class_scan",)
 # names of the Pallas kernels (`pallas_call(name=...)`): the segment
 # kernel and the partition kernel of the fused path, and the two
 # full-matrix kernels chip_smoke.py runs
 KERNEL_NAMES = ("seg_hist", "partition_rows", "masked_hist",
                 "frontier_hist")
-_SCOPE_WORDS = frozenset(DEVICE_SCOPES).union(*DEVICE_SUBSCOPES.values())
+_SCOPE_WORDS = frozenset(DEVICE_SCOPES + DEVICE_PATH_WORDS).union(
+    *DEVICE_SUBSCOPES.values())
 
 
 def scope(word):

@@ -28,9 +28,9 @@ from lightgbm_tpu.telemetry.ledger import (_CACHE_HIT_EVENT,
                                            _CACHE_LOAD_EVENT,
                                            _COMPILE_EVENT, LEDGER,
                                            CompileLedger)
-from lightgbm_tpu.telemetry.trace import (DEVICE_SCOPES, DEVICE_SUBSCOPES,
-                                          KERNEL_NAMES, PROCESS_TRACER,
-                                          SpanTracer)
+from lightgbm_tpu.telemetry.trace import (DEVICE_PATH_WORDS, DEVICE_SCOPES,
+                                          DEVICE_SUBSCOPES, KERNEL_NAMES,
+                                          PROCESS_TRACER, SpanTracer)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks")
@@ -39,9 +39,11 @@ import scopereduce  # noqa: E402
 import tracereduce  # noqa: E402
 
 ALL_WORDS = set(DEVICE_SCOPES).union(*DEVICE_SUBSCOPES.values())
-# the pairwise pass of a ranking objective: in no binary program, and read
-# by a reader that carries the words itself (tests/test_rank_bucketed.py
-# finds them in a lambdarank program)
+# an objective's own words under `gradients` (the pairwise pass of a
+# ranking objective, the softmax of a multiclass one): in no binary
+# program, and each read by a reader that carries the words itself
+# (tests/test_rank_bucketed.py finds the ranking ones in a lambdarank
+# program, test_class_axis_words_in_compiled_text the softmax here)
 RANK_WORDS = set(DEVICE_SUBSCOPES["gradients"])
 BLOCK = 2
 ROWS = 5000
@@ -87,6 +89,23 @@ def builder(n_pad, w=2, b=16, l=5):
     return core, shapes
 
 
+@contextlib.contextmanager
+def recorded_compiles():
+    """The compiled text of every program lowered inside, compiled
+    afresh (`fresh_compiles`), as a list that fills as they compile."""
+    texts = []
+    compile_ = jax.stages.Lowered.compile
+
+    def recording(self, *a, **k):
+        out = compile_(self, *a, **k)
+        texts.append(out.as_text())
+        return out
+
+    with pytest.MonkeyPatch.context() as mp, fresh_compiles():
+        mp.setattr(jax.stages.Lowered, "compile", recording)
+        yield texts
+
+
 @pytest.fixture(scope="module")
 def trained():
     """A 5,000-row booster (two row chunks: with one, writing a window
@@ -100,19 +119,10 @@ def trained():
     y = (x[:, 0] + x[:, 1] * x[:, 2] > 0).astype(np.float32)
     params = {"objective": "binary", "num_leaves": 7, "max_bin": 31,
               "partitioned_build": "true", "verbose": -1, "metric": "none"}
-    texts = []
-    compile_ = jax.stages.Lowered.compile
-
-    def recording(self, *a, **k):
-        out = compile_(self, *a, **k)
-        texts.append(out.as_text())
-        return out
-
     PROCESS_TRACER.reset()
     LEDGER.reset()
-    with pytest.MonkeyPatch.context() as mp, fresh_compiles():
+    with pytest.MonkeyPatch.context() as mp, recorded_compiles() as texts:
         mp.setenv("LIGHTGBM_TPU_DEVICE_BIN", "1")
-        mp.setattr(jax.stages.Lowered, "compile", recording)
         ds = lgb.Dataset(x, label=y, params=dict(params)).construct()
         vs = lgb.Dataset(x[:500], label=y[:500], reference=ds).construct()
         booster = lgb.Booster(params=dict(params), train_set=ds)
@@ -183,14 +193,21 @@ def test_vocabulary_avoids_primitive_names():
     prims = {p.name for p in vars(jax.lax).values()
              if isinstance(p, jex_core.Primitive)}
     assert {"gather", "slice", "scatter", "sort", "cond", "while"} <= prims
-    assert ALL_WORDS.union(KERNEL_NAMES).isdisjoint(prims)
+    assert ALL_WORDS.union(KERNEL_NAMES, DEVICE_PATH_WORDS).isdisjoint(prims)
     assert scopereduce.VOCABULARY == DEVICE_SCOPES
-    # the yardstick's table knows every sub-scope but the ranking ones,
-    # which their reader carries (benchmarks/metrics/rank_grad_ms_per_iter.py)
+    # the yardstick's table knows every sub-scope but the objectives' own,
+    # which their readers carry (benchmarks/metrics/rank_grad_ms_per_iter.py,
+    # softmax_grad_ms_per_iter.py), as class_scan_ms_per_iter.py carries
+    # the class axis' path word
     from datagen import load_module
     rank_reader = load_module("metrics", "rank_grad_ms_per_iter")
-    assert dict(scopereduce.SUBSCOPES,
-                gradients=rank_reader.SUBSCOPES) == DEVICE_SUBSCOPES
+    softmax_reader = load_module("metrics", "softmax_grad_ms_per_iter")
+    assert dict(scopereduce.SUBSCOPES, gradients=rank_reader.SUBSCOPES
+                + (softmax_reader.WORD,)) == DEVICE_SUBSCOPES
+    assert softmax_reader.TOP == "gradients"
+    assert DEVICE_PATH_WORDS == (
+        load_module("metrics", "class_scan_ms_per_iter").WORD,)
+    assert not set(DEVICE_PATH_WORDS) & ALL_WORDS
 
 
 def walk_eqns(jaxpr, prefix=()):
@@ -219,7 +236,7 @@ def test_score_update_moves_each_row_once(monkeypatch):
     assert DEVICE_SCOPES == ("gradients", "partition", "hist", "hist_reduce",
                              "split_scan", "tree_state", "score_update")
     assert {k: len(v) for k, v in DEVICE_SUBSCOPES.items()} == {
-        "gradients": 4, "partition": 6, "hist": 3, "tree_state": 2}
+        "gradients": 5, "partition": 6, "hist": 3, "tree_state": 2}
     leaves = 2 * LOOKUP_PIECE + 1
     rng = np.random.RandomState(3)
     x = rng.randn(ROWS, 4).astype(np.float32)
@@ -455,6 +472,76 @@ def test_scopereduce_on_built_trace(case, tmp_path, monkeypatch):
         scopereduce.table.cache_clear()
 
 
+def test_class_axis_words_in_compiled_text_and_trace(tmp_path, monkeypatch):
+    """K = 3 under the partitioned builder: the fused step scans the
+    classes under the path word `class_scan`, and the one gradient pass
+    an iteration stands under `gradients` / `softmax` outside that scan.
+    Every operation of a class's tree keeps its own top-level word, so
+    what carries `class_scan` and none is the scan's own work; the two
+    readers find exactly those in a trace, and nothing in one without
+    the words (None, never 0)."""
+    rng = np.random.RandomState(11)
+    x = rng.randn(ROWS, 6).astype(np.float32)
+    y = ((x[:, 0] > 0).astype(int) + (x[:, 1] > 0.3).astype(int)
+         ).astype(np.float32)
+    params = {"objective": "multiclass", "num_class": 3, "num_leaves": 7,
+              "max_bin": 31, "partitioned_build": "true", "verbose": -1}
+    with recorded_compiles() as texts:
+        booster = lgb.Booster(params=dict(params), train_set=lgb.Dataset(
+            x, label=y, params=dict(params)))
+        booster.gbdt.train_many(BLOCK, ignore_train_metrics=True)
+    gbdt = booster.gbdt
+    assert len(gbdt.models) == 3 * BLOCK
+    snap = gbdt.metrics.snapshot()
+    assert snap["gauges"]["class_axis_form"] == "scan"
+    assert snap["gauges"]["trees_per_iteration"] == 3
+    assert snap["counters"]["class_trees"] == 3 * BLOCK
+    block = [sp for sp in gbdt.tracer.recent(None)
+             if sp["path"] == "fused_block"]
+    assert block[0]["tags"] == {"iterations": BLOCK, "classes": 3,
+                                "first_iter": 0}
+    (text,) = [t for t in texts if "jit(fused)" in t]
+    paths = [p.split("/") for p in re.findall(r'op_name="([^"]*)"', text)]
+    top = lambda parts: next((w for w in parts if w in DEVICE_SCOPES), None)
+    scan = [p for p in paths if "class_scan" in p]
+    assert scan
+    # a class's tree: the builder's words, each after `class_scan`
+    assert {top(p) for p in scan} >= {"partition", "hist", "split_scan",
+                                      "tree_state", "score_update"}
+    # the scan's own work: the word and no top-level one
+    assert any(top(p) is None for p in scan)
+    # one gradient pass an iteration, outside the class scan
+    soft = [p for p in paths if "softmax" in p]
+    assert soft and all("gradients" in p[:p.index("softmax")]
+                        and "class_scan" not in p for p in soft)
+
+    # ---- the two readers on a built trace
+    from datagen import load_module
+    md = dict(DEVICE_MD)
+    md[5] = (md[5][0], P + "class_scan/while/body/dynamic_slice:", 2000)
+    md[6] = (md[6][0], P + "gradients/softmax/exp:", 3000)
+    md[4] = (md[4][0], P + "class_scan/while/body/partition/cond/"
+             "branch_1_fun/destinations/cumsum:", 1000)
+    # inside a class's tree and without a word: the builder's, not the scan's
+    md[3] = (md[3][0], P + "class_scan/while/body/closed_call/while/body/"
+             "closed_call", 4000)
+    ctx = {"trace": {"busy_s": 1.0}, "block_iterations": 2}
+    monkeypatch.setattr(scopereduce, "TRACE_ROOT", str(tmp_path / "with"))
+    write_trace(tmp_path / "with", md)
+    read = lambda name: load_module("metrics", name).read(ctx)
+    # id 5 is a leaf for 100 us of the window (its 20 us more span an
+    # allocation: a container's, nobody's), id 6 for 40 (30 after it)
+    assert read("class_scan_ms_per_iter") == pytest.approx(0.050)
+    assert read("softmax_grad_ms_per_iter") == pytest.approx(0.020)
+    monkeypatch.setattr(scopereduce, "TRACE_ROOT", str(tmp_path / "without"))
+    write_trace(tmp_path / "without", DEVICE_MD)
+    assert read("class_scan_ms_per_iter") is None
+    assert read("softmax_grad_ms_per_iter") is None
+    ctx["trace"] = None           # off a TPU the harness hands no trace
+    assert read("class_scan_ms_per_iter") is None
+    assert read("softmax_grad_ms_per_iter") is None
+
+
 # ------------------------------------------------------- 3. host spans
 def test_fused_block_child_spans(trained):
     spans = trained["gbdt"].tracer.recent(None)
@@ -463,7 +550,8 @@ def test_fused_block_child_spans(trained):
     for name in children:
         s = by_path["fused_block/" + name]
         assert s["name"] == name and s["duration_s"] > 0
-        assert s["tags"] == {"iterations": BLOCK, "first_iter": 0}
+        assert s["tags"] == {"iterations": BLOCK, "classes": 1,
+                             "first_iter": 0}
     block = by_path["fused_block"]
     assert sum(by_path["fused_block/" + c]["duration_s"]
                for c in children) <= block["duration_s"]
@@ -478,8 +566,11 @@ def test_fused_block_child_spans(trained):
     counters = trained["gbdt"].metrics.snapshot()["counters"]
     assert counters["fused_blocks"] == 1
     assert counters["tree_build_dispatches"] == BLOCK
+    assert "class_trees" not in counters      # one tree an iteration
     # the engine the learner's partition step compiled to, beside it
     gauges = trained["gbdt"].metrics.snapshot()["gauges"]
+    assert gauges["trees_per_iteration"] == 1
+    assert gauges["class_axis_form"] == "single"
     assert gauges["partition_engine"] == "xla"
     # and the end-of-tree un-permute + leaf-value lookup it traced
     assert gauges["score_update_form"] == "sort_kv+take"
