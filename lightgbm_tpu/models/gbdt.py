@@ -1038,6 +1038,37 @@ class GBDT:
         if self.num_class > 1:
             self.metrics.inc("class_trees", grown)
 
+    def _hist_min_rows(self):
+        """Rows of the lowest rung of the partitioned builder's
+        histogram ladder (ops/ordered_hist.py min_rows), from the
+        learner's packed word rows and bins."""
+        from ..ops.ordered_hist import min_rows
+        learner = self.tree_learner
+        return min_rows(4 * int(learner._bins.shape[0]), learner.max_bin)
+
+    def _count_hist_calls(self, trees):
+        """The histogram ladder's hit share, from trees already on the
+        host (each a dict of one tree's arrays): `seg_hist_calls`, one a
+        split (its smaller child's histogram), and of them
+        `seg_hist_subchunk_calls`, those whose smaller child has fewer
+        rows than HIST_CHUNK, which a rung under a chunk can take (0
+        where the ladder has none)."""
+        from ..ops.pallas_hist import HIST_CHUNK
+        sub = self._hist_min_rows() < HIST_CHUNK
+        calls = small = 0
+        for tree in trees:
+            k = int(tree["n_splits"])
+            counts = [np.where(child >= 0,
+                               tree["internal_count"][np.maximum(child, 0)],
+                               tree["leaf_count"][np.maximum(~child, 0)])
+                      for child in (np.asarray(tree["left_child"])[:k],
+                                    np.asarray(tree["right_child"])[:k])]
+            calls += k
+            if sub:
+                small += int(np.sum(np.minimum(*counts) < HIST_CHUNK))
+        self.metrics.inc("seg_hist_calls", calls)
+        self.metrics.inc("seg_hist_subchunk_calls", small)
+
     def _note_builder_kernels(self, fused=False):
         """The class axis, as registry gauges in /trainz:
         `trees_per_iteration` (K) and `class_axis_form` (`single`; in the
@@ -1049,7 +1080,10 @@ class GBDT:
         (ops/ordered_hist.py onehot_extent): `seg_hist_onehot_rows` a
         feature and `seg_hist_features_per_dot`; the grid's feature axis
         (feature_blocks): `seg_hist_feature_blocks` a call (1: no such
-        axis) and `seg_hist_block_features`; what the partition kernel
+        axis) and `seg_hist_block_features`; the histogram's ladder
+        (min_rows, hist_rungs): `seg_hist_min_rows`, the rows of its
+        lowest rung, and `seg_hist_rungs`, the branches a call site
+        compiles; what the partition kernel
         was sized to (pack_rows, chunk_lanes): `partition_rows_words` a
         row and `partition_rows_chunk_lanes` a DMA; and
         `score_update_form`, the end-of-tree un-permute and leaf-value
@@ -1061,7 +1095,9 @@ class GBDT:
             "class_axis_form", self._class_axis_form()
             if fused or self.num_class == 1 else "loop")
         if getattr(self.tree_learner, "_use_partitioned", False):
-            from ..ops.ordered_hist import feature_blocks, onehot_extent
+            from ..ops.ordered_hist import (feature_blocks, hist_rungs,
+                                            onehot_extent)
+            from ..ops.pallas_hist import HIST_CHUNK
             from ..ops.partition import (chunk_lanes, packed_word_rows,
                                          partition_engine)
             self.metrics.set("partition_engine", partition_engine())
@@ -1076,6 +1112,13 @@ class GBDT:
                 4 * words, self.tree_learner.max_bin)
             self.metrics.set("seg_hist_feature_blocks", blocks)
             self.metrics.set("seg_hist_block_features", block_features)
+            r = self._hist_min_rows()
+            self.metrics.set("seg_hist_min_rows", r)
+            rows = int(self.tree_learner._bins.shape[1])  # of one shard
+            if getattr(self.tree_learner, "shard_rows", False):
+                rows //= self.tree_learner.n_shards
+            self.metrics.set("seg_hist_rungs",
+                             len(hist_rungs(rows // HIST_CHUNK, r)))
             wp = packed_word_rows(words)
             self.metrics.set("partition_rows_words", wp)
             self.metrics.set("partition_rows_chunk_lanes", chunk_lanes(wp))
@@ -1291,14 +1334,17 @@ class GBDT:
 
             n_before = len(self.models)
             with span("materialize", **tags):
-                for t in range(t_eff):
-                    for k in range(self.num_class):
-                        self.models.append(learner.host_out_to_tree(
-                            slice_at(t, k), shrink=self.shrinkage_rate))
+                grown = [slice_at(t, k) for t in range(t_eff)
+                         for k in range(self.num_class)]
+                self.models.extend(
+                    learner.host_out_to_tree(tree, shrink=self.shrinkage_rate)
+                    for tree in grown)
         self.iter += t_eff
         self._note_rank_pairs(t_eff)
         self.metrics.inc("fused_blocks")
         self._count_trees(len(self.models) - n_before)
+        if getattr(learner, "_use_partitioned", False):
+            self._count_hist_calls(grown)
         self.metrics.inc("transfer_bytes",
                          sum(np.asarray(v).nbytes for v in host.values()))
         self.metrics.set("iteration", self.iter)

@@ -72,8 +72,9 @@ def _partition_segment_rows(rows_i, rows_f, seg_b, seg_c, feat, thr, cat,
 
     The decision reads a word row, and a word row of a tiled (8, N)
     array costs all eight (0.64 ms at 11.5M rows, measured), so it is
-    taken on the geometric chunk bucket covering the segment, like the
-    histogram's window (ops/ordered_hist.py cover_index): the switch's
+    taken on the geometric chunk bucket covering the segment
+    (ops/ordered_hist.py cover_index; the histogram's window has a
+    ladder of its own, hist_rungs): the switch's
     branches read a slice and return a fresh decision vector and a
     count, nothing else. The kernel has no window: it takes the
     segment's bounds as scalars and reads the decisions of the chunks it
@@ -133,8 +134,8 @@ def _partition_segment(words, ghc, perm, seg_b, seg_c, feat, thr, cat,
     split_destinations runs on the slice with slice-local bounds, where
     the segment's relative order is the global one — but the
     slice/gather/write-back traffic is O(bucket), not O(N): ~38x less
-    movement per 63-leaf tree. Chunk-cover dispatch is shared with
-    segment_histograms (ops/ordered_hist.py cover_index/window_start).
+    movement per 63-leaf tree. Chunk-cover dispatch:
+    ops/ordered_hist.py cover_index/window_start.
 
     decode_fn(word_slice, feat) -> the VIRTUAL feature's bin column of
     the slice (plain unpack for unbundled data; slot decode for EFB).

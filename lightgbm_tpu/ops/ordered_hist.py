@@ -17,6 +17,20 @@ rounded up to a geometric-bucket number of HIST_CHUNK-row chunks
 chunks mask rows outside the range by position (two iota compares —
 there is no row_leaf array at all on this path).
 
+The histogram's window follows a LADDER of its own (`hist_rungs`):
+`r x {1, 2, 4, ...}` rows up to one chunk, then the chunk buckets, each
+window starting on a multiple of `r` and the kernel's row block being
+min(rung, HIST_CHUNK). `r` (`min_rows`) follows from the one-hot
+elements a data row costs, `f x b_pad`: where a whole chunk costs no
+more than what stands round a call (28 or 136 columns: 10-52 us) `r` is
+HIST_CHUNK and the ladder is `bucket_sizes`; at 2,000 columns and 63
+bins a chunk is 780 us, `r` is 512, and a 300-row leaf streams 512
+rows. The row padding, the partition step's decision window
+(`cover_index` / `window_start`) and `compacted_histograms` keep whole
+chunks. In a trace every rung at each call site is its own
+`seg_hist.<n>` instruction: calls and ms a call by rung are read from
+those (docs/Observability.md).
+
 Bins are packed 4 features per int32 word (W = ceil(F/4), feature f in
 byte f%4 of word f//4): one permutation gather moves 4 features at
 once, and the kernel unpacks with a shift+mask (2 VPU ops per feature
@@ -93,24 +107,36 @@ def canonical_row_chunks(n_chunks):
     return -(-n_chunks // step) * step
 
 
+def rung_index(begin, cnt, rungs, unit):
+    """`lax.switch` index of the smallest of `rungs` (ascending window
+    lengths in units of `unit` rows) that covers the position range
+    [begin, begin+cnt) from a multiple of `unit`, and the first covered
+    unit. A consumer MUST window with `rung_start` over the same
+    ladder."""
+    first = begin // unit
+    last = (begin + jnp.maximum(cnt, 1) - 1) // unit
+    needed = last - first + 1
+    idx = jnp.searchsorted(jnp.asarray(rungs, dtype=jnp.int32), needed)
+    return idx, first
+
+
+def rung_start(first, rung, n_units, unit):
+    """First ROW of the `rung`-unit window at unit `first`, clipped
+    in-bounds (a pulled-back window still covers the range; see
+    rung_index)."""
+    return jnp.clip(first, 0, n_units - rung) * unit
+
+
 def cover_index(begin, cnt, n_chunks):
-    """Chunk-cover dispatch shared by segment_histograms and the
-    partition step (models/partitioned.py _partition_segment): the
-    `lax.switch` bucket index + first covered chunk for the position
-    range [begin, begin+cnt). Both consumers MUST window with
-    `window_start` so their slices agree."""
-    c_first = begin // HIST_CHUNK
-    c_last = (begin + jnp.maximum(cnt, 1) - 1) // HIST_CHUNK
-    needed = c_last - c_first + 1
-    idx = jnp.searchsorted(
-        jnp.asarray(bucket_sizes(n_chunks), dtype=jnp.int32), needed)
-    return idx, c_first
+    """Chunk-cover dispatch of the partition step (models/partitioned.py
+    _partition_segment) and `compacted_histograms`: `rung_index` over
+    `bucket_sizes`' whole chunks. Window with `window_start`."""
+    return rung_index(begin, cnt, bucket_sizes(n_chunks), HIST_CHUNK)
 
 
 def window_start(c_first, bk, n_chunks):
-    """First ROW of the bk-chunk window at c_first, clipped in-bounds
-    (a pulled-back window still covers the range; see cover_index)."""
-    return jnp.clip(c_first, 0, n_chunks - bk) * HIST_CHUNK
+    """First ROW of the bk-chunk window at c_first (see cover_index)."""
+    return rung_start(c_first, bk, n_chunks, HIST_CHUNK)
 
 
 # Feature count from which the kernel body loops over word rows instead
@@ -155,9 +181,43 @@ def feature_blocks(f, num_bins_total):
     return -(-f // fb), fb
 
 
+# One-hot elements (rows x features x padded bins) under which a rung of
+# the histogram's ladder is not halved again. Four MXUs stream 768e9
+# elements a second, so 32M elements are 44 us: about one 4,096-row
+# chunk of the shapes whose chunk was never the cost beside the ~50-200
+# us that stand round the kernel a call (the switch, the window's
+# slices, the accumulator's write-back, `fold`): 28 x 64 is 7M a chunk,
+# 28 x 256 29M, 136 x 64 36M, and their rungs stay whole chunks. At
+# 2,000 x 64 a chunk is 524M elements and 780 us, which a 300-row leaf
+# paid whole (PERF.md PR 34, 36), and a rung is an eighth of it.
+RUNG_ELEMENTS = 32 * 1024 * 1024
+
+
+def min_rows(f, num_bins_total):
+    """Rows of the lowest rung of the histogram's ladder (`r`): the
+    fewest, a power of two from one 128-lane tile to HIST_CHUNK, whose
+    one-hot elements reach RUNG_ELEMENTS at the `f x b_pad` elements one
+    data row costs (`onehot_extent`)."""
+    b_pad, _ = onehot_extent(num_bins_total)
+    r = HIST_CHUNK
+    while r > 128 and (r // 2) * f * b_pad >= RUNG_ELEMENTS:
+        r //= 2
+    return r
+
+
+def hist_rungs(n_chunks, r):
+    """The histogram's window lengths in units of `r` rows: powers of
+    two under one chunk, then `bucket_sizes`' whole chunks (`r` =
+    HIST_CHUNK: `bucket_sizes` itself)."""
+    per_chunk = HIST_CHUNK // r
+    return ([1 << k for k in range(per_chunk.bit_length() - 1)]
+            + [b * per_chunk for b in bucket_sizes(n_chunks)])
+
+
 def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad,
                      lanes, f_total=None):
-    """One grid step = one HIST_CHUNK block of the sliced segment (of
+    """One grid step = one row block of the sliced segment (HIST_CHUNK
+    rows, or the whole of a rung under a chunk: `words_ref.shape[1]`; of
     one block of `f` features where the grid has a feature axis: then
     `f_total` is the call's feature count, the feature block is the
     outer grid axis and the row block the inner one, so an accumulator
@@ -230,10 +290,13 @@ def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad,
 
 def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
                   interpret=False):
-    """Pallas segment histogram over a chunk-aligned slice. `interpret`
-    runs the kernel body in pallas interpret mode (CPU) — used by tests
-    to validate kernel semantics without TPU hardware."""
+    """Pallas segment histogram over a window of `n_blocks` equal row
+    blocks (one rung of the ladder: HIST_CHUNK rows a block, or the
+    whole of a rung under a chunk). `interpret` runs the kernel body in
+    pallas interpret mode (CPU) — used by tests to validate kernel
+    semantics without TPU hardware."""
     w = words_sl.shape[0]
+    block = words_sl.shape[1] // n_blocks
     b_pad, lanes = onehot_extent(num_bins_total)
     n_fb, fb = feature_blocks(f, num_bins_total)
     acc_shape = ((-(-f // 4), 4 * b_pad, STAT_TERMS) if lanes == 4
@@ -245,9 +308,9 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
         kernel = functools.partial(_seg_hist_kernel, f=f, b_pad=b_pad,
                                    lanes=lanes)
         grid = (n_blocks,)
-        words_spec = pl.BlockSpec((w, HIST_CHUNK), lambda i: (0, i),
+        words_spec = pl.BlockSpec((w, block), lambda i: (0, i),
                                   memory_space=pltpu.VMEM)
-        stats_spec = pl.BlockSpec((HIST_CHUNK, STAT_TERMS), lambda i: (i, 0),
+        stats_spec = pl.BlockSpec((block, STAT_TERMS), lambda i: (i, 0),
                                   memory_space=pltpu.VMEM)
         out_spec = pl.BlockSpec(acc_shape, lambda i: (0, 0, 0),
                                 memory_space=pltpu.VMEM)
@@ -255,10 +318,9 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
         kernel = functools.partial(_seg_hist_kernel, f=fb, b_pad=b_pad,
                                    lanes=lanes, f_total=f)
         grid = (n_fb, n_blocks)
-        words_spec = pl.BlockSpec((fb // 4, HIST_CHUNK), lambda j, i: (j, i),
+        words_spec = pl.BlockSpec((fb // 4, block), lambda j, i: (j, i),
                                   memory_space=pltpu.VMEM)
-        stats_spec = pl.BlockSpec((HIST_CHUNK, STAT_TERMS),
-                                  lambda j, i: (i, 0),
+        stats_spec = pl.BlockSpec((block, STAT_TERMS), lambda j, i: (i, 0),
                                   memory_space=pltpu.VMEM)
         out_spec = pl.BlockSpec((fb // lanes,) + acc_shape[1:],
                                 lambda j, i: (j, 0, 0),
@@ -308,8 +370,9 @@ def segment_histograms(words, ghc_t, begin, cnt, num_bins_total, f,
       num_bins_total: static histogram width B.
       f: static real feature count (<= 4W).
 
-    Returns (F, B, 3) float32. Cost scales with the geometric chunk
-    bucket covering the segment (bucket_sizes), not with N.
+    Returns (F, B, 3) float32. Cost scales with the rung of the ladder
+    (`hist_rungs` over `min_rows(f, num_bins_total)` rows) covering the
+    segment, not with N.
 
     Sub-scopes of the device scope `hist` (telemetry/trace.py): `window`
     (slices, transpose, stat split), the kernel `seg_hist`, `fold`.
@@ -317,12 +380,13 @@ def segment_histograms(words, ghc_t, begin, cnt, num_bins_total, f,
     w, n = words.shape
     if n % HIST_CHUNK != 0:
         raise ValueError(f"N={n} must be a multiple of {HIST_CHUNK}")
-    n_chunks = n // HIST_CHUNK
-    buckets = bucket_sizes(n_chunks)
+    r = min_rows(f, num_bins_total)
+    n_units = n // r
+    rungs = hist_rungs(n // HIST_CHUNK, r)
 
     begin = begin.astype(jnp.int32)
     cnt = jnp.maximum(cnt, 0).astype(jnp.int32)
-    idx, c_first = cover_index(begin, cnt, n_chunks)
+    idx, u_first = rung_index(begin, cnt, rungs, r)
 
     if interpret_backend is None:
         # same dispatch as ops/pallas_hist.py masked_histograms: a
@@ -333,20 +397,25 @@ def segment_histograms(words, ghc_t, begin, cnt, num_bins_total, f,
     else:
         on_tpu = interpret_backend == "tpu"
 
-    def make_branch(bk):
+    def make_branch(rung):
+        rows = rung * r
+
         def branch(begin, cnt):
             with scope("window"):
-                start = window_start(c_first, bk, n_chunks)
+                start = rung_start(u_first, rung, n_units, r)
                 words_sl = jax.lax.dynamic_slice(
-                    words, (jnp.int32(0), start), (w, bk * HIST_CHUNK))
+                    words, (jnp.int32(0), start), (w, rows))
                 ghc_sl = jax.lax.dynamic_slice(
-                    ghc_t, (jnp.int32(0), start), (3, bk * HIST_CHUNK)).T
+                    ghc_t, (jnp.int32(0), start), (3, rows)).T
                 lo = begin - start
                 hi = lo + cnt
             if on_tpu:
+                # the kernel's row block: a whole rung under a chunk
                 return _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f,
-                                     num_bins_total, bk, interpret=interpret)
+                                     num_bins_total,
+                                     max(rows // HIST_CHUNK, 1),
+                                     interpret=interpret)
             return _seg_hist_xla(words_sl, ghc_sl, lo, hi, f, num_bins_total)
         return branch
 
-    return jax.lax.switch(idx, [make_branch(b) for b in buckets], begin, cnt)
+    return jax.lax.switch(idx, [make_branch(u) for u in rungs], begin, cnt)

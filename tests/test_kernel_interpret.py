@@ -300,12 +300,13 @@ def _seg_hist_128_rows(words_sl, ghc_sl, lo, hi, f, num_bins_total,
         if f % 4:
             word_row(f // 4, f % 4)
 
-    w = words_sl.shape[0]
+    w, n = words_sl.shape
+    block = n // n_blocks
     out = pl.pallas_call(
         kernel, interpret=True, grid=(n_blocks,),
         in_specs=[pl.BlockSpec((2,), lambda i: (0,)),
-                  pl.BlockSpec((w, HIST_CHUNK), lambda i: (0, i)),
-                  pl.BlockSpec((HIST_CHUNK, STAT_TERMS), lambda i: (i, 0))],
+                  pl.BlockSpec((w, block), lambda i: (0, i)),
+                  pl.BlockSpec((block, STAT_TERMS), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((f, b_pad, STAT_TERMS), lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((f, b_pad, STAT_TERMS), jnp.float32),
     )(jnp.stack([lo, hi]).astype(jnp.int32), words_sl, split_stats(ghc_sl))
@@ -427,6 +428,71 @@ def test_segment_kernel_feature_blocks(f, b, blocks, block_features):
     assert got.shape == (f, b, 3)
     np.testing.assert_array_equal(got[..., 2], want[..., 2])
     np.testing.assert_array_equal(got[..., 2].sum(axis=1), np.full(f, 2048))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("rows", [128, 512, 2048])
+@pytest.mark.parametrize("f,b", [(150, 63), (515, 63), (70, 255), (8, 63)])
+def test_segment_kernel_block_under_a_chunk(rows, f, b):
+    """A rung under a chunk (PR 36) is one row block of that many rows:
+    with the feature axis (150 and 515 columns at 63 bins, 70 at 255: a
+    partly filled last word row and last block) and without (8), the
+    sums are the 128-row body's over the same block bit for bit, close
+    to the XLA formulation's, the counts exact; the grid has one row
+    step and the blocks are the window's rows."""
+    from lightgbm_tpu.ops.ordered_hist import (_seg_hist_tpu, _seg_hist_xla,
+                                               feature_blocks)
+    rng = np.random.RandomState(rows + f + b)
+    bins = rng.randint(0, b, size=(f, rows), dtype=np.uint8)
+    words = jnp.asarray(pack_feature_words(bins))
+    stats = rng.randn(rows, 3).astype(np.float32)
+    stats[:, 2] = 1.0
+    lo, hi = rows // 8 + 3, rows - 5
+    args = (words, jnp.asarray(stats), jnp.int32(lo), jnp.int32(hi), f, b, 1)
+    jaxpr = jax.make_jaxpr(
+        lambda w, g, lo, hi: _seg_hist_tpu(w, g, lo, hi, *args[4:],
+                                           interpret=True))(*args[:4])
+    (call,) = [e for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    blocks, fb = feature_blocks(f, b)
+    assert mapping.grid == ((blocks, 1) if blocks > 1 else (1,))
+    shapes = [tuple(getattr(d, "block_size", d) for d in bm.block_shape)
+              for bm in mapping.block_mappings]
+    assert shapes[1:3] == [(fb // 4 if blocks > 1 else -(-f // 4), rows),
+                           (rows, 9)]
+    got = np.asarray(_seg_hist_tpu(*args, interpret=True))
+    assert got.shape == (f, b, 3)
+    np.testing.assert_array_equal(got, np.asarray(_seg_hist_128_rows(*args)))
+    np.testing.assert_allclose(got, np.asarray(_seg_hist_xla(*args[:6])),
+                               rtol=1e-5, atol=1e-4)
+    np.testing.assert_array_equal(got[..., 2].sum(axis=1),
+                                  np.full(f, hi - lo))
+
+
+@pytest.mark.parametrize("begin,cnt", [(100, 20), (500, 24), (4000, 300),
+                                       (1000, 1500), (0, 8192)])
+def test_segment_kernel_interpret_over_small_rungs(monkeypatch, begin, cnt):
+    """`segment_histograms` through the kernel, interpreted, over a
+    ladder whose lowest rung is 512 rows at 150 columns (the named
+    constant patched; two feature blocks): the window's rows and mask
+    agree with the XLA formulation over the same ladder for a segment
+    in one rung, across a rung's and a chunk's boundary, and the root."""
+    from lightgbm_tpu.ops import ordered_hist
+    f, b, n = 150, 63, 2 * HIST_CHUNK
+    monkeypatch.setattr(ordered_hist, "RUNG_ELEMENTS", 512 * 152 * 64)
+    assert ordered_hist.min_rows(152, b) == 512
+    rng = np.random.RandomState(begin)
+    bins = rng.randint(0, b, size=(f, n), dtype=np.uint8)
+    words = jnp.asarray(pack_feature_words(bins))
+    ghc_t = rng.randn(3, n).astype(np.float32)
+    ghc_t[2] = 1.0
+    got, want = [np.asarray(segment_histograms(
+        words, jnp.asarray(ghc_t), jnp.int32(begin), jnp.int32(cnt), b,
+        f=152, interpret_backend=backend, interpret=True))
+        for backend in ("tpu", "cpu")]
+    np.testing.assert_array_equal(got[..., 2], want[..., 2])
+    np.testing.assert_array_equal(got[:f, :, 2].sum(axis=1), np.full(f, cnt))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
 
 

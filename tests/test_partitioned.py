@@ -35,17 +35,29 @@ def test_pack_unpack_roundtrip():
         np.testing.assert_array_equal(got, bins[f].astype(np.int32))
 
 
-def test_segment_histogram_matches_dense():
+@pytest.mark.parametrize("r", [4096, 1024, 128])
+def test_segment_histogram_matches_dense(monkeypatch, r):
+    """The dense histogram of the segment's rows, over whole chunks (what
+    8 x 64 one-hot elements a row give) and over a ladder with rungs
+    under a chunk (the named constant patched so that they give `r`, PR
+    36): segments that take the lowest rung, cross a rung's and a
+    chunk's boundary, end with the array, are empty, and the root's."""
+    from lightgbm_tpu.ops import ordered_hist
+    monkeypatch.setattr(ordered_hist, "RUNG_ELEMENTS", r * 8 * 64)
+    assert ordered_hist.min_rows(8, 16) == r
     rng = np.random.RandomState(1)
-    n, f, b = 8192, 6, 16
+    n, f, b = 3 * 4096, 6, 16
     bins = rng.randint(0, b, size=(f, n), dtype=np.uint8)
     words = jnp.asarray(pack_feature_words(bins))
     ghc = rng.rand(3, n).astype(np.float32)
-    for begin, cnt in [(0, n), (100, 500), (4000, 4096), (8000, 192), (5, 0)]:
-        got = jax.jit(
-            lambda be, cn: segment_histograms(
-                words, jnp.asarray(ghc), be, cn, b, f=8)
-        )(jnp.int32(begin), jnp.int32(cnt))
+    fn = jax.jit(lambda be, cn: segment_histograms(
+        words, jnp.asarray(ghc), be, cn, b, f=8))
+    text = str(jax.make_jaxpr(fn)(jnp.int32(0), jnp.int32(0)))
+    assert f"i32[2,{r}]" in text          # the lowest rung's window
+    for begin, cnt in [(0, n), (100, 500), (4000, 4096), (8000, 192), (5, 0),
+                       (100, 20), (120, 20), (1000, 1500), (4000, 300),
+                       (n - 64, 64), (8191, 2)]:
+        got = fn(jnp.int32(begin), jnp.int32(cnt))
         ref = build_histograms(
             jnp.asarray(bins[:, begin:begin + cnt]),
             jnp.asarray(ghc[:, begin:begin + cnt].T), b,
@@ -620,3 +632,124 @@ def test_split_hist_cache_is_the_five_lines(left_small, best_leaf, right_id,
     np.testing.assert_array_equal(new_cache[right_id], hist_right)
     rest = [i for i in range(l) if i not in (best_leaf, right_id)]
     np.testing.assert_array_equal(new_cache[rest], cache[0, rest])
+
+
+# ------------- the histogram's own ladder, with rungs under a chunk (PR 36)
+# (f = 4 x packed word rows, num_bins_total, chunks of the padded rows)
+# of the benchmark's five cells
+CELL_SHAPES = {
+    "higgs10m-b255-l63": (28, 255, 2816),
+    "higgs10m-b63-l255": (28, 63, 2816),
+    "mslr-web30k-b63-l255": (136, 63, 576),
+    "mslr-web30k-mc5-b63-l255": (136, 63, 576),
+    "epsilon400k-b63-l255": (2000, 63, 104),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_hist_ladder_follows_the_row(cell):
+    """The lowest rung follows the one-hot elements a data row costs:
+    at the four narrow shapes a rung is a whole chunk and the ladder is
+    `bucket_sizes` (the program the parent traced); at 2,000 x 63 it is
+    512 rows, three rungs under a chunk and then the chunk buckets."""
+    from lightgbm_tpu.ops.ordered_hist import (bucket_sizes, hist_rungs,
+                                               min_rows)
+    from lightgbm_tpu.ops.pallas_hist import HIST_CHUNK
+    f, b, n_chunks = CELL_SHAPES[cell]
+    r = min_rows(f, b)
+    rungs = hist_rungs(n_chunks, r)
+    if cell.startswith("epsilon"):
+        assert r == 512
+        assert rungs == [1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 832]
+        assert [u * r // HIST_CHUNK for u in rungs[3:]] == bucket_sizes(104)
+    else:
+        assert r == HIST_CHUNK
+        assert rungs == bucket_sizes(n_chunks)
+    assert rungs[-1] * r == n_chunks * HIST_CHUNK  # the root's rung
+
+
+def test_hist_ladder_bounds(monkeypatch):
+    """A rung never falls under one 128-lane tile nor passes a chunk,
+    whatever a row costs, and is a power of two."""
+    from lightgbm_tpu.ops import ordered_hist
+    from lightgbm_tpu.ops.pallas_hist import HIST_CHUNK
+    assert ordered_hist.min_rows(100000, 255) == 128
+    assert ordered_hist.min_rows(1, 2) == HIST_CHUNK
+    assert ordered_hist.hist_rungs(1, 128) == [1, 2, 4, 8, 16, 32]
+    seen = {ordered_hist.min_rows(f, 63) for f in range(4, 40000, 4)}
+    assert seen == {128, 256, 512, 1024, 2048, 4096}
+    monkeypatch.setattr(ordered_hist, "RUNG_ELEMENTS", 1)
+    assert ordered_hist.min_rows(4, 16) == 128
+
+
+@pytest.mark.parametrize("r", [128, 512, 4096])
+@pytest.mark.parametrize("begin,cnt", [
+    (0, 0), (0, 1), (5, 0), (4095, 1), (4096, 1), (100, 300), (500, 24),
+    (510, 4), (4000, 300), (4090, 4096), (0, 4096), (3, 4096),
+    (0, 5 * 4096), (5 * 4096 - 1, 1), (5 * 4096 - 700, 700),
+    (4 * 4096 + 1, 4095), (1, 5 * 4096 - 1), (2048, 8192), (2047, 8193)])
+def test_hist_window_is_the_smallest_aligned_cover(r, begin, cnt):
+    """For a segment anywhere in five chunks (empty, one row, across a
+    rung's and a chunk's boundary, at the array's end): the window
+    starts on a multiple of `r`, lies in bounds, covers the segment, and
+    no smaller rung could from that alignment."""
+    from lightgbm_tpu.ops.ordered_hist import (hist_rungs, rung_index,
+                                               rung_start)
+    n = 5 * 4096
+    rungs = hist_rungs(5, r)
+    idx, first = rung_index(jnp.int32(begin), jnp.int32(cnt), rungs, r)
+    idx, first = int(idx), int(first)
+    rows = rungs[idx] * r
+    start = int(rung_start(first, rungs[idx], n // r, r))
+    assert start % r == 0 and 0 <= start and start + rows <= n
+    assert start <= begin and begin + cnt <= start + rows
+    needed = (begin + max(cnt, 1) - 1) // r - begin // r + 1
+    assert rungs[idx] >= needed
+    assert idx == 0 or rungs[idx - 1] < needed
+
+
+def test_small_rungs_grow_the_same_tree(monkeypatch):
+    """One tree over a ladder whose lowest rung is 128 rows (the named
+    constant patched) against the same tree over whole chunks: the same
+    splits, leaf values to float32 rounding (a leaf's rows meet block
+    boundaries elsewhere, so the order of float32 additions differs);
+    and the gauges and the hit share the two leave in the registry."""
+    from lightgbm_tpu.ops import ordered_hist
+    from lightgbm_tpu.ops.pallas_hist import HIST_CHUNK
+    rng = np.random.RandomState(360)
+    n, f = 3 * HIST_CHUNK - 100, 8
+    x = rng.randn(n, f).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] * x[:, 2] + 0.3 * rng.randn(n) > 0).astype(
+        np.float32)
+    params = {"objective": "binary", "num_leaves": 31, "max_bin": 63,
+              "min_data_in_leaf": 1, "min_sum_hessian_in_leaf": 1.0,
+              "metric_freq": 0, "partitioned_build": "true"}
+    whole = _booster(x, y, params)
+    whole.train_many(2)
+    monkeypatch.setattr(ordered_hist, "RUNG_ELEMENTS", 1)
+    small = _booster(x, y, params)
+    small.train_many(2)
+    assert len(whole.models) == len(small.models) == 2
+    for tw, ts in zip(whole.models, small.models):
+        assert len(tw.split_feature) == 30
+        np.testing.assert_array_equal(tw.split_feature, ts.split_feature)
+        np.testing.assert_array_equal(tw.threshold_in_bin,
+                                      ts.threshold_in_bin)
+        np.testing.assert_array_equal(tw.leaf_count, ts.leaf_count)
+        np.testing.assert_allclose(tw.leaf_value, ts.leaf_value, rtol=2e-5)
+    snap_w, snap_s = whole.metrics.snapshot(), small.metrics.snapshot()
+    assert snap_w["gauges"]["seg_hist_min_rows"] == HIST_CHUNK
+    assert snap_w["gauges"]["seg_hist_rungs"] == 3       # 1, 2, 3 chunks
+    assert snap_s["gauges"]["seg_hist_min_rows"] == 128
+    assert snap_s["gauges"]["seg_hist_rungs"] == 8       # 128 .. 2,048 rows
+    assert snap_w["counters"]["seg_hist_calls"] == 60
+    assert snap_w["counters"]["seg_hist_subchunk_calls"] == 0
+    assert snap_s["counters"]["seg_hist_calls"] == 60
+    # every split whose smaller child is under a chunk, recounted
+    want = 0
+    for t in small.models:
+        for left, right in zip(t.left_child, t.right_child):
+            rows = [t.internal_count[c] if c >= 0 else t.leaf_count[~c]
+                    for c in (left, right)]
+            want += min(rows) < HIST_CHUNK
+    assert snap_s["counters"]["seg_hist_subchunk_calls"] == want > 30

@@ -837,6 +837,48 @@ def test_builder_compiles_at_2000_columns(one_chip, b, blocks):
     assert names == {"seg_hist", "partition_rows"}
 
 
+@pytest.mark.parametrize("columns,w,b,window_rows", [
+    (28, 7, 63, [4096, 8192, 16384]), (28, 7, 255, [4096, 8192, 16384]),
+    (136, 34, 63, [4096, 8192, 16384]),
+    (2000, 500, 63, [512, 1024, 2048, 4096, 8192, 16384])])
+def test_seg_hist_windows_follow_the_row(one_chip, columns, w, b,
+                                         window_rows):
+    """The windows the histogram kernel is compiled for, from the
+    builder's compiled text at four chunks of rows (ISSUE 36): at the
+    narrow cells' shapes (28 x 63, 28 x 255, 136 x 63) exactly the whole
+    chunks 1, 2, 4 the parent compiled, at both call sites; at 2,000 x
+    63 three rungs under a chunk before them. The kernel's second
+    operand is the window of packed words, the third the statistics'
+    nine terms over the same rows; every call sits under `hist`."""
+    from lightgbm_tpu.ops.ordered_hist import min_rows
+    from lightgbm_tpu.ops.partition import packed_word_rows
+    assert min_rows(4 * w, b) == window_rows[0]
+    wp = packed_word_rows(w)
+    core, shapes = builder(4 * 4096, w=w, b=b, l=3)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    with fresh_compiles(), \
+            mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(core).lower(*args).compile().as_text()
+    seen = []
+    for body in hlo_computations(text).values():
+        for name, ln in body.items():
+            if "tpu_custom_call" not in ln or not name.startswith("seg_hist"):
+                continue
+            path = re.search(r'op_name="([^"]*)"', ln).group(1).split("/")
+            assert path.index("hist") < path.index("seg_hist"), ln[:200]
+            _, words, stats = re.search(
+                r"custom-call\(([^)]*)\)", ln).group(1).split(", ")
+            shape = [re.match(r"\s+(?:ROOT )?%\S+ = (\w+\[[\d,]*\])",
+                              body[op.lstrip("%")]).group(1)
+                     for op in (words, stats)]
+            rows = int(re.match(r"s32\[(\d+),(\d+)\]", shape[0]).group(2))
+            assert shape == [f"s32[{wp},{rows}]", f"bf16[{rows},9]"], shape
+            seen.append(rows)
+    # the root's call site and the loop's: every rung once at each
+    assert sorted(seen) == sorted(2 * window_rows), seen
+
+
 def hlo_computations(text):
     """{computation: {instruction: its line}} of a compiled module's
     text, both in the text's order."""
