@@ -15,23 +15,37 @@ A from-scratch reimplementation of the capabilities of LightGBM
 
 Public API mirrors the reference python-package
 (`python-package/lightgbm/__init__.py:11-25`).
+
+The package's own import is the process tracer's span `import`, and the
+scikit-learn wrappers' its child `import/sklearn` (telemetry/trace.py
+PROCESS_TRACER): jax and the backend are not in it when the embedder
+imported them first.
 """
 
-from .basic import Dataset, Booster, LightGBMError
-from .engine import train, cv
-from .callback import (
+import time
+
+_IMPORT_T0 = time.perf_counter()
+
+from .basic import Dataset, Booster, LightGBMError  # noqa: E402
+from .engine import train, cv  # noqa: E402
+from .callback import (  # noqa: E402
     print_evaluation,
     record_evaluation,
     reset_parameter,
     early_stopping,
     EarlyStopException,
 )
+from .telemetry.trace import PROCESS_TRACER  # noqa: E402
 
+_sklearn_t0 = time.perf_counter()
 try:
     from .sklearn import LGBMModel, LGBMRegressor, LGBMClassifier, LGBMRanker
     SKLEARN_INSTALLED = True
 except ImportError:  # pragma: no cover - sklearn is expected in this image
     SKLEARN_INSTALLED = False
+_import_t1 = time.perf_counter()
+PROCESS_TRACER.add("import/sklearn", _import_t1 - _sklearn_t0)
+PROCESS_TRACER.add("import", _import_t1 - _IMPORT_T0)
 
 __version__ = "0.1.0"
 

@@ -698,21 +698,15 @@ class Config:
 # (JAX_COMPILATION_CACHE_DIR) or from the checkout, never from $HOME, a
 # temp name, a pid or the clock.
 
-_CACHE_HITS = {"hits": 0, "misses": 0, "listener": False,
-               "warned_unusable": False}
-
-
-def _cache_event_listener(name, **kwargs):
-    if name == "/jax/compilation_cache/cache_hits":
-        _CACHE_HITS["hits"] += 1
-    elif name == "/jax/compilation_cache/cache_misses":
-        _CACHE_HITS["misses"] += 1
+_CACHE_STATE = {"configured": False, "warned_unusable": False}
 
 
 def compile_cache_hits():
-    """Process-wide persistent-cache hit count (bench.py reports the
-    delta around its warm-up compile as `compile_cache_hit`)."""
-    return _CACHE_HITS["hits"]
+    """Process-wide persistent-cache hit count, the compile ledger's
+    (telemetry/ledger.py; bench.py reports the delta around its warm-up
+    compile as `compile_cache_hit`)."""
+    from .telemetry.ledger import LEDGER
+    return LEDGER.cache_hits
 
 
 def checkout_cache_dir():
@@ -741,9 +735,8 @@ def setup_compilation_cache(config=None):
     if mode.lower() in ("off", "false", "0", "-", "none"):
         return None
     import jax
-    if not _CACHE_HITS["listener"]:
-        _CACHE_HITS["listener"] = True
-        jax.monitoring.register_event_listener(_cache_event_listener)
+    if not _CACHE_STATE["configured"]:
+        _CACHE_STATE["configured"] = True
         # the tree builders' XLA-backend compile can land under the 1s
         # default threshold even when the full trace+lower+compile is
         # 10s+ — cache every executable, the disk cost is a few MB
@@ -759,8 +752,8 @@ def setup_compilation_cache(config=None):
         if not os.access(path, os.W_OK):
             raise PermissionError("directory is not writable")
     except OSError as e:
-        if not _CACHE_HITS["warned_unusable"]:
-            _CACHE_HITS["warned_unusable"] = True
+        if not _CACHE_STATE["warned_unusable"]:
+            _CACHE_STATE["warned_unusable"] = True
             Log.warning("compile cache off: cannot use %s (%s); set "
                         "JAX_COMPILATION_CACHE_DIR to place it elsewhere",
                         path, e)

@@ -31,6 +31,7 @@ import os
 
 import numpy as np
 
+from ..telemetry.ledger import LEDGER
 from ..telemetry.trace import PROCESS_TRACER
 from ..utils.log import Log
 from ..utils.random import Random
@@ -176,9 +177,12 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
     device path.
 
     Spans `dataset/host_prep`, `upload`, `bin_device`, `download`,
-    `pack` on the process tracer tell the phases apart; the
-    `block_until_ready` between upload and compute only makes visible
-    an order that was serial already."""
+    `pack` on the process tracer tell the phases apart (`upload` and
+    `download` tagged with their `bytes`); the `block_until_ready`
+    between upload and compute only makes visible an order that was
+    serial already. The compile ledger's label `dataset_bin` inside
+    `bin_device` marks the program's compile-or-load a hit or a miss
+    of the persistent cache."""
     mode = os.environ.get("LIGHTGBM_TPU_DEVICE_BIN", "auto")
     if mode == "0":
         return None
@@ -223,7 +227,7 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
         # differs when a column has negative bounds
         if np.isnan(x_used).any():
             x_used = np.nan_to_num(x_used, nan=0.0)
-    with span("upload"):
+    with span("upload", bytes=int(x_used.nbytes + b32.nbytes)):
         xdev = jnp.asarray(x_used).reshape(n_pad // chunk, chunk, f)
         bdev = jnp.asarray(b32)
         jax.block_until_ready((xdev, bdev))
@@ -236,9 +240,13 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
                            axis=-1, dtype=jnp.int32).astype(out_dt)
         return jax.lax.map(one, xc)
 
-    with span("bin_device"):     # first call: compile or load, then run
+    # no learner has set the compile cache up yet: the ledger listens
+    # from here, so that the label sees a hit
+    LEDGER.install()
+    with span("bin_device"), LEDGER.label("dataset_bin"):
+        # first call: compile or load, then run
         binned = jax.block_until_ready(bin_all(xdev))
-    with span("download"):
+    with span("download", bytes=int(binned.nbytes)):
         # narrow on device: the download is N x F bytes, not 4x that
         out = np.asarray(binned).reshape(n_pad, f)[:n]
     with span("pack"):
@@ -1360,10 +1368,12 @@ class DatasetLoader:
         if cfg.is_enable_sparse:
             # per-column callable: planning a wide-sparse input never
             # builds the dense (F, sample) bins stack
-            plan = plan_bundles(
-                mappers,
-                lambda u: mappers[u].value_to_bin(sample_col(real_idx[u])),
-                enable=True, max_conflict_rate=cfg.max_conflict_rate)
+            with span("bundle_plan"):
+                plan = plan_bundles(
+                    mappers,
+                    lambda u: mappers[u].value_to_bin(
+                        sample_col(real_idx[u])),
+                    enable=True, max_conflict_rate=cfg.max_conflict_rate)
             if plan.is_identity:
                 plan = None
 
@@ -1385,10 +1395,11 @@ class DatasetLoader:
             dtype = bins_dtype(int(plan.slot_bins.max()))
             check_bins_budget(plan.num_slots, n, np.dtype(dtype).itemsize,
                               "Bundled dataset construction")
-            ds.bins = build_stored_matrix(
-                plan,
-                lambda u: mappers[u].value_to_bin(src.col(real_idx[u])),
-                dtype)
+            with span("bundle_plan"):
+                ds.bins = build_stored_matrix(
+                    plan,
+                    lambda u: mappers[u].value_to_bin(src.col(real_idx[u])),
+                    dtype)
             ds.bundle_plan = plan
         ds.bin_mappers = mappers
         ds.used_feature_map = used_map
@@ -1399,9 +1410,11 @@ class DatasetLoader:
         # training-time half of the serving drift story
         from .profile import DatasetProfile, count_missing, profiling_enabled
         if profiling_enabled():
-            missing = (count_missing(src._m, ds.real_feature_idx)
-                       if isinstance(src, DenseColumns) else None)
-            ds.profile = DatasetProfile.from_dataset(ds, missing=missing)
+            with span("profile"):
+                missing = (count_missing(src._m, ds.real_feature_idx)
+                           if isinstance(src, DenseColumns) else None)
+                ds.profile = DatasetProfile.from_dataset(ds,
+                                                         missing=missing)
         Log.info("Number of data: %d, number of features: %d", n, len(mappers))
         return ds
 

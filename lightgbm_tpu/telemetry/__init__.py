@@ -3,10 +3,10 @@ live /trainz endpoint.
 
 The training-side observability stack (docs/Observability.md):
 
-- `trace.SpanTracer` — per-Booster nested span timing (replaces the
-  global `utils/timers.py` singleton), each span also a
-  `jax.profiler.TraceAnnotation` once the embedder has imported jax;
-  `trace.PROCESS_TRACER` for work that belongs to no Booster;
+- `trace.SpanTracer` — per-Booster nested span timing, each span also
+  a `jax.profiler.TraceAnnotation` once the embedder has imported jax;
+  `trace.PROCESS_TRACER` for work that belongs to no Booster (set-up:
+  import, dataset, booster init, the compile ledger's labels);
   `trace.DEVICE_SCOPES`, the `jax.named_scope` vocabulary of the
   device program.
 - `registry.MetricsRegistry` — thread-safe counters/gauges/histograms;

@@ -18,6 +18,10 @@ cannot answer:
   compiles are separable line items on /trainz and /metricz, and keeps
   the wall seconds each label covered (`label_seconds`), which is how
   trace + lower — served by no cache — is told from compile-or-load.
+  Each label is also a span of the same name on the process tracer
+  (telemetry/trace.py PROCESS_TRACER, on the profiler's clock), timed
+  once for both views and tagged `cache_hit`: whether a
+  persistent-cache hit fired inside it on its thread.
   On jax 0.9.0 `/jax/core/compile/backend_compile_duration` brackets
   `compile_or_get_cached`: it fires for a persistent-cache HIT too (its
   duration is then the load), after `/jax/compilation_cache/cache_hits`
@@ -38,6 +42,8 @@ import os
 import threading
 import time
 from collections import deque
+
+from .trace import PROCESS_TRACER
 
 RECENT_COMPILES = 256
 
@@ -83,9 +89,14 @@ class CompileLedger:
 
     def label(self, name):
         """Context manager attributing compiles inside it to `name`
-        (innermost label wins) and adding the wall seconds it covered
-        to `label_seconds[name]`."""
+        (innermost label wins), adding the wall seconds it covered to
+        `label_seconds[name]` and recording them as a process span.
+        Hits are seen only once `install()` has run."""
         return _LabelContext(self, str(name))
+
+    def _thread_hits(self):
+        """Persistent-cache hits seen on this thread so far."""
+        return getattr(self._local, "hits", 0)
 
     # -------------------------------------------------------- listeners
     def install(self):
@@ -132,6 +143,7 @@ class CompileLedger:
     def _on_event(self, name, **kwargs):
         if name == _CACHE_HIT_EVENT:
             self._local.hit = True
+            self._local.hits = self._thread_hits() + 1
             with self._lock:
                 self.cache_hits += 1
         elif name == _CACHE_MISS_EVENT:
@@ -175,27 +187,36 @@ class CompileLedger:
 
 
 class _LabelContext:
-    __slots__ = ("_ledger", "_name", "_t0")
+    """One label: a process span of the same name whose seconds are
+    also the label's."""
+
+    __slots__ = ("_ledger", "_name", "_span", "_hits0")
 
     def __init__(self, ledger, name):
         self._ledger = ledger
         self._name = name
-        self._t0 = None
+        self._span = None
+        self._hits0 = 0
 
     def __enter__(self):
-        self._ledger._labels().append(self._name)
-        self._t0 = time.perf_counter()
+        led = self._ledger
+        led._labels().append(self._name)
+        self._hits0 = led._thread_hits()
+        self._span = PROCESS_TRACER.span(self._name)
+        self._span.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        elapsed = time.perf_counter() - self._t0
         led = self._ledger
+        span = self._span
+        span.tag(cache_hit=led._thread_hits() > self._hits0)
+        span.__exit__(exc_type, exc, tb)
         stack = led._labels()
         if stack and stack[-1] == self._name:
             stack.pop()
         with led._lock:
             led.label_seconds[self._name] = (
-                led.label_seconds.get(self._name, 0.0) + elapsed)
+                led.label_seconds.get(self._name, 0.0) + span.seconds)
         return False
 
 

@@ -1,8 +1,7 @@
 """Span tracer: nested, tagged wall-clock spans for the training loop.
 
-Replaces the `utils/timers.py` global `PhaseTimers` singleton (whose
-accumulator two Boosters trained in one process silently shared) with a
-per-Booster instance. The reference's observability surface is the
+One instance per Booster, so two Boosters trained in one process keep
+their own totals. The reference's observability surface is the
 cumulative network-time counters in include/LightGBM/network.h /
 src/network/linkers.h:195-212 plus ad-hoc timers in application.cpp;
 GPU tree-boosting systems report per-kernel phase breakdowns as the
@@ -12,10 +11,8 @@ tracer is that instrument for the host-visible side of training.
 Three views of the same spans:
 
 - **Accumulator** (`acc`/`cnt`/`snapshot`/`report`): per-phase total
-  seconds and call counts, drop-in compatible with the old PhaseTimers
-  API so existing call sites and the bench keep working. A top-level
-  span counts under its name, a child under its path
-  (`fused_block/wait`).
+  seconds and call counts. A top-level span counts under its name, a
+  child under its path (`fused_block/wait`).
 - **Deltas** (`delta_snapshot`): per-phase seconds of the TOP-LEVEL
   spans since the previous call — what the run journal attaches to each
   iteration record; children are left out so a record's phases stay a
@@ -40,8 +37,12 @@ one place:
   (models/partitioned.py, ops/ordered_hist.py) write into each device
   operation's `op_name`; `scope(word)` is how they write it.
 - `PROCESS_TRACER`: one process-level tracer for work that belongs to no
-  Booster (dataset construction precedes any, and a reader may outlive
-  all of them). Nothing is mirrored between it and a Booster's tracer.
+  Booster, and the one record of set-up: the package's `import`
+  (`lightgbm_tpu/__init__.py`), `dataset/...`, `rank_layout`,
+  `booster_init` (`GBDT.init`), and every compile-ledger label
+  (telemetry/ledger.py), tagged `cache_hit`. A dataset precedes any
+  Booster, and a reader may outlive all of them. Nothing is mirrored
+  between it and a Booster's tracer.
 """
 
 import sys
@@ -124,9 +125,11 @@ class Span:
 
 
 class _SpanContext:
-    """Context manager for one span; created by SpanTracer.span()."""
+    """Context manager for one span; created by SpanTracer.span().
+    `seconds` is the span's duration once it has closed."""
 
-    __slots__ = ("_tracer", "_name", "_tags", "_t0", "_path", "_ann")
+    __slots__ = ("_tracer", "_name", "_tags", "_t0", "_path", "_ann",
+                 "seconds")
 
     def __init__(self, tracer, name, tags):
         self._tracer = tracer
@@ -135,6 +138,12 @@ class _SpanContext:
         self._t0 = None
         self._path = None
         self._ann = None
+        self.seconds = None
+
+    def tag(self, **tags):
+        """Add tags known only once the span's work is done; they are
+        recorded when it closes."""
+        self._tags.update(tags)
 
     def __enter__(self):
         tr = self._tracer
@@ -151,7 +160,7 @@ class _SpanContext:
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        elapsed = time.perf_counter() - self._t0
+        elapsed = self.seconds = time.perf_counter() - self._t0
         tr = self._tracer
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
@@ -167,7 +176,7 @@ class SpanTracer:
     docstring).
 
     The accumulator keys on the span's path: a top-level span's is its
-    name, as the old PhaseTimers kept it. Thread-safe: concurrent threads keep independent nesting stacks and
+    name. Thread-safe: concurrent threads keep independent nesting stacks and
     the shared accumulator mutates under one lock.
     """
 
@@ -196,7 +205,7 @@ class SpanTracer:
         """Context manager timing one (possibly nested) span."""
         return _SpanContext(self, name, tags)
 
-    # PhaseTimers-compatible alias: `with tracer.phase("build"): ...`
+    # alias the per-iteration loop reads by: `with tracer.phase("build"):`
     phase = span
 
     def _record(self, name, path, elapsed, t0, tags):
@@ -275,8 +284,7 @@ class SpanTracer:
         return [s.as_dict() for s in spans]
 
     def report(self):
-        """One line per phase, largest first (the old PhaseTimers
-        debug report)."""
+        """One line per phase, largest first."""
         with self._lock:
             items = sorted(self.acc.items(), key=lambda kv: -kv[1])
             lines = ["%-12s %8.3fs total, %7.2fms/call x%d"

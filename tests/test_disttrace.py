@@ -595,9 +595,16 @@ def test_router_forwards_trace_and_request_id(tmp_path, binary_model):
                      "X-Request-Id": "fwd-1"})
         assert status == 200
         assert body.get("request_id") == "fwd-1"
-        a.flush()
-        recs = read_trace_records(str(tmp_path))
-        roots = [r for r in recs if r["name"] == "serve.request"]
+        # the replica ends its root span after the response is written:
+        # the client can hold the answer before the span exists
+        deadline = time.time() + 5.0
+        while True:
+            a.flush()
+            recs = read_trace_records(str(tmp_path))
+            roots = [r for r in recs if r["name"] == "serve.request"]
+            if roots or time.time() > deadline:
+                break
+            time.sleep(0.05)
         assert roots and roots[0]["trace_id"] == head.trace_id
     finally:
         rsrv.shutdown()

@@ -229,3 +229,8 @@ def test_spans_of_a_ranking_job():
     assert spans["rank_layout"]["tags"] == {"queries": len(sizes),
                                             "rows": len(y)}
     assert "rank_layout" in PROCESS_TRACER.snapshot()
+    # the objective's set-up stays a path of its own, before the
+    # Booster's init and outside it
+    layout, init = spans["rank_layout"], spans["booster_init"]
+    assert layout["start_s"] + layout["duration_s"] <= init["start_s"] + 2e-6
+    assert not any(p.endswith("/rank_layout") for p in spans)

@@ -3,8 +3,7 @@ metrics registry, structured run journal, /trainz endpoint, and the
 serving /metricz parity after its refactor onto the registry.
 
 Covers the contracts docs/Observability.md documents: span nesting and
-exception safety, per-Booster tracer isolation (the old TIMERS
-singleton cross-contamination), registry thread-safety under
+exception safety, per-Booster tracer isolation, registry thread-safety under
 concurrent writers, journal line atomicity across a hard kill + resume
 (no torn JSONL), multi-rank merge ordering, schema lint of a REAL
 training journal, and phase-delta reconstruction (the bench's journal
@@ -86,26 +85,9 @@ def test_span_delta_snapshot_sums_to_totals():
     assert t.delta_snapshot() == {}  # nothing moved since
 
 
-def test_phase_timers_shim_compat():
-    # utils/timers.py deprecation shim: old API surface intact
-    from lightgbm_tpu.utils.timers import TIMERS, PhaseTimers
-    pt = PhaseTimers()
-    with pt.phase("a"):
-        pass
-    pt.add("b", 0.5)
-    assert set(pt.snapshot()) == {"a", "b"}
-    assert "b" in pt.report()
-    pt.reset()
-    assert pt.snapshot() == {}
-    assert hasattr(TIMERS, "phase")
-
-
 def test_per_booster_tracer_isolation(tmp_path):
     """Two Boosters trained in one process keep independent phase
-    accumulators (the TIMERS global-singleton cross-contamination this
-    PR removes), and the deprecated global stays untouched."""
-    from lightgbm_tpu.utils.timers import TIMERS
-    TIMERS.reset()
+    accumulators."""
     b1 = _train(tmp_path, "iso1", n_rounds=4)
     snap1 = dict(b1.gbdt.tracer.snapshot())
     b2 = _train(tmp_path, "iso2", n_rounds=2)
@@ -113,7 +95,6 @@ def test_per_booster_tracer_isolation(tmp_path):
     # training booster 2 did not move booster 1's accumulator
     assert b1.gbdt.tracer.snapshot() == snap1
     assert b2.gbdt.tracer.snapshot()
-    assert dict(TIMERS.acc) == {}
 
 
 # ------------------------------------------------------- metrics registry

@@ -9,9 +9,11 @@ on a port, a thread or a sleep.
 """
 
 import contextlib
+import json
 import os
 import re
 import struct
+import subprocess
 import sys
 from unittest import mock
 
@@ -133,6 +135,7 @@ def trained():
     del ds, vs
     return {"gbdt": gbdt, "booster": booster, "texts": texts,
             "process_spans": PROCESS_TRACER.recent(None),
+            "process_held": PROCESS_TRACER.snapshot(),
             "ledger": LEDGER.snapshot()}
 
 
@@ -369,8 +372,10 @@ DEVICE_EVENTS = [(1, 100, 1000),
                  (8, 960, 0), (5, 960, 20),
                  (6, 1200, 30)]          # after the window: not counted
 HOST_MD = {1: ("bench_block", None, 0), 2: ("fused_block", None, 0),
-           3: ("fused_block/wait", None, 0), 4: ("valid_update", None, 0)}
-HOST_EVENTS = [(1, 50, 1100), (2, 60, 1000), (3, 100, 900), (4, 1070, 20)]
+           3: ("fused_block/wait", None, 0), 4: ("valid_update", None, 0),
+           5: ("fused_block/guard", None, 0)}
+HOST_EVENTS = [(1, 50, 1100), (2, 60, 1000), (3, 100, 900), (4, 1070, 20),
+               (5, 1010, 30)]
 
 
 def write_trace(tmp_path, device_md):
@@ -439,7 +444,7 @@ def test_scopereduce_on_built_trace(case, tmp_path, monkeypatch):
     elif case == "host_annotations":
         assert tab["host"] == pytest.approx(
             {"fused_block": 1000e-6, "fused_block/wait": 900e-6,
-             "valid_update": 20e-6})
+             "fused_block/guard": 30e-6, "valid_update": 20e-6})
         assert "bench_block" not in tab["host"]
     elif case == "stale_executable":
         assert tab["scoped_s"] == 0
@@ -557,6 +562,7 @@ def test_fused_block_child_spans(trained):
                for c in children) <= block["duration_s"]
     assert by_path["valid_update"]["tags"]["iterations"] == BLOCK
     assert len(trained["gbdt"].models) == BLOCK
+    assert by_path["fused_block/guard"]["tags"]["iterations"] == BLOCK
     # children count under their path; the journal's deltas stay a
     # partition of wall time (top-level spans only)
     snap = trained["gbdt"].tracer.snapshot()
@@ -606,9 +612,10 @@ def test_process_tracer_holds_dataset_spans(trained):
     paths = [s["path"] for s in trained["process_spans"]]
     first = paths[:paths.index("dataset") + 1]     # the train set's
     assert first == ["dataset/sample", "dataset/bin_bounds",
-                     "dataset/host_prep", "dataset/upload",
+                     "dataset/bundle_plan", "dataset/host_prep",
+                     "dataset/upload", "dataset/bin_device/dataset_bin",
                      "dataset/bin_device", "dataset/download",
-                     "dataset/pack", "dataset"]
+                     "dataset/pack", "dataset/profile", "dataset"]
     tags = trained["process_spans"][0]["tags"]
     assert tags == {"rows": ROWS, "features": 6}
     # a Booster's tracer holds none of it, and the other way round
@@ -621,6 +628,51 @@ def test_process_tracer_holds_dataset_spans(trained):
         {"trace": {"busy_s": 1.0}}) == pytest.approx(
             held["dataset/upload"] + held["dataset/bin_device"]
             + held["dataset/download"])
+
+
+def test_process_tracer_names_setup(trained):
+    """Set-up has its names on the process tracer: the Booster's init
+    and its two parts, the bundle plan, the bytes the device binning
+    moves, and the fused program's two ledger labels, `:compile` tagged
+    with whether the persistent cache served it (here it cannot:
+    `fresh_compiles`)."""
+    spans = {s["path"]: s for s in trained["process_spans"]}
+    for path in ("booster_init", "booster_init/learner",
+                 "booster_init/score", "dataset/bundle_plan",
+                 f"fused_scan_{BLOCK}it:lower",
+                 f"fused_scan_{BLOCK}it:compile"):
+        assert spans[path]["duration_s"] > 0, path
+    assert spans["booster_init"]["tags"] == {"rows": ROWS, "features": 6,
+                                             "classes": 1}
+    assert spans[f"fused_scan_{BLOCK}it:compile"]["tags"] == \
+        {"cache_hit": False}
+    assert spans["dataset/bin_device/dataset_bin"]["tags"] == \
+        {"cache_hit": False}
+    # 5,000 rows pad to a 65,536-row chunk: float32 up, one byte down
+    assert spans["dataset/upload"]["tags"]["bytes"] >= 65536 * 6 * 4
+    assert spans["dataset/download"]["tags"]["bytes"] == 65536 * 6
+    # the fused program is built by the first block, after the init
+    init, lower = spans["booster_init"], spans[f"fused_scan_{BLOCK}it:lower"]
+    assert init["start_s"] + init["duration_s"] <= lower["start_s"] + 2e-6
+    kids = sum(spans["booster_init/" + c]["duration_s"]
+               for c in ("learner", "score"))
+    assert kids <= init["duration_s"] + 2e-6
+
+
+def test_import_is_a_process_span():
+    """A fresh process that imports the package holds `import` and its
+    child `import/sklearn`, and no other set-up."""
+    code = ("import json, lightgbm_tpu\n"
+            "from lightgbm_tpu.telemetry.trace import PROCESS_TRACER\n"
+            "print(json.dumps(PROCESS_TRACER.snapshot()))")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.dirname(BENCH))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    held = json.loads(r.stdout.strip().splitlines()[-1])
+    assert set(held) == {"import", "import/sklearn"}
+    assert held["import"] >= held["import/sklearn"] > 0
 
 
 def test_span_annotation_carries_the_path():
@@ -660,6 +712,80 @@ def test_ledger_holds_lower_and_compile_seconds(trained):
     assert reader.read({"trace": {"busy_s": 1.0}}) == pytest.approx(lower)
     # (the snapshot rounds to the microsecond)
     assert lower >= held[f"fused_scan_{BLOCK}it:lower"] - 1e-5
+
+
+def test_ledger_labels_are_process_spans(trained):
+    """Mirroring adds no key to `label_seconds`, and each label's
+    seconds are its process span's, under whatever span was open."""
+    held = trained["ledger"]["label_seconds"]
+    paths = {f"fused_scan_{BLOCK}it:lower": f"fused_scan_{BLOCK}it:lower",
+             f"fused_scan_{BLOCK}it:compile":
+                 f"fused_scan_{BLOCK}it:compile",
+             "dataset_bin": "dataset/bin_device/dataset_bin"}
+    assert set(held) == set(paths)
+    for label, path in paths.items():
+        assert held[label] == pytest.approx(trained["process_held"][path],
+                                            abs=1e-6)
+
+
+def test_ledger_label_span_tags_a_hit():
+    led = CompileLedger()
+    with PROCESS_TRACER.span("outer"):
+        with led.label("fused_scan_3it:compile"):
+            led._on_event(_CACHE_HIT_EVENT)
+        with led.label("serving_bucket_64"):
+            pass
+    hit, miss = PROCESS_TRACER.recent(3)[:2]
+    assert (hit["path"], hit["tags"]) == \
+        ("outer/fused_scan_3it:compile", {"cache_hit": True})
+    assert (miss["path"], miss["tags"]) == \
+        ("outer/serving_bucket_64", {"cache_hit": False})
+    assert set(led.label_seconds) == {"fused_scan_3it:compile",
+                                      "serving_bucket_64"}
+    assert led.label_seconds["fused_scan_3it:compile"] == \
+        pytest.approx(hit["duration_s"], abs=1e-6)
+    assert led.current_label() == ""
+
+
+def test_compile_cache_hits_is_the_ledgers(monkeypatch):
+    """One count of persistent-cache hits: the compile ledger's."""
+    from lightgbm_tpu import config
+    assert config.compile_cache_hits() == LEDGER.cache_hits
+    monkeypatch.setattr(LEDGER, "cache_hits", LEDGER.cache_hits + 41)
+    assert config.compile_cache_hits() == LEDGER.cache_hits
+    assert not [n for n in vars(config) if n.endswith("event_listener")]
+
+
+@pytest.mark.parametrize("name", ["import_s", "dataset_host_s",
+                                  "booster_init_s", "fused_compile_s",
+                                  "fused_cache_hit", "guard_ms_per_iter"])
+def test_setup_readers(name, tmp_path, monkeypatch):
+    """Each reader of PR 37 reads a recorded process tracer (or, for
+    the guard, a built trace), and nothing where the harness hands no
+    trace."""
+    from datagen import load_module
+    from lightgbm_tpu.telemetry import trace
+    held = SpanTracer()
+    for path, secs in (("import", 3.0), ("dataset", 10.0),
+                       ("dataset/upload", 1.0), ("dataset/bin_device", 2.0),
+                       ("dataset/download", 0.5), ("booster_init", 4.0)):
+        held.add(path, secs)
+    for hit in (True, False):
+        with held.span("fused_scan_3it:compile") as span:
+            span.tag(cache_hit=hit)
+    monkeypatch.setattr(trace, "PROCESS_TRACER", held)
+    monkeypatch.setattr(scopereduce, "TRACE_ROOT", str(tmp_path))
+    write_trace(tmp_path, DEVICE_MD)
+    scopereduce.table.cache_clear()
+    ctx = {"trace": {"busy_s": 1.0}, "block_iterations": 2}
+    read = load_module("metrics", name).read
+    want = {"import_s": 3.0, "dataset_host_s": 6.5, "booster_init_s": 4.0,
+            "fused_compile_s": held.snapshot()["fused_scan_3it:compile"],
+            "fused_cache_hit": 0.5, "guard_ms_per_iter": 0.015}[name]
+    assert read(ctx) == pytest.approx(want)
+    ctx["trace"] = None
+    assert read(ctx) is None
+    scopereduce.table.cache_clear()
 
 
 def test_ledger_reads_a_cache_hit_as_a_load():
