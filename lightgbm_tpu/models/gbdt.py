@@ -1090,9 +1090,11 @@ class GBDT:
         loop, in the per-iteration path). And what the partitioned
         builder's kernels compile to, beside `tree_build_dispatches`:
         `partition_engine` (ops/partition.py: `pallas` on a TPU, `xla`
-        off it), and the histogram kernel's one-hot operand
+        off it), and the histogram kernel's streamed operand
         (ops/ordered_hist.py onehot_extent): `seg_hist_onehot_rows` a
-        feature and `seg_hist_features_per_dot`; the grid's feature axis
+        feature and `seg_hist_features_per_dot`, and `seg_hist_low_bins`
+        (low_bins: L of the split-bin form, 0 where the one-hot form
+        runs); the grid's feature axis
         (feature_blocks): `seg_hist_feature_blocks` a call (1: no such
         axis) and `seg_hist_block_features`; the histogram's ladder
         (min_rows, hist_rungs): `seg_hist_min_rows`, the rows of its
@@ -1110,7 +1112,7 @@ class GBDT:
             if fused or self.num_class == 1 else "loop")
         if getattr(self.tree_learner, "_use_partitioned", False):
             from ..ops.ordered_hist import (feature_blocks, hist_rungs,
-                                            onehot_extent)
+                                            low_bins, onehot_extent)
             from ..ops.pallas_hist import HIST_CHUNK
             from ..ops.partition import (chunk_lanes, packed_word_rows,
                                          partition_engine)
@@ -1121,6 +1123,8 @@ class GBDT:
             rows, features = onehot_extent(self.tree_learner.max_bin)
             self.metrics.set("seg_hist_onehot_rows", rows)
             self.metrics.set("seg_hist_features_per_dot", features)
+            self.metrics.set("seg_hist_low_bins",
+                             low_bins(self.tree_learner.max_bin))
             words = int(self.tree_learner._bins.shape[0])
             blocks, block_features = feature_blocks(
                 4 * words, self.tree_learner.max_bin)

@@ -36,12 +36,15 @@ byte f%4 of word f//4): one permutation gather moves 4 features at
 once, and the kernel unpacks with a shift+mask (2 VPU ops per feature
 per chunk, far below the B x C one-hot compares).
 
-The kernel's time is its one-hot elements (rows x features x padded
-bins) through the MXU, so the padded bin extent follows the static
-`num_bins_total` (`onehot_extent`): 64 rows a feature up to 64 bins,
-multiples of 128 above. At 64 rows the four byte lanes of a packed word
-row are stacked into one 256-row one-hot and take one contraction, the
-operand shape a 255-bin feature has.
+The kernel's time is the operand rows it streams through the MXU (rows
+x features x rows a feature), so the form follows the static
+`num_bins_total` (`onehot_extent`). Up to 64 bins a feature's one-hot is
+64 rows, and the four byte lanes of a packed word row are stacked into
+one 256-row one-hot and take one contraction. Above, a bin is split
+into high and low bits (`low_bins`, the split-bin form): a feature
+streams its nine statistic terms masked by each of L = 4 low values, 40
+rows with a zero tenth slot, against a one-hot of its b_pad / 4 high
+values, where the one-hot form streamed b_pad (docs/Histogram-Engine.md).
 """
 
 import functools
@@ -53,8 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..telemetry.trace import scope
-from .pallas_hist import (HIST_CHUNK, STAT_TERMS, fold_stats, onehot_dot,
-                          split_stats)
+from .pallas_hist import HIST_CHUNK, STAT_TERMS, fold_stats, split_stats
 
 
 def pack_feature_words(bins_u8):
@@ -147,15 +149,53 @@ def window_start(c_first, bk, n_chunks):
 ROLL_FEATURES = 64
 
 
-def onehot_extent(num_bins_total):
-    """(rows a feature's one-hot spans, features a contraction takes)
-    for a static histogram width: 64 rows and the four features of a
-    packed word row up to 64 bins, else whole 128-row tiles and one
-    feature. 64 is a whole number of bfloat16 (16) and float32 (8)
-    sublane tiles, so the stacked lanes need no relayout."""
+def bin_extent(num_bins_total):
+    """Padded bins a feature's histogram spans in the kernel (`b_pad`):
+    64 up to 64 bins, whole 128s above. 64 is a whole number of
+    bfloat16 (16) and float32 (8) sublane tiles, so a word row's four
+    stacked one-hots need no relayout."""
     if num_bins_total <= 64:
-        return 64, 4
-    return -(-num_bins_total // 128) * 128, 1
+        return 64
+    return -(-num_bins_total // 128) * 128
+
+
+def low_bins(num_bins_total):
+    """L, the low values a bin is split into (b = hi * L + lo), from the
+    padded width alone; 0 where the one-hot form runs. Above 64 bins a
+    feature streams 10 L masked statistic rows (`split_extent`) where
+    the one-hot form streamed b_pad (128 or 256); up to 64 bins the
+    one-hot's 64 rows are already as few. L = 4: the root call at
+    11,534,336 x 28 x 255 took 18.6 ms on a TPU v5e, where L = 8 (72
+    rows a feature, at the MXU's pace) took 30.8, L = 2 (a 128-row high
+    one-hot a feature, at the VPU's) 27.4 and the one-hot form 108.3; at
+    100 bins L = 4 and L = 2 took 17.4 and 17.3 ms, L = 8 30.8, the
+    one-hot form 54.9 (docs/Histogram-Engine.md)."""
+    return 0 if bin_extent(num_bins_total) <= 64 else 4
+
+
+def split_extent(b_pad, low):
+    """(high values H, features a contraction takes, term slots) of the
+    split-bin form: as many features as fit their H-row high one-hots
+    into one 128-column weight tile, at most a word row's four; and the
+    statistic terms' slots a feature streams L rows each, a float32
+    (8, C) sublane tile holding 8 / L of them (at L = 4 the nine terms
+    take five tiles, the tenth slot zero)."""
+    high = b_pad // low
+    per_tile = 8 // low
+    return high, min(4, 128 // high), -(-STAT_TERMS // per_tile) * per_tile
+
+
+def onehot_extent(num_bins_total):
+    """(rows a feature streams through the MXU, features a contraction
+    takes) for a static histogram width: a 64-row one-hot and the four
+    features of a packed word row up to 64 bins; above, the masked
+    statistic rows of `split_extent`'s term slots (40 at L = 4) and the
+    features whose high one-hots share a weight tile."""
+    low = low_bins(num_bins_total)
+    if not low:
+        return bin_extent(num_bins_total), 4
+    _, group, slots = split_extent(bin_extent(num_bins_total), low)
+    return slots * low, group
 
 
 # 136 columns at 63 bins (34 word rows of 256: 4.5 MB, written back
@@ -167,14 +207,17 @@ ACC_BLOCK_ROWS = 32 * 256
 
 def feature_blocks(f, num_bins_total):
     """(feature blocks a call, features a block) of the kernel's grid.
-    The accumulator's last dimension, nine statistics, pads to 128 lanes
-    in VMEM, so what a block may hold beside its double-buffered words
-    and statistics is counted in accumulator rows of 128 lanes: up to
+    The one-hot form's accumulator has nine statistics on its last
+    dimension, padded to 128 lanes in VMEM, so what a block may hold
+    beside its double-buffered words and statistics is counted in
+    accumulator rows of 128 lanes, `b_pad` a feature: up to
     ONE_BLOCK_ROWS of them the whole accumulator is one block and the
     grid has no feature axis; above, a block holds ACC_BLOCK_ROWS (32
     word rows at 63 bins, 32 features at 255: whole (8, 128) tiles of
-    words either way)."""
-    b_pad, _ = onehot_extent(num_bins_total)
+    words either way). The split-bin form keeps these blocks; its
+    accumulator is smaller (160 x 128 a word row, where the one-hot
+    form's was 1,024 x 128 at 255 bins)."""
+    b_pad = bin_extent(num_bins_total)
     if f * b_pad <= ONE_BLOCK_ROWS:
         return 1, f
     fb = ACC_BLOCK_ROWS // b_pad
@@ -197,8 +240,8 @@ def min_rows(f, num_bins_total):
     """Rows of the lowest rung of the histogram's ladder (`r`): the
     fewest, a power of two from one 128-lane tile to HIST_CHUNK, whose
     one-hot elements reach RUNG_ELEMENTS at the `f x b_pad` elements one
-    data row costs (`onehot_extent`)."""
-    b_pad, _ = onehot_extent(num_bins_total)
+    data row costs (`bin_extent`)."""
+    b_pad = bin_extent(num_bins_total)
     r = HIST_CHUNK
     while r > 128 and (r // 2) * f * b_pad >= RUNG_ELEMENTS:
         r //= 2
@@ -214,23 +257,12 @@ def hist_rungs(n_chunks, r):
             + [b * per_chunk for b in bucket_sizes(n_chunks)])
 
 
-def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad,
-                     lanes, f_total=None):
-    """One grid step = one row block of the sliced segment (HIST_CHUNK
-    rows, or the whole of a rung under a chunk: `words_ref.shape[1]`; of
-    one block of `f` features where the grid has a feature axis: then
-    `f_total` is the call's feature count, the feature block is the
-    outer grid axis and the row block the inner one, so an accumulator
-    block is zeroed at its first row step and written back once).
-    `out_ref` is (f, b_pad, 9) at `lanes` = 1 and (ceil(f / 4),
-    4 * b_pad, 9) at `lanes` = 4: rows [b_pad * k, b_pad * (k + 1)) of
+def _onehot_word_row(lohi_ref, words_ref, ghc_ref, out_ref, step, b_pad):
+    """The one-hot form's body for one packed word row (up to 64 bins):
+    the four byte lanes' 64-row one-hots stacked into one 256-row
+    operand against the (C, 9) statistic terms; `out_ref` is
+    (ceil(f / 4), 4 * b_pad, 9), rows [b_pad * k, b_pad * (k + 1)) of
     word row w are feature 4 w + k."""
-    step = pl.program_id(0 if f_total is None else 1)
-
-    @pl.when(step == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
     c = words_ref.shape[1]
     # 2-D iota, kept 2-D: a bare 1-D iota fails TPU pallas lowering
     # (pallas_guide.md "TPU requires at least 2D iota"), and staying
@@ -239,28 +271,107 @@ def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad,
     mask = (pos >= lohi_ref[0]) & (pos < lohi_ref[1])             # (C, 1)
     ghc_m = jnp.where(mask, ghc_ref[...], 0)                      # (C, 9)
     b_iota = jax.lax.broadcasted_iota(jnp.int32, (b_pad, c), 0)
-    if lanes == 1 and f < ROLL_FEATURES:
-        for i in range(f):
-            word = words_ref[i >> 2, :]
-            bins_f = (word >> ((i & 3) * 8)) & 0xFF
-            out_ref[i, :, :] += onehot_dot(bins_f[None, :], b_iota, ghc_m)
-        return
 
     def word_row(wi, byte_lanes):
         # a partly filled last word keeps its unused lanes out: the
         # packed padding bytes are 0 and would count in bin 0
         word = words_ref[pl.ds(wi, 1), :]                         # (1, C)
-        if lanes == 1:
-            for b in range(byte_lanes):  # the four byte lanes stay static
-                out_ref[wi * 4 + b] += onehot_dot((word >> (b * 8)) & 0xFF,
-                                                  b_iota, ghc_m)
-            return
         onehot = jnp.concatenate(
             [(((word >> (b * 8)) & 0xFF) == b_iota).astype(jnp.bfloat16)
              for b in range(byte_lanes)], axis=0)
         out_ref[wi, :byte_lanes * b_pad, :] += jax.lax.dot_general(
             onehot, ghc_m, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+    return word_row
+
+
+def _split_word_row(lohi_ref, words_ref, stats_ref, out_ref, step, b_pad,
+                    low):
+    """The split-bin form's body for one packed word row (above 64 bins;
+    docs/Histogram-Engine.md). With b = hi * L + lo,
+
+        hist[f, b, s] = sum_c [hi_f(c) == hi] * ([lo_f(c) == lo] * stat_s(c))
+
+    is one bfloat16 contraction over the row block's lanes a group of
+    `g` features (`split_extent`): the streamed operand is the masked
+    statistic rows (f, s, l), `where(lo_f == l, stat_s, 0)`, float32
+    (8, C) tiles of 8 / L terms each (a feature's T tiles are one select
+    against one compare of its low bits), packed to bfloat16, against
+    the features' stacked (H, C) high one-hots, contracted lane with
+    lane. The products are a term or 0, the sums float32, as in the
+    one-hot form. `out_ref` is (ceil(f / 4), 4 S L, g H) for S term
+    slots: group j of word row w holds rows [g S L j, g S L (j + 1)), of
+    which feature 4 w + g j + k's own are the diagonal block (rows
+    S L k.., columns H k..) `_split_fold` keeps. `stats_ref` is the
+    (9, C) terms, lane-major."""
+    c = words_ref.shape[1]
+    high, group, slots = split_extent(b_pad, low)
+    per_tile = 8 // low
+    rows = group * slots * low
+    pos = step * c + jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
+    mask = (pos >= lohi_ref[0]) & (pos < lohi_ref[1])             # (1, C)
+    stats = jnp.where(mask, stats_ref[...].astype(jnp.float32), 0.0)
+    # the terms' tiles, once a row block: sublane r of tile t holds term
+    # t * 8 / L + r // L (at L = 4 the tenth slot is zero)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, c), 0)
+    tiles = []
+    for t in range(slots // per_tile):
+        tile = jnp.zeros((8, c), jnp.float32)
+        for q in range(per_tile):
+            if t * per_tile + q < STAT_TERMS:
+                tile = jnp.where(sub // low == q,
+                                 stats[t * per_tile + q:t * per_tile + q + 1],
+                                 tile)
+        tiles.append(tile)
+    tiles = jnp.stack(tiles)                                      # (T, 8, C)
+    lo_iota = sub % low
+    hi_iota = jax.lax.broadcasted_iota(jnp.int32, (high, c), 0)
+    shift = low.bit_length() - 1
+
+    def word_row(wi, byte_lanes):
+        # all four byte lanes, whatever `byte_lanes` says: the padding
+        # bytes of a partly filled last word are features past `f`,
+        # whose diagonal blocks the fold cuts off
+        del byte_lanes
+        word = words_ref[pl.ds(wi, 1), :]                         # (1, C)
+        for j in range(4 // group):
+            masked, onehot = [], []
+            for k in range(j * group, (j + 1) * group):
+                bins = (word >> (k * 8)) & 0xFF
+                at_low = (bins & (low - 1)) == lo_iota            # (8, C)
+                masked.append(jnp.where(at_low, tiles, 0.0))      # (T, 8, C)
+                onehot.append(((bins >> shift) == hi_iota)
+                              .astype(jnp.bfloat16))              # (H, C)
+            out_ref[wi, j * rows:(j + 1) * rows, :] += jax.lax.dot_general(
+                jnp.concatenate(masked, axis=0).reshape(rows, c)
+                .astype(jnp.bfloat16),
+                jnp.concatenate(onehot, axis=0), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+    return word_row
+
+
+def _seg_hist_kernel(lohi_ref, words_ref, stats_ref, out_ref, *, f, b_pad,
+                     low, f_total=None):
+    """One grid step = one row block of the sliced segment (HIST_CHUNK
+    rows, or the whole of a rung under a chunk: `words_ref.shape[1]`; of
+    one block of `f` features where the grid has a feature axis: then
+    `f_total` is the call's feature count, the feature block is the
+    outer grid axis and the row block the inner one, so an accumulator
+    block is zeroed at its first row step and written back once). The
+    packed word row is the body's one unit: `_onehot_word_row` (`low`
+    = 0) or `_split_word_row` (`low` = L)."""
+    step = pl.program_id(0 if f_total is None else 1)
+
+    @pl.when(step == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    if low:
+        word_row = _split_word_row(lohi_ref, words_ref, stats_ref, out_ref,
+                                   step, b_pad, low)
+    else:
+        word_row = _onehot_word_row(lohi_ref, words_ref, stats_ref, out_ref,
+                                    step, b_pad)
 
     if f_total is not None:
         # the last block's word rows end with the call's; the unused
@@ -275,6 +386,18 @@ def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad,
 
         jax.lax.fori_loop(0, rows_here, block_body, 0)
         return
+    if low:
+        # the word row is traced once and lowered a copy a row (rolled
+        # from ROLL_FEATURES on): a program traces and lowers this body
+        # at every rung and call site (26 at 2,816 chunks), and 28
+        # columns traced row by row doubled what those cost
+        def split_body(wi, _):
+            word_row(wi, 4)
+            return 0
+
+        jax.lax.fori_loop(0, -(-f // 4), split_body, 0,
+                          unroll=f < ROLL_FEATURES)
+        return
     if f < ROLL_FEATURES:
         for wi in range(f // 4):
             word_row(wi, 4)
@@ -288,6 +411,20 @@ def _seg_hist_kernel(lohi_ref, words_ref, ghc_ref, out_ref, *, f, b_pad,
         word_row(f // 4, f % 4)
 
 
+def _split_fold(acc, b_pad, low):
+    """The split-bin accumulator (W, 4 S L, g H) -> (4 W, b_pad, 9): each
+    feature's diagonal block (its own masked rows against its own high
+    columns), its nine terms of S slots, (term, lo) x hi reordered to
+    bin = hi * L + lo."""
+    high, group, slots = split_extent(b_pad, low)
+    w = acc.shape[0]
+    acc = acc.reshape(w, 4 // group, group, slots, low, group, high)
+    own = jnp.stack([acc[:, :, k, :STAT_TERMS, :, k, :]
+                     for k in range(group)], axis=2)
+    return (own.reshape(4 * w, STAT_TERMS, low, high)
+            .transpose(0, 3, 2, 1).reshape(4 * w, b_pad, STAT_TERMS))
+
+
 def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
                   interpret=False):
     """Pallas segment histogram over a window of `n_blocks` equal row
@@ -297,32 +434,42 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
     semantics without TPU hardware."""
     w = words_sl.shape[0]
     block = words_sl.shape[1] // n_blocks
-    b_pad, lanes = onehot_extent(num_bins_total)
+    b_pad = bin_extent(num_bins_total)
+    low = low_bins(num_bins_total)
     n_fb, fb = feature_blocks(f, num_bins_total)
-    acc_shape = ((-(-f // 4), 4 * b_pad, STAT_TERMS) if lanes == 4
-                 else (f, b_pad, STAT_TERMS))
+    if low:
+        high, group, slots = split_extent(b_pad, low)
+        acc_shape = (-(-f // 4), 4 * slots * low, group * high)
+    else:
+        acc_shape = (-(-f // 4), 4 * b_pad, STAT_TERMS)
     with scope("window"):
         lohi = jnp.stack([lo, hi]).astype(jnp.int32)
-        stats = split_stats(ghc_sl)
+        # the split-bin form's selects take each term as a lane row
+        stats = split_stats(ghc_sl.T, axis=0) if low else split_stats(ghc_sl)
+    if low:
+        stats_block, stats_index = (STAT_TERMS, block), lambda i: (0, i)
+    else:
+        stats_block, stats_index = (block, STAT_TERMS), lambda i: (i, 0)
     if n_fb == 1:
         kernel = functools.partial(_seg_hist_kernel, f=f, b_pad=b_pad,
-                                   lanes=lanes)
+                                   low=low)
         grid = (n_blocks,)
         words_spec = pl.BlockSpec((w, block), lambda i: (0, i),
                                   memory_space=pltpu.VMEM)
-        stats_spec = pl.BlockSpec((block, STAT_TERMS), lambda i: (i, 0),
+        stats_spec = pl.BlockSpec(stats_block, stats_index,
                                   memory_space=pltpu.VMEM)
         out_spec = pl.BlockSpec(acc_shape, lambda i: (0, 0, 0),
                                 memory_space=pltpu.VMEM)
     else:
         kernel = functools.partial(_seg_hist_kernel, f=fb, b_pad=b_pad,
-                                   lanes=lanes, f_total=f)
+                                   low=low, f_total=f)
         grid = (n_fb, n_blocks)
         words_spec = pl.BlockSpec((fb // 4, block), lambda j, i: (j, i),
                                   memory_space=pltpu.VMEM)
-        stats_spec = pl.BlockSpec((block, STAT_TERMS), lambda j, i: (i, 0),
+        stats_spec = pl.BlockSpec(stats_block,
+                                  lambda j, i: stats_index(i),
                                   memory_space=pltpu.VMEM)
-        out_spec = pl.BlockSpec((fb // lanes,) + acc_shape[1:],
+        out_spec = pl.BlockSpec((fb // 4,) + acc_shape[1:],
                                 lambda j, i: (j, 0, 0),
                                 memory_space=pltpu.VMEM)
     out = pl.pallas_call(
@@ -339,6 +486,8 @@ def _seg_hist_tpu(words_sl, ghc_sl, lo, hi, f, num_bins_total, n_blocks,
         out_shape=jax.ShapeDtypeStruct(acc_shape, jnp.float32),
     )(lohi, words_sl, stats)
     with scope("fold"):
+        if low:
+            out = _split_fold(out, b_pad, low)
         out = out.reshape(-1, b_pad, STAT_TERMS)[:f, :num_bins_total, :]
         return fold_stats(out)
 

@@ -106,16 +106,17 @@ def _bf16_floor(x):
                                         jnp.float32)
 
 
-def split_stats(ghc):
+def split_stats(ghc, axis=1):
     """(N, 3) f32 stats -> (N, 9) bfloat16 [hi | mid | lo] with
     hi + mid + lo == ghc exactly (finite inputs): each term takes the
     next 8 significand bits, every subtraction is exact in f32, and
-    every term is exactly representable in bfloat16."""
+    every term is exactly representable in bfloat16. `axis` is the
+    stats' axis: 0 takes (3, N) to the lane-major (9, N)."""
     hi = _bf16_floor(ghc)
     rest = ghc - hi
     mid = _bf16_floor(rest)
     lo = rest - mid
-    return jnp.concatenate([hi, mid, lo], axis=1).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, mid, lo], axis=axis).astype(jnp.bfloat16)
 
 
 def fold_stats(out):

@@ -335,18 +335,22 @@ def _kernel_out_shape(args):
 
 
 @pytest.mark.parametrize("b,rows_a_feature,features_a_dot", [
-    (63, 64, 4), (64, 64, 4), (65, 128, 1), (255, 256, 1)])
+    (63, 64, 4), (64, 64, 4), (65, 40, 4), (100, 40, 4), (255, 40, 2)])
 def test_segment_kernel_onehot_extent(b, rows_a_feature, features_a_dot):
     """Up to 64 bins a feature's one-hot is 64 rows and a packed word
-    row's four features share one 256-row contraction; above, whole
-    128-row tiles a feature, as before. Read off the kernel call's
-    output, which is the accumulator the one-hot's rows land in."""
-    from lightgbm_tpu.ops.ordered_hist import (_seg_hist_tpu,
-                                               _seg_hist_xla, onehot_extent)
+    row's four features share one 256-row contraction. Above, the
+    split-bin form (L = 4): a feature streams its nine terms' masked
+    rows in five tiles of 8 (40 rows) against its 32-row (b_pad 128:
+    four features a contraction) or 64-row (b_pad 256: two) high
+    one-hot. Read off the kernel call's output, the accumulator the
+    streamed rows land in; the split-bin sums are the one-hot body's
+    bit for bit."""
+    from lightgbm_tpu.ops.ordered_hist import (_seg_hist_tpu, _seg_hist_xla,
+                                               low_bins, onehot_extent)
     args, bins = _seg_hist_case(8, 8, b, seed=b)
     assert onehot_extent(b) == (rows_a_feature, features_a_dot)
-    want_shape = ((2, 256, 9) if features_a_dot == 4
-                  else (8, rows_a_feature, 9))
+    assert low_bins(b) == (0 if b <= 64 else 4)
+    want_shape = (2, 256, 9) if b <= 64 else (2, 160, 128)
     assert _kernel_out_shape(args) == want_shape
     got = np.asarray(_seg_hist_tpu(*args, interpret=True))
     assert got.shape == (8, b, 3)
@@ -400,7 +404,8 @@ def test_feature_blocks_follow_the_accumulator(f, b, blocks, block_features):
 
 @pytest.mark.parametrize("f,b,blocks,block_features", [
     (130, 63, 1, 130), (150, 63, 2, 128), (515, 63, 5, 128),
-    (130, 255, 5, 32), (515, 255, 17, 32), (69, 100, 2, 64)])
+    (130, 255, 5, 32), (515, 255, 17, 32), (69, 100, 2, 64),
+    (28, 255, 1, 28), (30, 255, 1, 30), (70, 255, 3, 32), (66, 100, 1, 66)])
 def test_segment_kernel_feature_blocks(f, b, blocks, block_features):
     """Past 136 columns at 63 bins (34 at 255) the accumulator no longer
     fits VMEM whole, and the grid gets a feature axis: blocks of 32 word
@@ -408,12 +413,16 @@ def test_segment_kernel_feature_blocks(f, b, blocks, block_features):
     block, a partly filled last word row (130, 150, 515 columns) and a
     partly filled last block: counts equal to the XLA formulation's to
     the bit, sums to float32 rounding; the accumulator keeps its shape
-    and a block's shape is what `feature_blocks` says."""
+    and a block's shape is what `feature_blocks` says. Above 64 bins the
+    split-bin form's word rows, (40 x 4, 128) each, at the control
+    cell's 28 columns, 30 (a partly filled last word, whose padding
+    bytes count in no bin of the result), 70 (the feature axis) and 66
+    at 100 bins (the rolled body, one block), are the one-hot body's
+    sums bit for bit."""
     from lightgbm_tpu.ops.ordered_hist import (_seg_hist_tpu, _seg_hist_xla,
-                                               feature_blocks, onehot_extent)
+                                               feature_blocks)
     assert feature_blocks(f, b) == (blocks, block_features)
     args, _ = _seg_hist_case(f, f, b, seed=f + b)
-    rows, lanes = onehot_extent(b)
     jaxpr = jax.make_jaxpr(
         lambda w, g, lo, hi: _seg_hist_tpu(w, g, lo, hi, *args[4:],
                                            interpret=True))(*args[:4])
@@ -422,10 +431,13 @@ def test_segment_kernel_feature_blocks(f, b, blocks, block_features):
     grid = call.params["grid_mapping"].grid
     assert grid == ((blocks, 2) if blocks > 1 else (2,))
     assert call.outvars[0].aval.shape == (
-        (-(-f // 4), 256, 9) if lanes == 4 else (f, rows, 9))
+        (-(-f // 4), 256, 9) if b <= 64 else (-(-f // 4), 160, 128))
     got = np.asarray(_seg_hist_tpu(*args, interpret=True))
     want = np.asarray(_seg_hist_xla(*args[:6]))
     assert got.shape == (f, b, 3)
+    if b > 64 and f <= 70:
+        np.testing.assert_array_equal(
+            got, np.asarray(_seg_hist_128_rows(*args)))
     np.testing.assert_array_equal(got[..., 2], want[..., 2])
     np.testing.assert_array_equal(got[..., 2].sum(axis=1), np.full(f, 2048))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
@@ -460,7 +472,7 @@ def test_segment_kernel_block_under_a_chunk(rows, f, b):
     shapes = [tuple(getattr(d, "block_size", d) for d in bm.block_shape)
               for bm in mapping.block_mappings]
     assert shapes[1:3] == [(fb // 4 if blocks > 1 else -(-f // 4), rows),
-                           (rows, 9)]
+                           (9, rows) if b > 64 else (rows, 9)]
     got = np.asarray(_seg_hist_tpu(*args, interpret=True))
     assert got.shape == (f, b, 3)
     np.testing.assert_array_equal(got, np.asarray(_seg_hist_128_rows(*args)))
@@ -501,9 +513,10 @@ def test_segment_kernel_interpret_over_small_rungs(monkeypatch, begin, cnt):
 def test_segment_kernel_one_block_has_no_feature_axis(f, w, b):
     """At the columns of the cells the benchmark had before PR 33 the
     whole accumulator is one block: the kernel call has the one grid
-    axis (row blocks) and the block shapes it always had, and its body
+    axis (row blocks) and the block shapes of its form (the split-bin
+    form's lane-major terms and accumulator at 255 bins), and its body
     reads `program_id(0)` alone."""
-    from lightgbm_tpu.ops.ordered_hist import _seg_hist_tpu, onehot_extent
+    from lightgbm_tpu.ops.ordered_hist import _seg_hist_tpu
     n_blocks = 4
     n = n_blocks * HIST_CHUNK
     jaxpr = jax.make_jaxpr(
@@ -517,11 +530,11 @@ def test_segment_kernel_one_block_has_no_feature_axis(f, w, b):
                if e.primitive.name == "pallas_call"]
     mapping = call.params["grid_mapping"]
     assert mapping.grid == (n_blocks,)
-    rows, lanes = onehot_extent(b)
-    acc = (-(-f // 4), 256, 9) if lanes == 4 else (f, rows, 9)
+    acc = (-(-f // 4), 256, 9) if b <= 64 else (-(-f // 4), 160, 128)
+    terms = (HIST_CHUNK, 9) if b <= 64 else (9, HIST_CHUNK)
     shapes = [tuple(getattr(d, "block_size", d) for d in bm.block_shape)
               for bm in mapping.block_mappings]
-    assert shapes == [(2,), (w, HIST_CHUNK), (HIST_CHUNK, 9), acc]
+    assert shapes == [(2,), (w, HIST_CHUNK), terms, acc]
     body = str(call.params["jaxpr"])
     assert "program_id[axis=0]" in body and "program_id[axis=1]" not in body
 

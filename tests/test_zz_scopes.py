@@ -583,11 +583,14 @@ def test_fused_block_child_spans(trained):
 
 
 @pytest.mark.parametrize("max_bin,rows,features", [(63, 64, 4),
-                                                   (255, 256, 1)])
+                                                   (255, 40, 2)])
 def test_seg_hist_onehot_gauges(max_bin, rows, features):
-    """The histogram kernel's one-hot operand, in /trainz: rows a
+    """The histogram kernel's streamed operand, in /trainz: rows a
     feature and features a contraction, from the bins the booster's
-    train set has (64 / 4 up to 64 bins, whole 128-row tiles / 1 above)."""
+    train set has (a 64-row one-hot / 4 up to 64 bins; above, the
+    split-bin form's 40 masked statistic rows / 2 at 255 bins), and L,
+    the low values of a bin the split-bin form takes (0: the one-hot
+    form)."""
     rng = np.random.RandomState(11)
     x = rng.randn(2000, 4).astype(np.float32)
     y = (x[:, 0] > 0).astype(np.float32)
@@ -600,6 +603,7 @@ def test_seg_hist_onehot_gauges(max_bin, rows, features):
     gauges = booster.gbdt.metrics.snapshot()["gauges"]
     assert gauges["seg_hist_onehot_rows"] == rows
     assert gauges["seg_hist_features_per_dot"] == features
+    assert gauges["seg_hist_low_bins"] == (0 if max_bin <= 64 else 4)
     # four columns: one word row, the whole accumulator one block, the
     # partition kernel's row one tile of words and a full chunk
     assert gauges["seg_hist_feature_blocks"] == 1
@@ -905,14 +909,16 @@ def test_leaf_lookup_compiles_to_selects(one_chip, leaves, classes):
 
 @pytest.mark.parametrize("f,w,b,result", [
     (28, 8, 63, "f32[7,256,9]"), (136, 40, 63, "f32[34,256,9]"),
-    (135, 40, 63, "f32[34,256,9]"), (28, 8, 255, "f32[28,256,9]")])
+    (135, 40, 63, "f32[34,256,9]"), (28, 8, 255, "f32[7,160,128]")])
 def test_seg_hist_compiles_at_the_cells_widths(one_chip, f, w, b, result):
     """The chip's compiler takes the histogram kernel at the columns,
     word rows and bins of the benchmark's cells (interpret mode does not
     see tiling or VMEM): one custom call named `seg_hist` whose result is
     the accumulator, a packed word row's four 64-row one-hots stacked at
     63 bins (the unrolled body at 28 columns, the rolled one at 136, a
-    partly filled last word row at 135), a feature's 256 rows at 255."""
+    partly filled last word row at 135); at 255 the split-bin form's
+    word row, four features' 40 masked statistic rows against two
+    features' 64-row high one-hots a contraction."""
     from lightgbm_tpu.ops.ordered_hist import _seg_hist_tpu
     from lightgbm_tpu.ops.pallas_hist import HIST_CHUNK
     n_blocks = 8
@@ -935,8 +941,9 @@ def test_builder_compiles_at_2000_columns(one_chip, b, blocks):
     """The chip's compiler takes the tree builder at Epsilon's 2,000
     columns (500 words a row, 504 in the partition kernel's array): the
     histogram kernel with its feature axis (16 blocks of 128 features at
-    63 bins, 63 of 32 at 255) and the partition kernel at 512-lane
-    chunks, each under its own name and scope."""
+    63 bins, 63 of 32 at 255, the split-bin accumulator there) and the
+    partition kernel at 512-lane chunks, each under its own name and
+    scope."""
     from lightgbm_tpu.ops.ordered_hist import feature_blocks
     from lightgbm_tpu.ops.partition import chunk_lanes, packed_word_rows
     assert feature_blocks(2000, b)[0] == blocks
@@ -957,7 +964,7 @@ def test_builder_compiles_at_2000_columns(one_chip, b, blocks):
             assert "s32[504,8192]" in ln, ln[:200]
             assert path.index("partition") < path.index("move"), ln[:200]
         else:
-            acc = "f32[500,256,9]" if b == 63 else "f32[2000,256,9]"
+            acc = "f32[500,256,9]" if b == 63 else "f32[500,160,128]"
             assert acc in ln, ln[:200]
             assert path.index("hist") < path.index("seg_hist"), ln[:200]
     assert names == {"seg_hist", "partition_rows"}
@@ -975,7 +982,8 @@ def test_seg_hist_windows_follow_the_row(one_chip, columns, w, b,
     chunks 1, 2, 4 the parent compiled, at both call sites; at 2,000 x
     63 three rungs under a chunk before them. The kernel's second
     operand is the window of packed words, the third the statistics'
-    nine terms over the same rows; every call sits under `hist`."""
+    nine terms over the same rows (lane-major at 255 bins, the split-bin
+    form's); every call sits under `hist`."""
     from lightgbm_tpu.ops.ordered_hist import min_rows
     from lightgbm_tpu.ops.partition import packed_word_rows
     assert min_rows(4 * w, b) == window_rows[0]
@@ -999,7 +1007,8 @@ def test_seg_hist_windows_follow_the_row(one_chip, columns, w, b,
                               body[op.lstrip("%")]).group(1)
                      for op in (words, stats)]
             rows = int(re.match(r"s32\[(\d+),(\d+)\]", shape[0]).group(2))
-            assert shape == [f"s32[{wp},{rows}]", f"bf16[{rows},9]"], shape
+            terms = f"bf16[9,{rows}]" if b > 64 else f"bf16[{rows},9]"
+            assert shape == [f"s32[{wp},{rows}]", terms], shape
             seen.append(rows)
     # the root's call site and the loop's: every rung once at each
     assert sorted(seen) == sorted(2 * window_rows), seen
