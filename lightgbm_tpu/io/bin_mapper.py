@@ -103,13 +103,23 @@ class BinMapper:
             if np.isnan(v).any():
                 v = np.nan_to_num(v, nan=0.0)
             return np.searchsorted(self.bin_upper_bound, v, side="left").astype(np.int32)
+        # categorical (bin.h: static_cast<int> then the id's bin, 0 for an
+        # id not kept): truncated toward zero, found among the kept ids
+        # sorted; unseen ids, ids past the kept max_bin and NaN go to bin 0
         if self._cat_lookup is None:
-            self._cat_lookup = {int(c): i for i, c in enumerate(self.bin_2_categorical)}
-        look = self._cat_lookup
-        flat = values.reshape(-1)
-        out = np.fromiter((look.get(int(v), 0) for v in flat), dtype=np.int32,
-                          count=len(flat))
-        return out.reshape(values.shape)
+            order = np.argsort(self.bin_2_categorical, kind="stable")
+            self._cat_lookup = (self.bin_2_categorical[order],
+                                order.astype(np.int32))
+        ids, bin_of = self._cat_lookup
+        if len(ids) == 0:
+            return np.zeros(values.shape, np.int32)
+        if values.dtype.kind in "iub":
+            key = values.astype(np.int64)
+        else:
+            # ids are truncated float64 sample values: exact in float64
+            key, ids = np.trunc(values.astype(np.float64)), ids.astype(np.float64)
+        pos = np.minimum(np.searchsorted(ids, key), len(ids) - 1)
+        return np.where(ids[pos] == key, bin_of[pos], 0).astype(np.int32)
 
     def bin_to_value(self, bin_idx):
         """Representative real value of a bin, used as the tree's stored

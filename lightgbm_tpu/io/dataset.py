@@ -169,8 +169,14 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
     for every f32 input v (same boundary rule as the device-predict
     thresholds, models/gbdt.py _device_model).
 
-    Gated by LIGHTGBM_TPU_DEVICE_BIN (default auto = non-CPU backends,
-    numerical features only). Returns (F, N) bins, or None when the
+    Categorical columns bin by equality in a program of their own (the
+    matrix is uploaded once): trunc(v) against each column's kept ids,
+    BinMapper.value_to_bin's rule, under the span
+    `dataset/bin_categorical`. A matrix without them traces the
+    numerical program alone, with no column selection in it.
+
+    Gated by LIGHTGBM_TPU_DEVICE_BIN (default auto = non-CPU
+    backends). Returns (F, N) bins, or None when the
     inputs are ineligible or the gate is off (the caller then bins on
     the host). Those are decisions; a failure of the device pass itself
     propagates — a host result in its place would hide a broken
@@ -190,8 +196,6 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
     import jax.numpy as jnp
     if mode == "auto" and jax.default_backend() == "cpu":
         return None
-    if any(m.bin_type != NUMERICAL for m in mappers):
-        return None
     if mat.dtype != np.float32:
         # the -inf-rounded f32 bounds make the compare exact for
         # f32 INPUTS only; f64 matrices (text loads keep f64 so
@@ -200,12 +204,15 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
         return None
     n = mat.shape[0]
     f = len(real_idx)
+    cat = [u for u, m in enumerate(mappers) if m.bin_type == CATEGORICAL]
+    num = [u for u, m in enumerate(mappers) if m.bin_type != CATEGORICAL]
     span = functools.partial(PROCESS_TRACER.span, rows=n, features=f)
     with span("host_prep"):
-        b_max = max(len(m.bin_upper_bound) for m in mappers)
-        bounds = np.full((f, b_max), np.inf)
-        for u, m in enumerate(mappers):
-            bounds[u, :len(m.bin_upper_bound)] = m.bin_upper_bound
+        b_max = max((len(mappers[u].bin_upper_bound) for u in num), default=1)
+        bounds = np.full((len(num), b_max), np.inf)
+        for i, u in enumerate(num):
+            bounds[i, :len(mappers[u].bin_upper_bound)] = (
+                mappers[u].bin_upper_bound)
         b32 = bounds.astype(np.float32)
         lifted = b32.astype(np.float64) > bounds
         b32 = np.where(lifted,
@@ -227,30 +234,106 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
         # differs when a column has negative bounds
         if np.isnan(x_used).any():
             x_used = np.nan_to_num(x_used, nan=0.0)
+        ids = _categorical_ids(mappers, cat) if cat else None
     with span("upload", bytes=int(x_used.nbytes + b32.nbytes)):
         xdev = jnp.asarray(x_used).reshape(n_pad // chunk, chunk, f)
         bdev = jnp.asarray(b32)
         jax.block_until_ready((xdev, bdev))
     out_dt = jnp.dtype(dtype)
+    # the numerical columns of a matrix that has categorical ones too
+    num_cols = np.asarray(num) if cat else None
 
     @jax.jit
     def bin_all(xc):
         def one(xb):   # (chunk, F) -> (chunk, F) narrow ints
+            if num_cols is not None:
+                xb = xb[:, num_cols]
             return jnp.sum(xb[:, :, None] > bdev[None, :, :],
+                           axis=-1, dtype=jnp.int32).astype(out_dt)
+        return jax.lax.map(one, xc)
+
+    @jax.jit
+    def bin_categorical(xc, cols, idv):
+        # a value truncated toward zero is bin k where it equals kept id
+        # k, bin 0 where it equals none (unseen, past the kept max_bin,
+        # NaN): BinMapper.value_to_bin's rule; the columns and the ids
+        # are arguments, so the program depends on shapes alone
+        k = jnp.arange(idv.shape[1], dtype=jnp.int32)
+
+        def one(xb):   # (chunk, F) -> (chunk, categorical F) narrow ints
+            t = jnp.trunc(jnp.take(xb, cols, axis=1))
+            return jnp.sum(jnp.where(t[:, :, None] == idv[None], k, 0),
                            axis=-1, dtype=jnp.int32).astype(out_dt)
         return jax.lax.map(one, xc)
 
     # no learner has set the compile cache up yet: the ledger listens
     # from here, so that the label sees a hit
     LEDGER.install()
-    with span("bin_device"), LEDGER.label("dataset_bin"):
-        # first call: compile or load, then run
-        binned = jax.block_until_ready(bin_all(xdev))
-    with span("download", bytes=int(binned.nbytes)):
+    parts = []        # (the mappers' positions, their (C, chunk, k) bins)
+    if num:
+        with span("bin_device"), LEDGER.label("dataset_bin"):
+            # first call: compile or load, then run
+            parts.append((num, jax.block_until_ready(bin_all(xdev))))
+    if cat:
+        # a program of its own, so that its seconds stand apart
+        with _categorical_span(n, real_idx, mappers, cat), \
+                LEDGER.label("dataset_bin_categorical"):
+            parts.append((cat, jax.block_until_ready(bin_categorical(
+                xdev, jnp.asarray(cat, jnp.int32), jnp.asarray(ids)))))
+    with span("download", bytes=int(sum(p.nbytes for _, p in parts))):
         # narrow on device: the download is N x F bytes, not 4x that
-        out = np.asarray(binned).reshape(n_pad, f)[:n]
+        outs = [(cols, np.asarray(p).reshape(n_pad, -1)[:n])
+                for cols, p in parts]
     with span("pack"):
-        return np.ascontiguousarray(out.T).astype(dtype, copy=False)
+        if not cat:
+            return np.ascontiguousarray(outs[0][1].T).astype(dtype,
+                                                            copy=False)
+        bins = np.empty((f, n), dtype)
+        for cols, out in outs:
+            bins[cols] = out.T
+        return bins
+
+
+def _categorical_span(n, real_idx, mappers, cat):
+    """The process span `dataset/bin_categorical` around the binning of
+    the categorical columns (mappers' positions `cat`), on the device or
+    on the host: tagged with the `rows`, the input's `columns` (their
+    indices) and the kept ids of them all (`categories`)."""
+    return PROCESS_TRACER.span(
+        "bin_categorical", rows=n,
+        columns=[int(real_idx[u]) for u in cat],
+        categories=int(sum(mappers[u].num_bin for u in cat)))
+
+
+def _categorical_ids(mappers, cat):
+    """(len(cat), most kept) float32: row i holds the kept ids of
+    categorical mapper cat[i], id k that of bin k. NaN pads a row, and
+    stands in for an id no float32 value truncates to (past 2**24), so
+    that neither ever matches, as on the host."""
+    k_max = max(len(mappers[u].bin_2_categorical) for u in cat)
+    ids = np.full((len(cat), k_max), np.nan, np.float32)
+    for i, u in enumerate(cat):
+        kept = mappers[u].bin_2_categorical
+        exact = kept.astype(np.float32).astype(np.int64) == kept
+        ids[i, :len(kept)] = np.where(exact, kept.astype(np.float32), np.nan)
+    return ids
+
+
+def _bin_on_host(src, real_idx, mappers, dtype):
+    """(F, N) bins of a column source by the mappers' own value_to_bin,
+    the columns on threads; the categorical ones after the rest, under
+    the span `dataset/bin_categorical`."""
+    bins = np.empty((len(real_idx), src.n), dtype)
+    cat = [u for u, m in enumerate(mappers) if m.bin_type == CATEGORICAL]
+    num = [u for u, m in enumerate(mappers) if m.bin_type != CATEGORICAL]
+
+    def one(u):
+        bins[u] = mappers[u].value_to_bin(src.col(real_idx[u]))
+    _bin_columns_threaded(lambda i: one(num[i]), len(num))
+    if cat:
+        with _categorical_span(src.n, real_idx, mappers, cat):
+            _bin_columns_threaded(lambda i: one(cat[i]), len(cat))
+    return bins
 
 
 def _bin_columns_threaded(col_fn, count):
@@ -1386,11 +1469,8 @@ class DatasetLoader:
                                              mappers, dtype)
                         if isinstance(src, DenseColumns) else None)
             ds.binned_on_device = dev_bins is not None
-            ds.bins = dev_bins if dev_bins is not None else np.stack(
-                _bin_columns_threaded(
-                    lambda u: mappers[u].value_to_bin(
-                        src.col(real_idx[u])).astype(dtype),
-                    len(real_idx)), axis=0)
+            ds.bins = (dev_bins if dev_bins is not None
+                       else _bin_on_host(src, real_idx, mappers, dtype))
         else:
             dtype = bins_dtype(int(plan.slot_bins.max()))
             check_bins_budget(plan.num_slots, n, np.dtype(dtype).itemsize,

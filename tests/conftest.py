@@ -37,6 +37,16 @@ def pytest_configure(config):
                             "the tier-1 `-m 'not slow'` filter")
 
 
+@pytest.fixture(autouse=True)
+def _log_level():
+    # training with verbose=-1 mutes the process's Log: the next test in
+    # the same worker starts at the level this one found
+    from lightgbm_tpu.utils.log import Log
+    level = Log._level
+    yield
+    Log.reset_log_level(level)
+
+
 @pytest.fixture(scope="session")
 def rng():
     return np.random.RandomState(42)

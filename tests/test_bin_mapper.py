@@ -208,3 +208,101 @@ def test_find_bin_is_the_loop_it_replaced(kind):
             np.testing.assert_array_equal(m.bin_upper_bound, bounds)
             assert m.num_bin == len(bounds)
             assert m.sparse_rate == first / float(total)
+
+
+def _categorical_by_lookup(m, values):
+    """value_to_bin's categorical rule as it was first written: a
+    dict from the kept id to its bin, one `int(v)` a value. Kept here as
+    the oracle of the vectorised form (it raises on NaN)."""
+    look = {int(c): i for i, c in enumerate(m.bin_2_categorical)}
+    return np.asarray([look.get(int(v), 0) for v in np.ravel(values)],
+                      np.int32).reshape(np.shape(values))
+
+
+@pytest.mark.parametrize("max_bin", [4, 31, 255])
+def test_categorical_value_to_bin_is_the_lookup_it_replaced(max_bin):
+    """Sorted ids and a search give the dict's bins bit for bit, on
+    every value the dict took: fractional values truncated toward zero,
+    negative ids, ids never seen in the sample, ids past the kept
+    max_bin, large magnitudes; for float64, float32 and integer input."""
+    rng = np.random.RandomState(max_bin)
+    sample = np.concatenate([rng.zipf(1.4, 3000) % 400 - 20,
+                             rng.randint(-3, 3, 200) + 0.5]).astype(float)
+    m = BinMapper().find_bin(sample[np.abs(sample) > 1e-10], len(sample),
+                             max_bin, bin_type=CATEGORICAL)
+    assert m.num_bin == min(max_bin, len(np.unique(np.trunc(sample))))
+    probe = np.concatenate([
+        rng.uniform(-40, 500, 5000), np.arange(-30, 460, dtype=float),
+        -np.arange(30) - 0.999, np.arange(30) + 0.999, [-0.0, 0.0, 2.0**40,
+                                                        -2.0**52, 1e15]])
+    for values in (probe, probe.astype(np.float32)):
+        got = m.value_to_bin(values)
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(got, _categorical_by_lookup(m, values))
+    ints = np.arange(-30, 460)
+    np.testing.assert_array_equal(m.value_to_bin(ints),
+                                  _categorical_by_lookup(m, ints))
+    grid = probe[:600].reshape(20, 30)
+    np.testing.assert_array_equal(m.value_to_bin(grid),
+                                  _categorical_by_lookup(m, grid))
+
+
+def test_categorical_nan_bins_to_zero():
+    """A NaN in a categorical column goes to bin 0 with the unseen ids
+    (the dict lookup raised ValueError on it); infinities likewise."""
+    vals = np.array([5] * 10 + [2] * 7 + [9] * 3, dtype=np.float64)
+    m = BinMapper().find_bin(vals, total_sample_cnt=20, max_bin=255,
+                             bin_type=CATEGORICAL)
+    got = m.value_to_bin(np.array([np.nan, 2.0, np.inf, -np.inf, 9.5, np.nan]))
+    assert got.tolist() == [0, 1, 0, 0, 2, 0]
+    assert m.value_to_bin(np.array([np.nan], np.float32)).tolist() == [0]
+
+
+@pytest.mark.parametrize("max_bin", [63, 255])
+def test_device_binning_matches_host_with_categorical_columns(monkeypatch,
+                                                              max_bin):
+    """With categorical columns beside numerical ones the device pass
+    bins both (the categorical ones by equality against the kept ids, in
+    a program of their own under the span `dataset/bin_categorical`) and
+    is bit-equal to the host, which bins them by value_to_bin under the
+    same span: ids past max_bin, unseen ids, fractional and negative
+    ids, a NaN and ids past 2**24 that float32 cannot hold."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import DatasetLoader
+    from lightgbm_tpu.telemetry.trace import PROCESS_TRACER
+
+    rng = np.random.RandomState(7)
+    n = 9000
+    x = rng.randn(n, 6).astype(np.float32)
+    x[:, 1] = rng.zipf(1.3, n) % 400                   # past max_bin
+    x[:, 3] = rng.randint(-5, 40, n) + rng.rand(n).round(1)
+    x[:50, 3] = np.nan
+    x[:, 4] = 2.0**24 + 2 * rng.randint(0, 6, n)       # float32-exact, big
+    x[-7:, 4] = 123.0                                  # never in the sample
+    y = (x[:, 0] > 0).astype(np.float32)
+
+    def build():
+        cfg = Config.from_params({"objective": "binary", "verbose": -1,
+                                  "max_bin": max_bin,
+                                  "bin_construct_sample_cnt": 5000})
+        PROCESS_TRACER.reset()
+        ds = DatasetLoader(cfg).construct_from_matrix(
+            x, label=y, categorical_features=(1, 3, 4))
+        spans = [s for s in PROCESS_TRACER.recent(None)
+                 if s["path"] == "dataset/bin_categorical"]
+        assert len(spans) == 1
+        assert spans[0]["tags"]["columns"] == [1, 3, 4]
+        assert spans[0]["tags"]["categories"] == sum(
+            m.num_bin for m in ds.bin_mappers if m.bin_type == CATEGORICAL)
+        return ds
+
+    monkeypatch.setenv("LIGHTGBM_TPU_DEVICE_BIN", "0")
+    host = build()
+    monkeypatch.setenv("LIGHTGBM_TPU_DEVICE_BIN", "1")  # force on CPU
+    dev = build()
+    assert not host.binned_on_device and dev.binned_on_device
+    assert host.bin_mappers[1].num_bin == max_bin
+    np.testing.assert_array_equal(host.bins, dev.bins)
+    assert host.bins.dtype == dev.bins.dtype == np.uint8
+    for mh, md in zip(host.bin_mappers, dev.bin_mappers):
+        assert mh == md
