@@ -1101,7 +1101,8 @@ class GBDT:
         lowest rung, and `seg_hist_rungs`, the branches a call site
         compiles; what the partition kernel
         was sized to (pack_rows, chunk_lanes): `partition_rows_words` a
-        row and `partition_rows_chunk_lanes` a DMA; and
+        row and `partition_rows_chunk_lanes` a DMA, and how it moves a
+        tile's rows, `partition_rows_tile_form` (TILE_FORM); and
         `score_update_form`, the end-of-tree un-permute and leaf-value
         lookup that were traced (ops/partition.py unpermute, one
         key-value sort, + score_updater.py lookup_form:
@@ -1114,8 +1115,8 @@ class GBDT:
             from ..ops.ordered_hist import (feature_blocks, hist_rungs,
                                             low_bins, onehot_extent)
             from ..ops.pallas_hist import HIST_CHUNK
-            from ..ops.partition import (chunk_lanes, packed_word_rows,
-                                         partition_engine)
+            from ..ops.partition import (TILE_FORM, chunk_lanes,
+                                         packed_word_rows, partition_engine)
             self.metrics.set("partition_engine", partition_engine())
             self.metrics.set(
                 "score_update_form", "sort_kv+"
@@ -1140,6 +1141,7 @@ class GBDT:
             wp = packed_word_rows(words)
             self.metrics.set("partition_rows_words", wp)
             self.metrics.set("partition_rows_chunk_lanes", chunk_lanes(wp))
+            self.metrics.set("partition_rows_tile_form", TILE_FORM)
 
     def _get_fused_fn(self, num_iters):
         if not hasattr(self, "_fused_cache"):

@@ -98,14 +98,20 @@ def apply_partition(src, words, ghc_t, perm):
 # gather unit (about 50M indices a second whatever the bytes, PERF.md
 # section 5). `partition_rows` does the same stable partition with no
 # indexed operation: the row tiles covering the segment stream through
-# VMEM once, each 128-row tile is compacted by a one-hot permutation
-# matrix on the MXU (the 32-bit words split into bytes, which bfloat16
-# holds exactly, one non-zero a column, f32 accumulation: the product
-# moves bits and rounds nothing), and whole aligned chunks are DMA'd
-# back. Left rows overwrite the input (their write chunk never passes
-# the read chunk); right rows go to an HBM scratch at their final
-# offsets and are copied back chunk-aligned once the left stream is
-# done, the boundary chunk merged with the left stream's residue.
+# VMEM once, each 128-row tile is stable-partitioned by one one-hot
+# permutation matrix on the MXU (the 32-bit words split into bytes, which
+# bfloat16 holds exactly, one non-zero a column, f32 accumulation: the
+# product moves bits and rounds nothing): left rows to its first lanes,
+# right rows after them. A lane roll by the fill of each side's ring
+# (`pltpu.roll`, a shift read at run time) then puts each side where its
+# ring continues, and whole aligned chunks are DMA'd back. No tile's
+# permutation depends on another's fill: the left counts of all tiles of
+# a chunk come from one rank matmul, and a ring tile is only stored,
+# never read back, so the tiles of a chunk overlap. Left rows overwrite
+# the input (their write chunk never passes the read chunk); right rows
+# go to an HBM scratch at their final offsets and are copied back
+# chunk-aligned once the left stream is done, the boundary chunk merged
+# with the left stream's residue.
 #
 # Mosaic slices an HBM array only in whole (8, 128) / (4, 128) tiles,
 # so the kernel's arrays carry whole tiles of rows (`pack_rows`): the
@@ -137,7 +143,10 @@ def unpermute(perm, values):
 
 
 PART_CHUNK = 2048   # lanes a DMA moves at most; divides HIST_CHUNK
-PART_TILE = 128     # rows one permutation matrix compacts
+PART_TILE = 128     # rows one permutation matrix partitions
+# how a tile's rows reach the rings (`/trainz` gauge
+# partition_rows_tile_form): one permutation, then a lane roll a side
+TILE_FORM = "one_perm+roll"
 _COPY_DEPTH = 4     # chunk copies of the right stream kept in flight
 # VMEM the two chunks read and the two rings (six chunks of `wp` word
 # rows in all) may take of a v5e's 16 MB of scoped VMEM, beside the
@@ -182,11 +191,18 @@ def unpack_rows(rows_i, rows_f, w):
 
 def _partition_rows_kernel(sc, ri_in, rf_in, go_hbm, ri, rf, si, sf,
                            ibuf, fbuf, gbuf, li, lf, rgi, rgf, xs, ys,
-                           tri, sem_in, sem_fl, sem_cp, *, wp, c):
+                           tri, lv, sem_in, sem_fl, sem_cp, *, wp, c):
     """sc = [seg_b, seg_c, n_left]. ri/rf are the arrays, in place
     (ri_in/rf_in alias them and are not touched); si/sf the HBM scratch
     of the right stream; go_hbm the (1, N) 0/1 decision vector; `c` the
-    lanes of a chunk (chunk_lanes)."""
+    lanes of a chunk (chunk_lanes).
+
+    A tile: its decisions' inclusive ranks and left count `cl` (one
+    matmul for the chunk's tiles), one (T, T) one-hot that sends left
+    row r to lane r and right row r to lane cl + r, one permutation
+    matmul over the byte planes, one re-assembly; then the left side
+    rolled by its ring's fill `fl`, the right by `fr - cl`, each merged
+    into the ring tile its fill stopped in."""
     del ri_in, rf_in
     t = PART_TILE
     i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
@@ -217,63 +233,94 @@ def _partition_rows_kernel(sc, ri_in, rf_in, go_hbm, ri, rf, si, sf,
                                       sem_fl.at[stream, j])
                 for j, (ring, dst) in enumerate(zip(rings, dsts))]
 
-    # constants of the tile: U[s, t] = s <= t gives inclusive ranks
-    s_iota = jax.lax.broadcasted_iota(i32, (t, t), 0)
-    tri[...] = (s_iota <= jax.lax.broadcasted_iota(i32, (t, t), 1)
+    # constants: U[s, u] = s <= u gives inclusive ranks in lanes [0, T)
+    # and, all ones, a tile's left count on every lane of [T, 2T)
+    tri[...] = (jax.lax.broadcasted_iota(i32, (t, 2 * t), 0)
+                <= jax.lax.broadcasted_iota(i32, (t, 2 * t), 1)
                 ).astype(bf16)
+    lv[...] = jnp.zeros(lv.shape, f32)
+    s_iota = jax.lax.broadcasted_iota(i32, (t, t), 0)
     lane = jax.lax.broadcasted_iota(i32, (1, t), 1)
 
     def permute(x, dest):
         """x (R, T) bf16 byte planes; dest (1, T) lane each row goes to
-        (-1: nowhere) -> (R, T) int32 with the rows moved."""
+        -> (R, T) int32 with the rows moved, each byte b as the bits of
+        the float 2^23 + b: 0x4B000000 | b."""
         onehot = (s_iota == dest).astype(bf16)               # [n, t]
         y = jax.lax.dot_general(x, onehot, (((1,), (1,)), ((), ())),
                                 preferred_element_type=f32)
-        return y.astype(i32)
+        return jax.lax.bitcast_convert_type(y + 8388608.0, i32)
 
     def assemble(y):
-        """(R, T) int32 byte planes -> (wp, T) int32, (4, T) float32."""
+        """(R, T) int32 byte planes (permute's) -> (wp, T) int32, (4, T)
+        float32; a shift by 8 or more drops the 2^23's bits."""
         ys[...] = y
-        wi = ys[0:wp]
-        gi = ys[4 * wp:4 * wp + 4]
+        wi = ys[0:wp] & 0xFF
+        gi = ys[4 * wp:4 * wp + 4] & 0xFF
         for b in range(1, 4):
             wi = wi | (ys[b * wp:(b + 1) * wp] << (8 * b))
             gi = gi | (ys[4 * wp + 4 * b:4 * wp + 4 * b + 4] << (8 * b))
         return wi, jax.lax.bitcast_convert_type(gi, f32)
 
-    def append(ring_i, ring_f, wi, gf, fill, cnt):
-        """Rows sit in wi/gf at lanes (fill + r) % T, r < cnt: select
-        them into the two ring tiles position `fill` onwards covers."""
-        off = fill % t
-        s0 = pl.multiple_of((fill // t * t) % (2 * c), t)
-        s1 = pl.multiple_of((s0 + t) % (2 * c), t)
-        m0 = (lane >= off) & (lane < off + cnt)
-        m1 = lane < off + cnt - t
-        for s, m in ((s0, m0), (s1, m1)):
-            ring_i[:, pl.ds(s, t)] = jnp.where(m, wi, ring_i[:, pl.ds(s, t)])
-            ring_f[:, pl.ds(s, t)] = jnp.where(m, gf, ring_f[:, pl.ds(s, t)])
+    def ring_tile(fill):
+        """Lane offset of the ring tile position `fill` falls in (c and T
+        are powers of two)."""
+        return pl.multiple_of(fill & (2 * c - t), t)
 
-    def tile(k, slot, j, fl, fr):
+    def append(ring_i, ring_f, part, wi, gf, fill, cnt):
+        """Rows sit in wi/gf at lanes (fill + r) % T, r < cnt: merged into
+        `part`, the ring tile `fill` falls in as far as it is filled, and
+        stored whole. Returns what the next rows join: the rows that ran
+        past the tile's end where they did. No ring tile is read back, so
+        no tile waits on the last one's store."""
+        off = fill & (t - 1)
+        m = (lane >= off) & (lane < off + cnt)
+        pi = jnp.where(m, wi, part[0])
+        pf = jnp.where(m, gf, part[1])
+        ring_i[:, pl.ds(ring_tile(fill), t)] = pi
+        ring_f[:, pl.ds(ring_tile(fill), t)] = pf
+        over = off + cnt >= t
+        return jnp.where(over, wi, pi), jnp.where(over, gf, pf)
+
+    def decisions(k, slot):
+        """Each tile's decision (1, T), and for all tiles of the chunk in
+        one matmul their inclusive left ranks and left counts, a tile a
+        row: lanes [0, T) and [T, 2T) of (rows, 2T) int32."""
+        lefts = []
+        for j in range(c // t):
+            pos = k * c + j * t + lane
+            go = gbuf[slot, :, j * t:(j + 1) * t] != 0
+            lefts.append((pos < seg_b) | ((pos < seg_e) & go))
+            lv[j:j + 1, :] = lefts[j].astype(f32)
+        ranks = jnp.dot(lv[...].astype(bf16), tri[...],
+                        preferred_element_type=f32).astype(i32)
+        return lefts, ranks
+
+    def tile(slot, j, left, rank, fl, fr, parts):
         sl = slice(j * t, (j + 1) * t)
         wv = ibuf[slot, :, sl]
         gv = jax.lax.bitcast_convert_type(fbuf[slot, :, sl], i32)
-        pos = k * c + j * t + lane
-        left = (pos < seg_b) | ((pos < seg_e) & (gbuf[slot, :, sl] != 0))
-        ones = left.astype(f32)
-        incl = jnp.dot(jnp.broadcast_to(ones, (16, t)).astype(bf16),
-                       tri[...], preferred_element_type=f32)[0:1]
-        excl = (incl - ones).astype(i32)        # left rows before this one
-        cl = jnp.sum(left.astype(i32))
+        ones = left.astype(i32)
+        excl = rank[:, :t] - ones               # left rows before this one
+        cl = jnp.sum(ones)
         for b in range(4):
             xs[b * wp:(b + 1) * wp] = ((wv >> (8 * b)) & 0xFF).astype(f32)
             xs[4 * wp + 4 * b:4 * wp + 4 * b + 4] = (
                 (gv >> (8 * b)) & 0xFF).astype(f32)
         x = xs[...].astype(bf16)
-        dl = jnp.where(left, (fl + excl) & (t - 1), -1)
-        dr = jnp.where(left, -1, (fr + lane - excl) & (t - 1))
-        append(li, lf, *assemble(permute(x, dl)), fl, cl)
-        append(rgi, rgf, *assemble(permute(x, dr)), fr, t - cl)
-        return fl + cl, fr + (t - cl)
+        # the tile's own stable partition: left rows to lanes [0, cl),
+        # right rows to [cl, T), each side in its old order; nothing of
+        # the rings' fill in it, so no tile's matmul waits on the last
+        wi, gf = assemble(permute(x, jnp.where(left, excl,
+                                               rank[:, t:] + lane - excl)))
+        # each side turned to its ring's phase: left row r to lane
+        # (fl + r) % T, right row r (at lane cl + r) to (fr + r) % T
+        parts = [append(ring_i, ring_f, part, pltpu.roll(wi, shift, 1),
+                        pltpu.roll(gf, shift, 1), fill, cnt)
+                 for ring_i, ring_f, part, shift, fill, cnt in (
+                     (li, lf, parts[0], fl & (t - 1), fl, cl),
+                     (rgi, rgf, parts[1], (fr - cl) & (t - 1), fr, t - cl))]
+        return fl + cl, fr + (t - cl), parts
 
     def wait_flush(stream, pending):
         @pl.when(pending == 1)
@@ -311,8 +358,19 @@ def _partition_rows_kernel(sc, ri_in, rf_in, go_hbm, ri, rf, si, sf,
         # a ring half is written again a chunk after its flush started
         wait_flush(0, pend_l)
         wait_flush(1, pend_r)
+        rings = ((li, lf, fl), (rgi, rgf, fr))
+        parts = [(ring_i[:, pl.ds(ring_tile(fill), t)],
+                  ring_f[:, pl.ds(ring_tile(fill), t)])
+                 for ring_i, ring_f, fill in rings]
+        lefts, ranks = decisions(k, slot)
         for j in range(c // t):
-            fl, fr = tile(k, slot, j, fl, fr)
+            fl, fr, parts = tile(slot, j, lefts[j], ranks[j:j + 1], fl, fr,
+                                 parts)
+        # the tiles the fills stopped in, rows past a tile's end included
+        for (ring_i, ring_f, _), fill, (pi, pf) in zip(rings, (fl, fr),
+                                                        parts):
+            ring_i[:, pl.ds(ring_tile(fill), t)] = pi
+            ring_f[:, pl.ds(ring_tile(fill), t)] = pf
         done_l, pend_l = maybe_flush(0, fl, done_l)
         done_r, pend_r = maybe_flush(1, fr, done_r)
         return fl, fr, done_l, done_r, pend_l, pend_r
@@ -415,7 +473,8 @@ def partition_rows(rows_i, rows_f, go_left, seg_b, seg_c, n_left,
                 pltpu.VMEM((4, 2 * c), jnp.float32),
                 pltpu.VMEM((planes, t), jnp.float32),   # byte planes in
                 pltpu.VMEM((planes, t), jnp.int32),     # ... and out
-                pltpu.VMEM((t, t), jnp.bfloat16),       # rank matrix
+                pltpu.VMEM((t, 2 * t), jnp.bfloat16),   # rank matrix
+                pltpu.VMEM((PART_CHUNK // t, t), jnp.float32),  # decisions
                 pltpu.SemaphoreType.DMA((2, 3)),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SemaphoreType.DMA((_COPY_DEPTH, 2)),

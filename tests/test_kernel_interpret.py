@@ -627,3 +627,122 @@ def test_partition_kernel_interpret_wide_rows(wp, case):
     np.testing.assert_array_equal(np.asarray(got_i), rows_i[:, order])
     np.testing.assert_array_equal(np.asarray(got_f).view(np.int32),
                                   rows_f[:, order].view(np.int32))
+
+
+# ------------------ a tile's rows turned to the rings' phase by lane rolls
+def _tile_counts_case():
+    """Whole array, each tile's left count given: the left fill passes
+    lanes 0, 1, 63 and 127 in the first four tiles, a tile all left and
+    one all right follow, and the total is a multiple of 128 so that the
+    right stream's shifts (n_left - fill) take 127, 63, 1 and 0."""
+    rng = np.random.RandomState(40)
+    counts = [1, 62, 64, 128, 0, 1, 65]
+    counts += list(rng.randint(0, 129, _N // 128 - len(counts) - 1))
+    counts.append(-sum(counts) % 128)
+    go = np.zeros(_N, bool)
+    for j, k in enumerate(counts):
+        go[j * 128 + rng.choice(128, k, replace=False)] = True
+    return go, 0, _N
+
+
+def _alternating_case():
+    return np.arange(_N) % 2 == 0, 5, 6000
+
+
+def _left_on_lane_127_case():
+    return np.arange(_N) % 128 == 127, 0, _N - 300
+
+
+def _three_chunks_case():
+    """Begins on lane 1 of a tile of chunk 0, ends in chunk 2."""
+    return np.random.RandomState(41).rand(_N) < 0.5, 13 * 128 + 1, 4335
+
+
+def _whole_tiles_case():
+    """A tile all left and a tile all right in the middle of a segment,
+    twice, in both orders."""
+    go = np.random.RandomState(42).rand(_N) < 0.3
+    for j, side in ((20, True), (21, False), (30, False), (31, True)):
+        go[j * 128:(j + 1) * 128] = side
+    return go, 100, 7900
+
+
+TILE_PHASE_CASES = {
+    "tile_counts": _tile_counts_case,
+    "alternating": _alternating_case,
+    "left_on_lane_127": _left_on_lane_127_case,
+    "three_chunks_begin_on_lane_1": _three_chunks_case,
+    "whole_tiles_mid_segment": _whole_tiles_case,
+}
+
+
+def _roll_shifts(go, seg_b, seg_c, c=2048, t=128):
+    """The lane rolls `partition_rows` gives each tile of the segment's
+    chunks: the left side's fill % T, the right side's (fill - cl) % T;
+    and each tile's left count."""
+    seg_e = seg_b + seg_c
+    pos = np.arange(go.shape[0])
+    left = (pos < seg_b) | ((pos < seg_e) & go)
+    fl, fr = seg_b // c * c, seg_b + int(left[seg_b:seg_e].sum())
+    shifts_l, shifts_r, counts = set(), set(), []
+    for p in range(seg_b // c * c, -(-seg_e // c) * c, t):
+        cl = int(left[p:p + t].sum())
+        shifts_l.add(fl % t)
+        shifts_r.add((fr - cl) % t)
+        counts.append(cl)
+        fl, fr = fl + cl, fr + t - cl
+    return shifts_l, shifts_r, counts
+
+
+def test_tile_phase_cases_reach_every_roll():
+    """The crafted cases put both sides' rolls at 0, 1, 63 and 127, and
+    hold tiles with no left row and with 128 inside a segment."""
+    edges = {0, 1, 63, 127}
+    ls, rs, counts = _roll_shifts(*_tile_counts_case())
+    assert edges <= ls and edges <= rs, (sorted(edges - ls),
+                                         sorted(edges - rs))
+    for make in (_tile_counts_case, _whole_tiles_case):
+        counts = _roll_shifts(*make())[2]
+        assert 0 in counts[1:-1] and 128 in counts[1:-1]
+    assert _three_chunks_case()[1] % 128 == 1
+
+
+@pytest.mark.parametrize("wp", [8, 40])
+@pytest.mark.parametrize("case", sorted(TILE_PHASE_CASES))
+def test_partition_kernel_tile_phases(wp, case):
+    """`partition_rows` on crafted decisions (each tile stable-partitioned
+    by one permutation, each side turned to its ring's fill by a lane
+    roll) against prefix sums, the inverse-permutation scatter and the
+    gathers: every word, statistic and perm bit-equal, at 8 and 40 word
+    rows (chunks of 2,048 lanes)."""
+    from lightgbm_tpu.ops.partition import (apply_partition,
+                                            invert_permutation,
+                                            partition_rows,
+                                            split_destinations)
+    go, seg_b, seg_c = TILE_PHASE_CASES[case]()
+    rng = np.random.RandomState(wp)
+    rows_i = jnp.asarray(rng.randint(-2**31, 2**31 - 1, size=(wp, _N),
+                                     dtype=np.int64).astype(np.int32))
+    # every 32-bit pattern of the statistics, NaN payloads included
+    rows_f = jax.lax.bitcast_convert_type(jnp.asarray(rng.randint(
+        -2**31, 2**31 - 1, size=(4, _N), dtype=np.int64).astype(np.int32)),
+        jnp.float32)
+    perm = jnp.arange(_N, dtype=jnp.int32)
+    go, b, c = jnp.asarray(go), jnp.int32(seg_b), jnp.int32(seg_c)
+
+    @jax.jit
+    def kernel(ri, rf, go, b, c):
+        n_left = split_destinations(go, b, c)[1]
+        return partition_rows(ri, rf, go, b, c, n_left, interpret=True)
+
+    @jax.jit
+    def formulation(ri, rf, go, b, c):
+        src = invert_permutation(split_destinations(go, b, c)[0])
+        return apply_partition(src, ri, rf, perm)[:2]
+
+    for name, g, w in zip(("words", "stats"), kernel(rows_i, rows_f, go, b, c),
+                          formulation(rows_i, rows_f, go, b, c)):
+        np.testing.assert_array_equal(
+            np.asarray(jax.lax.bitcast_convert_type(g, jnp.int32)),
+            np.asarray(jax.lax.bitcast_convert_type(w, jnp.int32)),
+            err_msg=f"{case} at wp={wp}: {name}")

@@ -412,6 +412,7 @@ def test_fused_matches_per_iteration_at_300_columns(max_bin):
     assert gauges["seg_hist_block_features"] == (128 if max_bin == 63 else 32)
     assert gauges["partition_rows_words"] == 80
     assert gauges["partition_rows_chunk_lanes"] == 2048
+    assert gauges["partition_rows_tile_form"] == "one_perm+roll"
 
 
 def test_builder_engines_grow_the_same_tree(monkeypatch):

@@ -610,6 +610,7 @@ def test_seg_hist_onehot_gauges(max_bin, rows, features):
     assert gauges["seg_hist_block_features"] == 4
     assert gauges["partition_rows_words"] == 8
     assert gauges["partition_rows_chunk_lanes"] == 2048
+    assert gauges["partition_rows_tile_form"] == "one_perm+roll"
 
 
 def test_process_tracer_holds_dataset_spans(trained):
@@ -968,6 +969,29 @@ def test_builder_compiles_at_2000_columns(one_chip, b, blocks):
             assert acc in ln, ln[:200]
             assert path.index("hist") < path.index("seg_hist"), ln[:200]
     assert names == {"seg_hist", "partition_rows"}
+
+
+@pytest.mark.parametrize("wp,lanes", [(8, 2048), (40, 2048), (504, 512)])
+def test_partition_rows_compiles_at_the_cells_widths(one_chip, wp, lanes):
+    """The chip's compiler takes the partition kernel alone at the word
+    rows of the benchmark's cells (8: 28 or 13 columns; 40: 136; 504:
+    2,000) and their chunks: the tile's one permutation and the two
+    lane rolls by a shift read at run time lower through Mosaic at every
+    width, and the compiled text holds one custom call `partition_rows`
+    on the rows' array."""
+    from lightgbm_tpu.ops.partition import chunk_lanes, partition_rows
+    assert chunk_lanes(wp) == lanes
+    n = 4 * 2048
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in [((wp, n), jnp.int32), ((4, n), jnp.float32),
+                         ((n,), jnp.bool_), ((), jnp.int32),
+                         ((), jnp.int32), ((), jnp.int32)]]
+    with fresh_compiles():
+        text = jax.jit(partition_rows).lower(*args).compile().as_text()
+    (kernel,) = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert re.match(r"\s*(ROOT )?%partition_rows[.\d]* = ", kernel), \
+        kernel[:200]
+    assert f"s32[{wp},{n}]" in kernel, kernel[:200]
 
 
 @pytest.mark.parametrize("columns,w,b,window_rows", [
