@@ -255,6 +255,11 @@ class OutOfCoreTreeLearner:
     def local_row_leaf(self, out, n_local):
         return out["row_leaf"][:n_local]
 
+    def score_layout(self):
+        """None: the bins stream from disk a block at a time, so there
+        is no fused scan over them (models/gbdt.py _fused_learner_ok)."""
+        return None
+
     def local_leaf_values(self, out):
         return out["leaf_value"]
 

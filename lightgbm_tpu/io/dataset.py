@@ -208,17 +208,7 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
     num = [u for u, m in enumerate(mappers) if m.bin_type != CATEGORICAL]
     span = functools.partial(PROCESS_TRACER.span, rows=n, features=f)
     with span("host_prep"):
-        b_max = max((len(mappers[u].bin_upper_bound) for u in num), default=1)
-        bounds = np.full((len(num), b_max), np.inf)
-        for i, u in enumerate(num):
-            bounds[i, :len(mappers[u].bin_upper_bound)] = (
-                mappers[u].bin_upper_bound)
-        b32 = bounds.astype(np.float32)
-        lifted = b32.astype(np.float64) > bounds
-        b32 = np.where(lifted,
-                       np.nextafter(b32, np.float32(-np.inf),
-                                    dtype=np.float32), b32)
-        # (+inf pad bounds contribute 0 to the strict-compare count)
+        b32 = _device_bounds(mappers, num)
         chunk = 1 << 16
         n_pad = -(-n // chunk) * chunk
         all_cols = (f == mat.shape[1]
@@ -292,6 +282,173 @@ def _bin_dense_on_device(mat, real_idx, mappers, dtype):
         for cols, out in outs:
             bins[cols] = out.T
         return bins
+
+
+def _device_bounds(mappers, num):
+    """(len(num), most bounds) float32 upper bounds of the numerical
+    mappers at positions `num`, each the largest float32 not above its
+    float64 bound (so `v > bound32` is the float64 `v > bound` for every
+    float32 v); +inf pads a row and counts nothing."""
+    b_max = max((len(mappers[u].bin_upper_bound) for u in num), default=1)
+    bounds = np.full((len(num), b_max), np.inf)
+    for i, u in enumerate(num):
+        bounds[i, :len(mappers[u].bin_upper_bound)] = (
+            mappers[u].bin_upper_bound)
+    b32 = bounds.astype(np.float32)
+    lifted = b32.astype(np.float64) > bounds
+    return np.where(lifted, np.nextafter(b32, np.float32(-np.inf),
+                                         dtype=np.float32), b32)
+
+
+class RowShards:
+    """The bins of a dataset as a data-parallel mesh holds them: `array`
+    is (F, W * shard_rows) on `devices`, sharded by row, device i
+    holding rows [i * shard_rows, (i + 1) * shard_rows) of the dataset
+    with zeros past row num_rows - 1 (parallel/learners.py
+    row_shard_layout: the learner's own padding). `counts` are the
+    per-mapper bin occupancies of the real rows and `missing` their NaN
+    counts a used feature (io/profile.py)."""
+
+    def __init__(self, array, devices, shard_rows, num_rows, dtype, counts,
+                 missing):
+        self.array = array
+        self.devices = list(devices)
+        self.shard_rows = int(shard_rows)
+        self.num_rows = int(num_rows)
+        self.dtype = np.dtype(dtype)
+        self.counts = counts
+        self.missing = missing
+
+    def to_host(self):
+        """(F, num_rows) host bins: the one download, for a reader off
+        the mesh (host traversal, subset, save_binary)."""
+        return np.asarray(self.array)[:, :self.num_rows].astype(
+            self.dtype, copy=False)
+
+
+def _bin_dense_on_shards(mat, real_idx, mappers, dtype, devices, shard_rows):
+    """`_bin_dense_on_device` for a data-parallel mesh: device i takes
+    rows [i * shard_rows, (i + 1) * shard_rows) of the matrix (zeros past
+    the last row), already cut into the program's chunks on the host, and
+    bins them where they land; the bins stay there, placed as the
+    learner's row sharding expects (RowShards). No device holds another
+    device's rows and nothing is downloaded. Same rules as the one-chip
+    pass (numerical bounds rounded toward -inf, a categorical column by
+    equality with its kept ids, NaN as 0.0), in ONE program over the mesh
+    that also counts each bin's real rows, and each column's NaN, for the
+    dataset profile (so the host reads the matrix for neither).
+
+    Spans: `dataset/bin_shard` a device (tagged `device`, `rows` it holds,
+    `bytes` uploaded), then `dataset/bin_device` (compile-ledger label
+    `dataset_bin_shards`). Returns None where `_bin_dense_on_device`
+    would (gate off, not float32)."""
+    mode = os.environ.get("LIGHTGBM_TPU_DEVICE_BIN", "auto")
+    if mode == "0":
+        return None
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from ..parallel.mesh import AXIS
+    if mode == "auto" and jax.default_backend() == "cpu":
+        return None
+    if mat.dtype != np.float32:
+        return None
+    n, f, w = mat.shape[0], len(real_idx), len(devices)
+    cat = [u for u, m in enumerate(mappers) if m.bin_type == CATEGORICAL]
+    num = [u for u, m in enumerate(mappers) if m.bin_type != CATEGORICAL]
+    nb = max(m.num_bin for m in mappers)
+    # rows a step of the program bins: a power of two dividing a shard
+    chunk = min(1 << 16, shard_rows & -shard_rows)
+    all_cols = f == mat.shape[1] and np.array_equal(real_idx, np.arange(f))
+    b32 = _device_bounds(mappers, num)
+    ids = (_categorical_ids(mappers, cat) if cat
+           else np.zeros((1, 1), np.float32))
+    pieces = []
+    for i, dev in enumerate(devices):
+        lo, hi = min(i * shard_rows, n), min((i + 1) * shard_rows, n)
+        nbytes = shard_rows * f * 4
+        with PROCESS_TRACER.span("bin_shard", device=int(dev.id),
+                                 rows=hi - lo, bytes=nbytes):
+            if hi - lo == shard_rows and all_cols and mat.flags.c_contiguous:
+                block = mat[lo:hi]           # a view: no host copy
+            else:
+                block = np.zeros((shard_rows, f), np.float32)
+                block[:hi - lo] = mat[lo:hi] if all_cols else \
+                    mat[lo:hi][:, real_idx]
+            pieces.append(jax.block_until_ready(jax.device_put(
+                block.reshape(shard_rows // chunk, chunk, f), dev)))
+    mesh = Mesh(np.asarray(devices), (AXIS,))
+    x = jax.make_array_from_single_device_arrays(
+        (w * shard_rows // chunk, chunk, f), NamedSharding(mesh, P(AXIS)),
+        pieces)
+    held = np.asarray([min(max(n - i * shard_rows, 0), shard_rows)
+                       for i in range(w)], np.int32)
+    program = _shard_bin_program(mesh, chunk, nb, num, cat, dtype)
+    LEDGER.install()
+    with PROCESS_TRACER.span("bin_device", rows=n, features=f, shards=w), \
+            LEDGER.label("dataset_bin_shards"):
+        bins, counts, missing = jax.block_until_ready(program(
+            x, jax.device_put(held, NamedSharding(mesh, P(AXIS))),
+            jnp.asarray(b32), jnp.asarray(ids)))
+    del x, pieces
+    total = np.asarray(counts).sum(axis=0)
+    return RowShards(bins, devices, shard_rows, n, dtype,
+                     [total[u, :mappers[u].num_bin].astype(np.int64)
+                      for u in range(f)],
+                     np.asarray(missing).sum(axis=0).astype(np.int64))
+
+
+def _shard_bin_program(mesh, chunk, nb, num, cat, dtype):
+    """The jitted program `_bin_dense_on_shards` runs over `mesh`:
+    (x (W * C, chunk, F) float32 sharded by its first axis, held (W,)
+    int32 real rows a device, bounds, ids) -> ((F, W * C * chunk) bins
+    sharded by row, (W, F, nb) int32 bin counts of the real rows, (W, F)
+    int32 NaN counts of the real rows)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from ..parallel.mesh import AXIS, shard_map
+    f = len(num) + len(cat)
+    out_dt = jnp.dtype(dtype)
+    num_cols = np.asarray(num) if cat else None
+
+    def local(xc, held, bdev, idv):
+        # xc: this device's (C, chunk, F) rows; held: (1,) its real rows
+        def step(carry, xs):
+            counts, missing = carry
+            c, xb = xs
+            real = (c * chunk + jnp.arange(chunk)) < held[0]
+            nan = jnp.isnan(xb)
+            missing = missing + jnp.sum(nan & real[:, None], axis=0,
+                                        dtype=jnp.int32)
+            xb = jnp.where(nan, 0.0, xb)
+            xn = xb if num_cols is None else xb[:, num_cols]
+            out = jnp.sum(xn[:, :, None] > bdev[None], axis=-1,
+                          dtype=jnp.int32)
+            if cat:
+                t = jnp.trunc(xb[:, jnp.asarray(cat)])
+                k = jnp.arange(idv.shape[1], dtype=jnp.int32)
+                cb = jnp.sum(jnp.where(t[:, :, None] == idv[None], k, 0),
+                             axis=-1, dtype=jnp.int32)
+                out = (jnp.zeros((chunk, f), jnp.int32)
+                       .at[:, jnp.asarray(num)].set(out)
+                       .at[:, jnp.asarray(cat)].set(cb))
+            out = jnp.where(real[:, None], out, 0)   # padding: bin 0
+            counts = counts + jnp.sum(
+                (out[:, :, None] == jnp.arange(nb, dtype=jnp.int32))
+                & real[:, None, None], axis=0, dtype=jnp.int32)
+            return (counts, missing), out.T.astype(out_dt)
+        steps = jnp.arange(xc.shape[0], dtype=jnp.int32)
+        (counts, missing), outs = jax.lax.scan(
+            step, (jnp.zeros((f, nb), jnp.int32), jnp.zeros(f, jnp.int32)),
+            (steps, xc))
+        # (C, F, chunk) -> (F, C * chunk): the device's rows in order
+        return (outs.transpose(1, 0, 2).reshape(f, -1), counts[None],
+                missing[None])
+
+    return jax.jit(shard_map(
+        local, mesh=mesh, in_specs=(P(AXIS), P(AXIS), P(), P()),
+        out_specs=(P(None, AXIS), P(AXIS), P(AXIS))))
 
 
 def _categorical_span(n, real_idx, mappers, cat):
@@ -482,7 +639,10 @@ class CoreDataset:
     """Eagerly-binned dataset (the reference's `Dataset`, dataset.h:278-421)."""
 
     def __init__(self):
-        self.bins = None              # (F_used, N) packed (bins_dtype), host
+        self._bins = None             # (F_used, N) packed (bins_dtype), host
+        # RowShards: the bins on the devices of a data-parallel mesh,
+        # a row block a device, where `bins` is read from if asked
+        self.row_shards = None
         self.bin_mappers = []         # per used feature
         self.used_feature_map = None  # (num_total_features,) int32: total->used or -1
         self.real_feature_idx = None  # (F_used,) int32: used -> total
@@ -504,7 +664,22 @@ class CoreDataset:
 
     # ------------------------------------------------------------ properties
     @property
+    def bins(self):
+        """(F_used, N) host bins. A dataset binned row block by row block
+        on a mesh's devices (`row_shards`) downloads them here on first
+        read; training on that mesh never reads them."""
+        if self._bins is None and self.row_shards is not None:
+            self._bins = self.row_shards.to_host()
+        return self._bins
+
+    @bins.setter
+    def bins(self, value):
+        self._bins = value
+
+    @property
     def num_data(self):
+        if self.row_shards is not None:
+            return self.row_shards.num_rows
         return 0 if self.bins is None else self.bins.shape[1]
 
     @property
@@ -572,6 +747,8 @@ class CoreDataset:
         """dtype of the stored bin matrix — resolvable without a
         resident matrix (the out-of-core dataset forwards its block
         store's dtype), so valid sets can align against either form."""
+        if self._bins is None and self.row_shards is not None:
+            return self.row_shards.dtype
         return self.bins.dtype
 
     def feature_is_categorical(self):
@@ -581,7 +758,9 @@ class CoreDataset:
         """The (F, N) bin matrix on the default device (cached)."""
         import jax.numpy as jnp
         if self._device_bins is None:
-            self._device_bins = jnp.asarray(self.bins)
+            self._device_bins = (
+                jnp.asarray(self.bins) if self.row_shards is None
+                else self.row_shards.array[:, :self.num_data])
         return self._device_bins
 
     # ------------------------------------------------------------- alignment
@@ -1462,15 +1641,24 @@ class DatasetLoader:
 
         if plan is None:
             dtype = bins_dtype(max(m.num_bin for m in mappers))
-            check_bins_budget(len(real_idx), n, np.dtype(dtype).itemsize,
-                              "Dense (unbundled) dataset construction")
-            dev_bins = (_bin_dense_on_device(src._m,
-                                             np.asarray(real_idx),
-                                             mappers, dtype)
-                        if isinstance(src, DenseColumns) else None)
-            ds.binned_on_device = dev_bins is not None
-            ds.bins = (dev_bins if dev_bins is not None
-                       else _bin_on_host(src, real_idx, mappers, dtype))
+            dense = isinstance(src, DenseColumns)
+            # a data-parallel mesh: each device bins its own row block
+            layout = self._row_shard_layout(n) if dense else None
+            if layout is not None:
+                ds.row_shards = _bin_dense_on_shards(
+                    src._m, np.asarray(real_idx), mappers, dtype, *layout)
+            dev_bins = None
+            if ds.row_shards is None:
+                check_bins_budget(len(real_idx), n, np.dtype(dtype).itemsize,
+                                  "Dense (unbundled) dataset construction")
+                dev_bins = (_bin_dense_on_device(src._m,
+                                                 np.asarray(real_idx),
+                                                 mappers, dtype)
+                            if dense else None)
+                ds.bins = (dev_bins if dev_bins is not None
+                           else _bin_on_host(src, real_idx, mappers, dtype))
+            ds.binned_on_device = (ds.row_shards is not None
+                                   or dev_bins is not None)
         else:
             dtype = bins_dtype(int(plan.slot_bins.max()))
             check_bins_budget(plan.num_slots, n, np.dtype(dtype).itemsize,
@@ -1491,12 +1679,30 @@ class DatasetLoader:
         from .profile import DatasetProfile, count_missing, profiling_enabled
         if profiling_enabled():
             with span("profile"):
-                missing = (count_missing(src._m, ds.real_feature_idx)
-                           if isinstance(src, DenseColumns) else None)
+                if ds.row_shards is not None:
+                    # counted on the devices as they binned
+                    missing = ds.row_shards.missing
+                elif isinstance(src, DenseColumns):
+                    missing = count_missing(src._m, ds.real_feature_idx)
+                else:
+                    missing = None
                 ds.profile = DatasetProfile.from_dataset(ds,
                                                          missing=missing)
         Log.info("Number of data: %d, number of features: %d", n, len(mappers))
         return ds
+
+    def _row_shard_layout(self, n):
+        """(devices, rows a device) where this dataset trains with
+        `tree_learner=data` over more than one device of this process:
+        each device then bins its own row block (_bin_dense_on_shards),
+        laid out as the learner pads them (parallel/learners.py
+        row_shard_layout). None otherwise."""
+        cfg = self.config
+        if (getattr(cfg, "tree_learner", "serial") != "data"
+                or int(getattr(cfg, "num_machines", 1)) <= 1):
+            return None
+        from ..parallel.learners import row_shard_layout
+        return row_shard_layout(cfg, n)
 
     def _bin_with_mappers(self, feats, ref_ds: CoreDataset, meta) -> CoreDataset:
         src = feats if is_column_source(feats) else DenseColumns(feats)

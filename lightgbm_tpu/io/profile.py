@@ -119,6 +119,13 @@ class DatasetProfile:
         (F, N) matrix, a bundled stored matrix (slots decode through
         the bundle plan), and an out-of-core block store (streamed
         block by block — never materializes the matrix)."""
+        shards = getattr(ds, "row_shards", None)
+        if shards is not None:
+            # counted on the devices while they binned (io/dataset.py
+            # _bin_dense_on_shards): the bins are never downloaded
+            return cls.from_parts(ds.bin_mappers, ds.real_feature_idx,
+                                  ds.feature_names, shards.counts,
+                                  ds.num_data, missing=missing)
         counts = [np.zeros(m.num_bin, np.int64) for m in ds.bin_mappers]
         plan = ds.bundle_plan
 

@@ -1015,7 +1015,24 @@ class GBDT:
                 # sees — the scan cannot bake that in. train_many falls
                 # back to the per-iteration loop transparently.
                 and not bool(getattr(cfg, "linear_tree", False))
-                and type(self.tree_learner).__name__ == "SerialTreeLearner")
+                and self._fused_learner_ok())
+
+    def _fused_learner_ok(self):
+        """Whether the learner has a score layout for the scan
+        (learner.score_layout): the serial learner's (K, N) score as it
+        is, or the data-parallel one's padded and row-sharded as the
+        bins are, so each shard's rows stay on their chip for the whole
+        block. A sharded layout also pads the gradients' operands, so
+        an objective whose operands are not per-row arrays (a ranking
+        layout) keeps the per-iteration loop there."""
+        layout = self.tree_learner.score_layout()
+        if layout is None:
+            return False
+        if layout[1] is None:
+            return True
+        n = self.num_data
+        return all(np.ndim(a) >= 1 and np.shape(a)[-1] == n for a in
+                   jax.tree_util.tree_leaves(self.objective._grad_ops))
 
     def _note_rank_pairs(self, iterations):
         """A ranking objective's pairwise work, `iterations` times: the
@@ -1108,6 +1125,8 @@ class GBDT:
         key-value sort, + score_updater.py lookup_form:
         `sort_kv+split64` at 255 leaves)."""
         self.metrics.set("trees_per_iteration", int(self.num_class))
+        self.metrics.set("mesh_devices",
+                         int(getattr(self.tree_learner, "n_shards", 1)))
         self.metrics.set(
             "class_axis_form", self._class_axis_form()
             if fused or self.num_class == 1 else "loop")
@@ -1167,13 +1186,27 @@ class GBDT:
         # (every fused-eligible objective has the pure form,
         # _fused_eligible)
         grad_pure = self.objective._grad_pure
+        # a row-sharded learner (learner.score_layout): the score, the
+        # gradients' per-row operands and the in-bag weights padded to
+        # N_pad and placed as the bins are, so the block's row work
+        # stays on each row's chip; `rows` is what a row -> leaf map is
+        # cut to for the score update
+        rows, sharding = learner.score_layout()
+        if sharding is None:
+            inbag = jnp.concatenate([jnp.ones(n, jnp.float32),
+                                     jnp.zeros(pad, jnp.float32)])
+            gops = self.objective._grad_ops
+        else:
+            self.train_score_updater.set_row_layout(rows, sharding)
+            inbag = learner.pad_rows_sharded(jnp.ones(n, jnp.float32))
+            gops = jax.tree_util.tree_map(learner.pad_rows_sharded,
+                                          self.objective._grad_ops)
         data = {
             "bins": learner._bins,
             "nbpf": learner._num_bin_pf,
             "iscat": learner._is_cat,
-            "inbag": jnp.concatenate([jnp.ones(n, jnp.float32),
-                                      jnp.zeros(pad, jnp.float32)]),
-            "gops": self.objective._grad_ops,
+            "inbag": inbag,
+            "gops": gops,
         }
 
         self._note_builder_kernels(fused=True)
@@ -1192,8 +1225,8 @@ class GBDT:
                 # builder writes its own between these two
                 with scope("gradients"):
                     g, h = grad_pure(d["gops"], score)
-                    gp = jnp.pad(g, ((0, 0), (0, pad)))
-                    hp = jnp.pad(h, ((0, 0), (0, pad)))
+                    gp = jnp.pad(g, ((0, 0), (0, n_pad - g.shape[1])))
+                    hp = jnp.pad(h, ((0, 0), (0, n_pad - h.shape[1])))
                     # per-iteration in-bag weights (GOSS); pad rows stay
                     # zero
                     ib = (inbag if inbag_fn is None
@@ -1203,7 +1236,7 @@ class GBDT:
                                iscat)
                     with scope("score_update"):
                         upd = leaf_lookup(out["leaf_value"] * shrink,
-                                          out["row_leaf"][:n])[None, :]
+                                          out["row_leaf"][:rows])[None, :]
                 elif class_axis == "vmap":
                     # one device program for ALL classes: vmap the
                     # whole-tree builder over the class axis (SURVEY M2;
@@ -1216,7 +1249,7 @@ class GBDT:
                         # a class at a time: batched over K the lookup
                         # compiles to the generic gather again
                         upd = jax.lax.map(
-                            lambda o: leaf_lookup(o[0] * shrink, o[1][:n]),
+                            lambda o: leaf_lookup(o[0] * shrink, o[1][:rows]),
                             (out["leaf_value"], out["row_leaf"]))
                 else:
                     # scan the class axis (_class_axis_form has why):
@@ -1231,7 +1264,7 @@ class GBDT:
                         row_leaf = o.pop("row_leaf")
                         with scope("score_update"):
                             u = leaf_lookup(o["leaf_value"] * shrink,
-                                            row_leaf[:n])
+                                            row_leaf[:rows])
                         return None, (o, u)
 
                     # a path component, no scope of its own
@@ -1246,7 +1279,7 @@ class GBDT:
 
             return jax.lax.scan(step, score, (fmasks, iters))
 
-        score = self.train_score_updater.score
+        score = self.train_score_updater.carried()
         fmasks = jnp.ones((num_iters, self.num_class, learner.f_pad),
                           dtype=bool)
         iters = jnp.arange(num_iters, dtype=jnp.int32)
@@ -1327,8 +1360,8 @@ class GBDT:
             with heartbeat.collective_guard("fused_block"):
                 with span("launch", **tags):
                     final_score, stacked = fn(
-                        self.train_score_updater.score, fmasks, iters)
-                    self.train_score_updater.score = final_score
+                        self.train_score_updater.carried(), fmasks, iters)
+                    self.train_score_updater.set_carried(final_score)
                 with span("wait", **tags):
                     # the guard's host read below would block here anyway
                     jax.block_until_ready(final_score)
@@ -1339,9 +1372,14 @@ class GBDT:
                     # detectable
                     with span("guard", bytes=int(final_score.nbytes),
                               **tags):
-                        guardrails.guard_scores(np.asarray(final_score),
-                                                self.iter + num_iters,
-                                                policy)
+                        # a row-sharded score lies on every chip: they
+                        # check their own rows, and the host reads the
+                        # score only to name a value that is not finite
+                        if (learner.score_layout()[1] is None or not bool(
+                                jnp.isfinite(final_score).all())):
+                            guardrails.guard_scores(
+                                np.asarray(final_score),
+                                self.iter + num_iters, policy)
                 with span("tree_fetch", **tags):
                     # ONE transfer for the block
                     host = jax.device_get(stacked)
@@ -1368,6 +1406,12 @@ class GBDT:
         self._count_trees(len(self.models) - n_before)
         if getattr(learner, "_use_partitioned", False):
             self._count_hist_calls(grown)
+        account = getattr(learner, "account_tree_collectives", None)
+        if account is not None:
+            # the block's collectives, from its trees' split counts
+            # (the learner's CommPlan: root + per split)
+            for tree in grown:
+                account(int(tree["n_splits"]))
         self.metrics.inc("transfer_bytes",
                          sum(np.asarray(v).nbytes for v in host.values()))
         self.metrics.set("iteration", self.iter)

@@ -123,6 +123,15 @@ def _stacked_deltas(bins_dev, is_cat, sf, thr, lc, rc, lv, nsp, scale,
 
 
 class ScoreUpdater:
+    """`score` is the (num_class, N) float32 score in dataset row order.
+    A row-sharded trainer (the fused data-parallel scan) keeps it as
+    (num_class, N_pad) with its learner's row sharding instead
+    (`set_row_layout`, `carried` / `set_carried`): rows in dataset order,
+    the padding after row N - 1, every row on the chip that holds its
+    bins. `score` is then the first N columns of that array, cut when
+    read; an assignment to `score` is padded and placed again when the
+    trainer next asks for `padded`."""
+
     def __init__(self, dataset, num_class):
         self.dataset = dataset
         self.num_class = int(num_class)
@@ -130,6 +139,9 @@ class ScoreUpdater:
         self.num_data = n
         self._is_cat_dev = None
         self._decode_dev = None
+        self._layout = None      # (N_pad, sharding) of the padded form
+        self._padded = None      # (K, N_pad) on the layout, or None
+        self._score = None       # (K, N), or None while only _padded is
         init = dataset.metadata.init_score
         if init is not None:
             if len(init) != n * self.num_class:
@@ -139,6 +151,48 @@ class ScoreUpdater:
                 np.asarray(init, dtype=np.float32).reshape(self.num_class, n))
         else:
             self.score = jnp.zeros((self.num_class, n), dtype=jnp.float32)
+
+    @property
+    def score(self):
+        if self._score is None:
+            self._score = self._padded[:, :self.num_data]
+        return self._score
+
+    @score.setter
+    def score(self, value):
+        self._score = value
+        self._padded = None
+
+    def set_row_layout(self, n_pad, sharding):
+        """Keep the score padded to `n_pad` rows on `sharding` (a
+        (K, N_pad) placement) from the next `padded` on."""
+        if self._layout != (n_pad, sharding):
+            self.score = self.score
+            self._layout = (n_pad, sharding)
+
+    @property
+    def padded(self):
+        """The (K, N_pad) score on the row layout: zeros past row N - 1
+        when made from `score`."""
+        if self._padded is None:
+            n_pad, sharding = self._layout
+            self._padded = jax.device_put(
+                jnp.pad(self.score, ((0, 0), (0, n_pad - self.num_data))),
+                sharding)
+        return self._padded
+
+    def carried(self):
+        """The score a fused block takes: `padded` on a row layout,
+        else `score`."""
+        return self.score if self._layout is None else self.padded
+
+    def set_carried(self, value):
+        """Take the score a fused block returns (see `carried`); on a
+        row layout `score` is cut from it when next read."""
+        if self._layout is None:
+            self.score = value
+        else:
+            self._padded, self._score = value, None
 
     def add_score_by_partition(self, leaf_values, row_leaf, curr_class):
         """score += leaf_values[row_leaf] (device lookup)."""

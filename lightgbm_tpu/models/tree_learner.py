@@ -66,6 +66,23 @@ def _tristate(value, name):
     return "auto"
 
 
+def chunk_pad(n, bucketing):
+    """HIST_CHUNK-granular row padding, canonicalized to the
+    shape-bucket grid (`bucketing`, config shape_bucketing) so nearby
+    dataset sizes reuse one lowered executable from the persistent
+    compile cache."""
+    n_chunks = (n + HIST_CHUNK - 1) // HIST_CHUNK
+    if bucketing:
+        n_chunks = canonical_row_chunks(n_chunks)
+    return n_chunks * HIST_CHUNK
+
+
+def shape_bucketing(cfg):
+    """Whether row padding follows the shape-bucket grid (chunk_pad)."""
+    return _tristate(getattr(cfg, "shape_bucketing", "auto"),
+                     "shape_bucketing") != "false"
+
+
 def _partitioned_mode(cfg):
     """Validate + normalize partitioned_build to "auto"/"true"/"false"."""
     return _tristate(getattr(cfg, "partitioned_build", "auto"),
@@ -488,9 +505,7 @@ class SerialTreeLearner:
         self._bundle = train_set.bundle_plan
         self._use_partitioned = self._partitioned_enabled(cfg)
         self._use_compact = self._compaction_enabled(cfg)
-        self._use_shape_bucketing = _tristate(
-            getattr(cfg, "shape_bucketing", "auto"),
-            "shape_bucketing") != "false"
+        self._use_shape_bucketing = shape_bucketing(cfg)
         if self._bundle is not None:
             from ..io.bundling import expansion_maps
             src, slot_of = expansion_maps(self._bundle, train_set.bin_mappers,
@@ -504,8 +519,11 @@ class SerialTreeLearner:
         self.n_pad = n_pad
         chunk = self._effective_chunk(chunk)
         self.row_chunk = chunk
-        bins = train_set.bins
-        if n_pad != self.num_data:
+        # bins a mesh already holds as this learner shards them (the
+        # data-parallel learner: io/dataset.py RowShards) stay there
+        resident = self._resident_bins(train_set, n_pad)
+        bins = train_set.bins if resident is None else None
+        if resident is None and n_pad != self.num_data:
             pad = np.zeros((bins.shape[0], n_pad - self.num_data), dtype=bins.dtype)
             bins = np.concatenate([bins, pad], axis=1)
         if self._bundle is not None and self._use_partitioned:
@@ -528,11 +546,14 @@ class SerialTreeLearner:
         is_cat = train_set.feature_is_categorical()
         if f_pad != self.num_features:
             extra = f_pad - self.num_features
-            bins = np.concatenate(
-                [bins, np.zeros((extra, bins.shape[1]), dtype=bins.dtype)], axis=0)
+            if resident is None:
+                bins = np.concatenate(
+                    [bins, np.zeros((extra, bins.shape[1]), dtype=bins.dtype)],
+                    axis=0)
             num_bin_pf = np.concatenate([num_bin_pf, np.ones(extra, np.int32)])
             is_cat = np.concatenate([is_cat, np.zeros(extra, bool)])
-        self._bins = self._place_bins(bins)
+        self._bins = (self._place_bins(bins) if resident is None
+                      else self._place_resident_bins(resident, f_pad))
         self._num_bin_pf = self._place_rep(num_bin_pf)
         self._is_cat = self._place_rep(is_cat)
         # host-side lookup tables for vectorized device->Tree conversion:
@@ -609,13 +630,7 @@ class SerialTreeLearner:
 
     # hooks overridden by the parallel learners (parallel/learners.py) -------
     def _chunk_pad(self, n):
-        """HIST_CHUNK-granular row padding, canonicalized to the
-        shape-bucket grid so nearby dataset sizes reuse one lowered
-        executable from the persistent compile cache."""
-        n_chunks = (n + HIST_CHUNK - 1) // HIST_CHUNK
-        if self._use_shape_bucketing:
-            n_chunks = canonical_row_chunks(n_chunks)
-        return n_chunks * HIST_CHUNK
+        return chunk_pad(n, self._use_shape_bucketing)
 
     def _pad_rows(self, n, chunk):
         if (jax.default_backend() == "tpu" or self._use_partitioned
@@ -644,11 +659,24 @@ class SerialTreeLearner:
             return jnp.asarray(pack_feature_words(bins))
         return jnp.asarray(bins)
 
+    def _resident_bins(self, train_set, n_pad):
+        """Device bins to train from in place, or None: the host bins
+        are placed (the serial learner always)."""
+        return None
+
     def _place_rows(self, arr):
         return arr
 
     def _place_rep(self, arr):
         return jnp.asarray(arr)
+
+    def score_layout(self):
+        """(rows, sharding) of the score a fused block carries, or None
+        where this learner cannot train in the fused scan. Serial: the
+        (K, N) score as it is, placed by default, (N, None); the
+        data-parallel learner carries it padded and row-sharded
+        (parallel/learners.py)."""
+        return self.num_data, None
 
     def local_row_leaf(self, out, n_local):
         """This process's rows of the row->leaf partition (trivial in

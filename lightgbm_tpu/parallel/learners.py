@@ -49,7 +49,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.tree_learner import SerialTreeLearner, build_tree_device
+from ..models.tree_learner import (SerialTreeLearner, build_tree_device,
+                                   chunk_pad, shape_bucketing)
 from ..ops.split import (K_MIN_SCORE, find_best_split, per_feature_best,
                          split_info_at)
 from ..utils.log import Log
@@ -71,6 +72,38 @@ _TREE_OUT_KEYS = (
 )
 
 _SPLIT_INFO_BYTES = 11 * 4   # SplitInfo: 11 scalar fields on the wire
+
+
+def row_shard_layout(config, n):
+    """(devices, rows a device) of the data-parallel learner on the mesh
+    of `config` for a dataset of n rows, where it pads each shard to the
+    canonical HIST_CHUNK grid (on a TPU, and under the leaf-contiguous
+    builder): device i holds rows [i * S, (i + 1) * S), zeros past row
+    n - 1. The dataset bins each block on its device to this layout
+    (io/dataset.py _bin_dense_on_shards); None off a multi-device mesh
+    of one process."""
+    if jax.process_count() > 1:
+        return None
+    devices = list(make_mesh(config).devices.flat)
+    if len(devices) < 2:
+        return None
+    return devices, chunk_pad(-(-n // len(devices)), shape_bucketing(config))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2), static_argnames="out_sharding")
+def _pack_resident(bins, f_pad, pack, out_sharding=None):
+    """(F, N) device bins -> zero rows up to f_pad, and the packed words
+    (4 features an int32, ops/ordered_hist.py pack_feature_words) where
+    `pack`; row-wise, so each shard stays on its device."""
+    f, n = bins.shape
+    rows = 4 * -(-f_pad // 4) if pack else f_pad
+    bins = jnp.pad(bins, ((0, rows - f), (0, 0)))
+    if pack:
+        p = bins.reshape(rows // 4, 4, n).astype(jnp.uint32)
+        bins = jax.lax.bitcast_convert_type(
+            p[:, 0] | (p[:, 1] << 8) | (p[:, 2] << 16) | (p[:, 3] << 24),
+            jnp.int32)
+    return jax.lax.with_sharding_constraint(bins, out_sharding)
 
 
 class _MeshedTreeLearner(SerialTreeLearner):
@@ -161,8 +194,14 @@ class _MeshedTreeLearner(SerialTreeLearner):
             # serial learner, computed from the rank-invariant n_max so
             # every rank lands on identical global shapes
             shard = self._chunk_pad(shard)
-        elif shard > chunk:
-            shard = ((shard + chunk - 1) // chunk) * chunk
+        else:
+            # a shard holds whole chunks of the grid the serial learner
+            # gives this process's rows (chunk, or all of them when they
+            # fit one): every chunk's f32 partial then sums the rows
+            # serial's does, and only the compensated folds differ —
+            # the serial == parallel contract
+            c = min(chunk, n_max)
+            shard = -(-shard // c) * c
         return shard * d_local
 
     def _effective_chunk(self, chunk):
@@ -194,6 +233,21 @@ class _MeshedTreeLearner(SerialTreeLearner):
                       P(None), P(None), P(None)),
             out_specs=self._out_specs())
 
+    def score_layout(self):
+        """None: a meshed learner's fused block is the data-parallel
+        learner's alone (its override); the others keep the
+        per-iteration loop."""
+        return None
+
+    def pad_rows_sharded(self, arr):
+        """(..., N) -> (..., N_pad): zeros past row N - 1, the last axis
+        sharded as the rows are (score_layout)."""
+        arr = np.asarray(arr)
+        widths = [(0, 0)] * (arr.ndim - 1) + [(0, self.n_pad - arr.shape[-1])]
+        spec = P(*([None] * (arr.ndim - 1)), AXIS)
+        return jax.device_put(np.pad(arr, widths),
+                              NamedSharding(self.mesh, spec))
+
     def _bins_sharding(self):
         if self.shard_features:
             return NamedSharding(self.mesh, P(AXIS, None))
@@ -215,6 +269,25 @@ class _MeshedTreeLearner(SerialTreeLearner):
                 return place_global_rows(sh, bins)
             return place_replicated(sh, bins)
         return jax.device_put(bins, sh)
+
+    def _resident_bins(self, train_set, n_pad):
+        """The dataset's RowShards where they are laid out as this
+        learner shards rows (same devices in mesh order, same padded
+        length, no bundling); else None and the host bins are placed."""
+        shards = getattr(train_set, "row_shards", None)
+        if (shards is None or not self.shard_rows or self.n_proc > 1
+                or self._bundle is not None
+                or shards.devices != list(self.mesh.devices.flat)
+                or shards.shard_rows * len(shards.devices) != n_pad):
+            return None
+        return shards.array
+
+    def _place_resident_bins(self, bins, f_pad):
+        """Device-resident (F, N_pad) row-sharded bins -> the builder's
+        operand on the same devices: features padded to f_pad, packed
+        into words under the leaf-contiguous builder."""
+        return _pack_resident(bins, f_pad, bool(self._use_partitioned),
+                              out_sharding=self._bins_sharding())
 
     def _place_rows(self, arr):
         sh = self._rows_sharding()
@@ -353,6 +426,15 @@ class DataParallelTreeLearner(_MeshedTreeLearner):
     name = "data"
     shard_rows = True
     partitioned_capable = True
+
+    def score_layout(self):
+        """(N_pad, sharding of a (K, N_pad) array) on which a fused
+        block keeps the score and every other per-row array, so that
+        row i sits on the chip that holds row i's bins; None where the
+        rows span processes (each holds its own block)."""
+        if self.n_proc > 1:
+            return None
+        return self.n_pad, NamedSharding(self.mesh, P(None, AXIS))
 
     def _rs_eligible(self):
         """Reduce-scatter runs on the masked core for unbundled

@@ -278,6 +278,10 @@ class CommPlan:
                 for k in COLLECTIVE_KINDS}
 
     def account(self, metrics, n_splits):
+        """Advance the counters by one tree of `n_splits` splits: the
+        bytes of each kind, and the histogram reductions themselves,
+        `hist_reduce_calls` (one for the root, one a split) and
+        `hist_reduce_bytes` (their received bytes)."""
         total = 0
         for kind, nbytes in self.per_tree(n_splits).items():
             if nbytes:
@@ -285,6 +289,10 @@ class CommPlan:
                 total += nbytes
         if total:
             metrics.inc("collective_bytes", total)
+        reduced = self.per_tree(n_splits)["hist_reduce"]
+        if reduced:
+            metrics.inc("hist_reduce_calls", 1 + int(n_splits))
+            metrics.inc("hist_reduce_bytes", reduced)
         return total
 
 

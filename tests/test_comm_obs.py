@@ -157,9 +157,10 @@ def test_comm_records_journal_and_gauges(tmp_path):
         assert 0.0 <= rec["overlap_pct"] <= 100.0
         assert rec["wait_s"] >= 0 and rec["wall_s"] > 0
         assert "mono" in rec
-    # the guarded build dispatch was attributed as dispatch, not wait
+    # the guarded dispatch was attributed as dispatch, not wait: with
+    # tree_learner=data on the fused scan, the block's one guarded sync
     all_waits = {k for r in comm for k in (r.get("waits") or {})}
-    assert any(k.endswith("tree_build") for k in all_waits)
+    assert any(k.endswith("fused_block") for k in all_waits)
     snap = g.metrics.snapshot()["gauges"]
     assert 0.0 <= snap["comm_overlap_pct"] <= 100.0
     assert snap["comm_wait_s"] >= 0.0

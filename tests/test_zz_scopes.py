@@ -1110,3 +1110,88 @@ def test_split_writes_two_rows_of_the_cache_in_place(one_chip, which, leaves,
     carried = re.search(r" get-tuple-element\(%([^\s,)]+)\)", source).group(1)
     assert re.search(r" parameter\(0\)", comps[branch][carried]), source
     assert re.search(rf" fusion\(%{re.escape(first)},", second_ln)
+
+
+# ------------------------------------- the data-parallel exchange (PR 41)
+def test_hist_reduce_survives_the_data_parallel_fused_scan():
+    """The fused block of `tree_learner=data` over 4 of the 8 virtual
+    devices, compiled afresh: its histogram all-reduces (the root's and
+    the split loop's) carry `hist_reduce` on their op_name, and the block
+    holds no other collective (rows stay on their device)."""
+    rng = np.random.RandomState(11)
+    x = rng.randn(ROWS, 6).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 7, "max_bin": 31,
+              "partitioned_build": "true", "verbose": -1, "metric": "none",
+              "tree_learner": "data", "num_machines": 4}
+    with recorded_compiles() as texts:
+        ds = lgb.Dataset(x, label=y, params=dict(params)).construct()
+        lgb.train(dict(params), ds, num_boost_round=BLOCK)
+    (text,) = [t for t in texts if "jit(fused)" in t]
+    reduces = [ln for ln in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", ln)]
+    assert len(reduces) >= 2
+    for ln in reduces:
+        path = re.search(r'op_name="([^"]*)"', ln).group(1).split("/")
+        assert "hist_reduce" in path, ln[:300]
+    assert not [ln for ln in text.splitlines() if re.search(
+        r" (all-gather|all-to-all|collective-permute)(-start)?\(", ln)]
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return Mesh(np.asarray(topo.devices), ("data",))
+
+
+def test_data_parallel_builder_compiles_for_four_chips(four_chips):
+    """The chip's compiler takes the data-parallel partitioned builder
+    over a described v5e 2x2 at the Criteo cell's width (67 columns in 17
+    word rows, 255 bins): both kernels on each chip under their names,
+    the histogram's all-reduce under `hist_reduce`, and no collective
+    that would move rows between chips."""
+    import functools
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from lightgbm_tpu.parallel.mesh import AXIS, compressed_psum, shard_map
+    w, b, shard = 17, 255, 2 * 4096
+    psum = functools.partial(compressed_psum, axis_name=AXIS)
+
+    def part(words, g, h, ib, fm, nb, ic):
+        out = build_tree_partitioned(
+            words, g, h, ib, fm, nb, ic, num_leaves=3, max_bin=b,
+            params=SplitParams(100.0, 10.0, 0.0, 0.0, 0.0), max_depth=-1,
+            f_real=67, hist_reduce_fn=psum)
+        return out["leaf_value"], out["row_leaf"]
+    core = shard_map(part, mesh=four_chips,
+                     in_specs=(P(None, AXIS), P(AXIS), P(AXIS), P(AXIS),
+                               P(), P(), P()),
+                     out_specs=(P(), P(AXIS)))
+    n = 4 * shard
+    sh = lambda *spec: NamedSharding(four_chips, P(*spec))  # noqa: E731
+    args = [jax.ShapeDtypeStruct(s, d, sharding=h) for s, d, h in [
+        ((w, n), jnp.int32, sh(None, AXIS)), ((n,), jnp.float32, sh(AXIS)),
+        ((n,), jnp.float32, sh(AXIS)), ((n,), jnp.float32, sh(AXIS)),
+        ((4 * w,), jnp.bool_, sh()), ((4 * w,), jnp.int32, sh()),
+        ((4 * w,), jnp.bool_, sh())]]
+    with fresh_compiles(), \
+            mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(core).lower(*args).compile().as_text()
+    names = {re.match(r"\s*(ROOT )?%([a-z_]+)", ln).group(2)
+             for ln in text.splitlines() if "tpu_custom_call" in ln}
+    assert names == {"seg_hist", "partition_rows"}
+    reduces = [ln for ln in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", ln)]
+    assert reduces
+    for ln in reduces:
+        assert "hist_reduce" in re.search(r'op_name="([^"]*)"',
+                                          ln).group(1).split("/"), ln[:300]
+    assert not [ln for ln in text.splitlines() if re.search(
+        r" (all-gather|all-to-all|collective-permute)(-start)?\(", ln)]
