@@ -1119,7 +1119,9 @@ class GBDT:
         compiles; what the partition kernel
         was sized to (pack_rows, chunk_lanes): `partition_rows_words` a
         row and `partition_rows_chunk_lanes` a DMA, and how it moves a
-        tile's rows, `partition_rows_tile_form` (TILE_FORM); and
+        tile's rows, `partition_rows_tile_form` (TILE_FORM); how the
+        position -> leaf map is made, `pos_leaf_form` (POS_LEAF_FORM:
+        `once_a_tree`, from the segment bounds after the split loop); and
         `score_update_form`, the end-of-tree un-permute and leaf-value
         lookup that were traced (ops/partition.py unpermute, one
         key-value sort, + score_updater.py lookup_form:
@@ -1134,8 +1136,9 @@ class GBDT:
             from ..ops.ordered_hist import (feature_blocks, hist_rungs,
                                             low_bins, onehot_extent)
             from ..ops.pallas_hist import HIST_CHUNK
-            from ..ops.partition import (TILE_FORM, chunk_lanes,
-                                         packed_word_rows, partition_engine)
+            from ..ops.partition import (POS_LEAF_FORM, TILE_FORM,
+                                         chunk_lanes, packed_word_rows,
+                                         partition_engine)
             self.metrics.set("partition_engine", partition_engine())
             self.metrics.set(
                 "score_update_form", "sort_kv+"
@@ -1161,6 +1164,7 @@ class GBDT:
             self.metrics.set("partition_rows_words", wp)
             self.metrics.set("partition_rows_chunk_lanes", chunk_lanes(wp))
             self.metrics.set("partition_rows_tile_form", TILE_FORM)
+            self.metrics.set("pos_leaf_form", POS_LEAF_FORM)
 
     def _get_fused_fn(self, num_iters):
         if not hasattr(self, "_fused_cache"):

@@ -56,7 +56,7 @@ from ..ops.ordered_hist import (bucket_sizes, cover_index,
 from ..ops.pallas_hist import HIST_CHUNK
 from ..ops.partition import (apply_partition, invert_permutation,
                              pack_rows, partition_engine, partition_rows,
-                             split_destinations, unpermute)
+                             segment_leaves, split_destinations, unpermute)
 from ..ops.split import SplitParams, find_best_split, K_MIN_SCORE
 from ..telemetry.trace import scope
 from .tree_learner import (apply_tree_split, init_split_state,
@@ -304,8 +304,6 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
         state["ghc"] = ghc0
         if not kernel_engine:
             state["perm"] = perm0
-        with scope("pos_leaf"):
-            state["pos_leaf"] = jnp.zeros(n_pad, dtype=jnp.int32)
         state["seg_begin"] = jnp.zeros(l, dtype=jnp.int32)
         # FULL row counts (in-bag + oob + pad), not the tree's in-bag
         # counts
@@ -353,11 +351,6 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
                                    .at[right_id].set(seg_b + n_left))
                 st["seg_cnt"] = (st["seg_cnt"].at[best_leaf].set(n_left)
                                  .at[right_id].set(seg_c - n_left))
-                with scope("pos_leaf"):
-                    pos = jnp.arange(n_pad, dtype=jnp.int32)
-                    st["pos_leaf"] = jnp.where(
-                        (pos >= seg_b + n_left) & (pos < seg_b + seg_c),
-                        right_id, st["pos_leaf"])
 
             if cache_hists:
                 # ---- smaller-child histogram + parent subtraction
@@ -412,10 +405,15 @@ def build_tree_partitioned(words, grad, hess, inbag, feature_mask,
         return jax.lax.cond(do, do_split, no_split, st)
 
     state = jax.lax.fori_loop(0, l - 1, body, state)
+    # position->leaf map: the leaves' segments tile the positions, so it
+    # is made once from their bounds (no pass over N at each split)
+    with scope("tree_state"), scope("pos_leaf"):
+        pos_leaf = segment_leaves(state["seg_begin"], state["seg_cnt"],
+                                  n_pad)
     # original-order row->leaf map: each row moved once at tree end
     with scope("score_update"):
         perm = state["words"][-1] if kernel_engine else state["perm"]
-        row_leaf = unpermute(perm, state["pos_leaf"])
+        row_leaf = unpermute(perm, pos_leaf)
     return {
         "n_splits": state["n_splits"],
         "row_leaf": row_leaf,

@@ -142,6 +142,61 @@ def unpermute(perm, values):
     return jax.lax.sort((perm, values), num_keys=1, is_stable=False)[1]
 
 
+# how a tree's position -> leaf map is made (`/trainz` gauge
+# pos_leaf_form): once, after the split loop, from the segment bounds
+POS_LEAF_FORM = "once_a_tree"
+
+
+def leaf_row(num_leaves):
+    """Positions a row of `segment_leaves`' product: 2,048 (it divides
+    every n_pad, a multiple of HIST_CHUNK), fewer only past 8,192
+    leaves, so that a row's partial sums (a step of |step| < L at most
+    at each position) stay under 2^24, where float32 is exact."""
+    return min(2048, 1 << (((1 << 24) // num_leaves).bit_length() - 1))
+
+
+def segment_leaves(seg_begin, seg_cnt, n):
+    """(n,) int32: the leaf whose segment [seg_begin[l], +seg_cnt[l])
+    holds each position, for segments that tile [0, n) (a leaf with
+    seg_cnt 0 holds none: an unused leaf, or an empty child). The same
+    bits as rewriting a zero vector at each split with the right
+    child's id over its range, at one pass over n for the tree.
+
+    Each non-empty segment steps the map from the leaf that ends where
+    it begins (none at 0) to its own id, so a position's leaf is the sum
+    of the steps whose segment begins at or before it. With positions
+    as rows of `leaf_row` lanes, that sum is the steps of the segments
+    that begin in earlier rows (a (rows, L) compare-sum) plus one
+    product of each row's one-hot of the segments that begin in it
+    against each step spread over the lanes at or after its begin: the
+    MXU's work, n x L multiply-adds, and no gather, scatter or sort.
+    The steps enter in bfloat16 pieces of 8 significant bits (one piece
+    up to 256 leaves), so every product is exact, and every partial sum
+    is an integer under 2^24, exact in the float32 accumulator."""
+    l = seg_begin.shape[0]
+    width = leaf_row(l)
+    ids = jnp.arange(l, dtype=jnp.int32)
+    live = seg_cnt > 0
+    ends_here = live[None, :] & (seg_begin[None, :] + seg_cnt[None, :]
+                                 == seg_begin[:, None])
+    pred = jnp.sum(jnp.where(ends_here, ids[None, :], 0), axis=1)
+    step = jnp.where(live, ids - pred, 0)
+    row_of = seg_begin // width
+    row = jnp.arange(n // width, dtype=jnp.int32)[:, None]
+    before = jnp.sum(jnp.where(row_of[None, :] < row, step[None, :], 0),
+                     axis=1)
+    begins_in = (row_of[None, :] == row).astype(jnp.bfloat16)
+    lane = jnp.arange(width, dtype=jnp.int32)[None, :]
+    spread = jnp.where(seg_begin[:, None] % width <= lane, step[:, None], 0)
+    within = 0.0
+    for _ in range(-(-max(l - 1, 1).bit_length() // 8)):
+        piece = spread.astype(jnp.bfloat16)
+        within = within + jnp.dot(begins_in, piece,
+                                  preferred_element_type=jnp.float32)
+        spread = spread - piece.astype(jnp.int32)
+    return (before[:, None] + within.astype(jnp.int32)).reshape(n)
+
+
 PART_CHUNK = 2048   # lanes a DMA moves at most; divides HIST_CHUNK
 PART_TILE = 128     # rows one permutation matrix partitions
 # how a tile's rows reach the rings (`/trainz` gauge

@@ -133,9 +133,10 @@ def test_partition_segment_matches_full_array():
         np.testing.assert_array_equal(np.asarray(p2), np.asarray(p_ref))
 
 
-def _booster(x, y, params):
+def _booster(x, y, params, categorical_features=()):
     cfg = Config.from_params(params)
-    ds = DatasetLoader(cfg).construct_from_matrix(x, label=y)
+    ds = DatasetLoader(cfg).construct_from_matrix(
+        x, label=y, categorical_features=categorical_features)
     objective = create_objective(cfg.objective, cfg)
     objective.init(ds.metadata, ds.num_data)
     booster = GBDT()
@@ -469,6 +470,10 @@ SCORE_UPDATE_CASES = {
     "l255": (255, {}, 1),
     "bagging": (129, {"bagging_fraction": 0.5, "bagging_freq": 1}, 1),
     "stops_early": (255, {"min_data_in_leaf": 400}, 1),
+    # four row shards of 4,096 over 5,000 rows: the last two hold only
+    # padding, so their splits keep every row on the left and leave the
+    # right child empty
+    "empty_child": (63, {"tree_learner": "data", "num_machines": 4}, 1),
     "class_step": (129, {}, 3),
     # the masked builder: one class, then vmapped over three with a
     # lookup a class
@@ -510,6 +515,8 @@ def test_fused_score_update_bit_exact(case):
     fused = _booster(x, y, params)
     learner = fused.tree_learner
     assert learner._use_partitioned == (not case.startswith("masked"))
+    if case == "empty_child":
+        assert learner.n_shards == 4 and learner.n_pad == 4 * 4096
     assert not learner._use_compact and fused._fused_eligible()
     assert (learner.n_pad > n) == learner._use_partitioned
     score0 = np.asarray(fused.train_score_updater.score)
@@ -571,6 +578,191 @@ def test_unpermute_moves_each_value_to_its_row(classes):
     assert "sort" in prims and not prims & {"scatter", "scatter-add"}
     got = jax.jit(fn)(jnp.asarray(perm), jnp.asarray(values))
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _per_split_select(n, splits):
+    """The position -> leaf map as the split loop used to keep it: a zero
+    vector, rewritten at the i-th split with the right child's id (i + 1)
+    over the positions its rows moved to."""
+    pos = np.arange(n, dtype=np.int32)
+    pos_leaf = np.zeros(n, np.int32)
+    for i, (seg_b, seg_c, n_left) in enumerate(splits):
+        pos_leaf = np.where((pos >= seg_b + n_left) & (pos < seg_b + seg_c),
+                            i + 1, pos_leaf)
+    return pos_leaf
+
+
+def _drawn_splits(rng, n, leaves, stop):
+    """`stop` splits of random leaves at random left counts, a fifth of
+    them sending every row left (an empty right child) and a fifth every
+    row right; the final segment bounds and each split's (begin, count,
+    left count)."""
+    seg_begin = np.zeros(leaves, np.int32)
+    seg_cnt = np.zeros(leaves, np.int32)
+    seg_cnt[0] = n
+    splits = []
+    for i in range(stop):
+        leaf = rng.randint(0, i + 1)
+        b, c = int(seg_begin[leaf]), int(seg_cnt[leaf])
+        r = rng.rand()
+        n_left = c if r < 0.2 else 0 if r < 0.4 else rng.randint(0, c + 1)
+        splits.append((b, c, n_left))
+        seg_cnt[leaf] = n_left
+        seg_begin[i + 1], seg_cnt[i + 1] = b + n_left, c - n_left
+    return seg_begin, seg_cnt, splits
+
+
+@pytest.mark.parametrize("leaves", [2, 63, 64, 255, 511])
+@pytest.mark.parametrize("stops_early", [False, True])
+def test_segment_leaves_is_the_per_split_select(leaves, stops_early):
+    """From the final segment bounds alone, `segment_leaves` gives the
+    map the per-split select builds, bit for bit, over drawn splits that
+    leave right children empty, left children empty and empty leaves
+    split again; and over a tree that stops early, whose unused leaves
+    keep a count of 0 and a begin of 0. Past 256 leaves a step takes
+    two bfloat16 pieces."""
+    from lightgbm_tpu.ops.partition import segment_leaves
+    rng = np.random.RandomState(leaves)
+    n = 3 * 4096
+    derive = jax.jit(segment_leaves, static_argnums=2)
+    empty_right = 0
+    for _ in range(4):
+        stop = rng.randint(1, leaves) if stops_early else leaves - 1
+        seg_begin, seg_cnt, splits = _drawn_splits(rng, n, leaves, stop)
+        got = np.asarray(derive(jnp.asarray(seg_begin), jnp.asarray(seg_cnt),
+                                n))
+        np.testing.assert_array_equal(got, _per_split_select(n, splits))
+        assert not stops_early or seg_cnt[stop + 1:].sum() == 0
+        empty_right += sum(c > 0 and nl == c for _, c, nl in splits)
+    assert leaves == 2 or empty_right
+
+
+def _record_splits(monkeypatch):
+    """What the builder's program reports to the host, a device at a
+    time (its shard under the data-parallel learner's `shard_map`, else
+    0): each split's segment begin, count and left count, and each
+    tree's once-a-tree map."""
+    from lightgbm_tpu.models import partitioned
+    from lightgbm_tpu.parallel.mesh import AXIS
+    events = []
+
+    def device():
+        try:
+            return jax.lax.axis_index(AXIS)
+        except NameError:          # no row shards
+            return jnp.int32(0)
+
+    def note(*values):
+        events.append(tuple(int(v) for v in values))
+
+    partition = partitioned._partition_segment
+    derive = partitioned.segment_leaves
+
+    def recorded_partition(words, ghc, perm, seg_b, seg_c, *rest):
+        out = partition(words, ghc, perm, seg_b, seg_c, *rest)
+        jax.debug.callback(note, device(), seg_b, seg_c, out[3])
+        return out
+
+    def recorded_derive(seg_begin, seg_cnt, n):
+        out = derive(seg_begin, seg_cnt, n)
+        jax.debug.callback(
+            lambda d, o: events.append((int(d), np.asarray(o))),
+            device(), out)
+        return out
+
+    monkeypatch.setattr(partitioned, "_partition_segment",
+                        recorded_partition)
+    monkeypatch.setattr(partitioned, "segment_leaves", recorded_derive)
+    return events
+
+
+# (num_leaves, extra params, classes) of a booster whose every tree's
+# map is held against the per-split select
+POS_LEAF_CASES = {
+    "l2": (2, {}, 1),
+    "l63": (63, {}, 1),
+    "l64": (64, {}, 1),
+    "l255": (255, {}, 1),
+    "stops_early": (255, {"min_data_in_leaf": 400}, 1),
+    "bagging": (63, {"bagging_fraction": 0.5, "bagging_freq": 1}, 1),
+    "categorical": (63, {}, 1),
+    "class_scan": (15, {}, 3),
+    # four row shards of 4,096 over 5,000 rows: the last two hold only
+    # padding, and a shard's side of a split is often empty
+    "data_parallel": (63, {"tree_learner": "data", "num_machines": 4}, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(POS_LEAF_CASES))
+def test_pos_leaf_once_a_tree_is_the_per_split_select(case, monkeypatch):
+    """The map the builder derives once a tree from its segment bounds
+    is, bit for bit, the one the per-split select (written out here)
+    builds from the splits the program made, on every tree of two fused
+    iterations: at 2, 63, 64 and 255 leaves, in a tree that stops early,
+    with out-of-bag and padding rows inside the segments, with
+    categorical splits, for each class of the class scan and on each
+    shard of the data-parallel learner (local positions and bounds,
+    empty children among them)."""
+    leaves, extra, k = POS_LEAF_CASES[case]
+    events = _record_splits(monkeypatch)
+    rng = np.random.RandomState(5)
+    n, f = 5000, 4
+    x = rng.rand(n, f).astype(np.float32)
+    categorical = ()
+    if case == "categorical":
+        x[:, 0] = rng.randint(0, 12, size=n)
+        categorical = (0,)
+    if k == 1:
+        y = (x[:, 0] / (12 if categorical else 1) + 0.5 * x[:, 1] * x[:, 2]
+             + 0.2 * rng.randn(n) > 0.7).astype(np.float32)
+        if categorical:
+            y = np.where(np.isin(x[:, 0], [2, 5, 7]), 1.0 - y, y)
+        params = {"objective": "binary"}
+    else:
+        y = ((x[:, 0] * 3 + x[:, 1] * 2).astype(np.int32) % k).astype(
+            np.float32)
+        params = {"objective": "multiclass", "num_class": k}
+    params.update({"num_leaves": leaves, "max_bin": 32, "metric_freq": 0,
+                   "min_data_in_leaf": 1, "min_sum_hessian_in_leaf": 1e-3,
+                   "learning_rate": 0.1, "partitioned_build": "true"})
+    params.update(extra)
+    booster = _booster(x, y, params, categorical)
+    learner = booster.tree_learner
+    assert learner._use_partitioned and booster._fused_eligible()
+    booster.train_many(2)
+
+    shards = getattr(learner, "n_shards", 1)
+    pending, trees = {}, []
+    for event in events:
+        if len(event) == 4:
+            pending.setdefault(event[0], []).append(event[1:])
+        else:
+            shard, got = event
+            splits = pending.pop(shard, [])
+            np.testing.assert_array_equal(
+                got, _per_split_select(got.size, splits))
+            trees.append(splits)
+    assert not pending
+    assert len(trees) == 2 * k * shards
+    assert booster.metrics.snapshot()["gauges"]["pos_leaf_form"] == \
+        "once_a_tree"
+    counts = {len(s) for s in trees}
+    if case == "stops_early":
+        assert max(counts) < leaves - 1
+    else:
+        assert counts == {leaves - 1}
+    if case == "bagging":
+        assert booster._bagging_device_fn() is not None
+    if case == "categorical":
+        assert any((t.decision_type == 1).any() for t in booster.models)
+    if case == "class_scan":
+        assert booster.metrics.snapshot()["gauges"]["class_axis_form"] == \
+            "scan"
+    if case == "data_parallel":
+        assert shards == 4
+        splits = [sp for s in trees for sp in s]
+        assert any(c > 0 and n_left == c for _, c, n_left in splits)
+        assert any(c == 0 for _, c, _ in splits)
 
 
 @pytest.mark.parametrize("classes", [0, 3])

@@ -611,6 +611,7 @@ def test_seg_hist_onehot_gauges(max_bin, rows, features):
     assert gauges["partition_rows_words"] == 8
     assert gauges["partition_rows_chunk_lanes"] == 2048
     assert gauges["partition_rows_tile_form"] == "one_perm+roll"
+    assert gauges["pos_leaf_form"] == "once_a_tree"
 
 
 def test_process_tracer_holds_dataset_spans(trained):
@@ -875,6 +876,53 @@ def test_scopes_survive_the_tpu_compiler(one_chip):
     assert not any(re.search(r" scatter\(", ln) for ln in tail)
     sorts = [ln for ln in tail if re.search(r" sort\(", ln)]
     assert len(sorts) == 1 and "s32[16384]" in sorts[0], sorts
+
+
+def top_level_lines(text):
+    """The instructions of the computations a fusion does not call: what
+    the chip runs as operations of their own (a fusion's body is inside
+    its fusion)."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", text))
+    out, inside = [], False
+    for ln in text.splitlines():
+        head = re.match(r"(ENTRY )?(%[\w.\-]+) \(", ln)
+        if head:
+            inside = head.group(2) not in fused
+        elif inside and re.match(r"\s+(ROOT )?%", ln):
+            out.append(ln)
+    return out
+
+
+def test_pos_leaf_is_made_once_after_the_split_loop(one_chip):
+    """The chip's compiler keeps the position -> leaf map out of the
+    split loop: nothing in the loop's body is under `tree_state` /
+    `pos_leaf` (no select over all n_pad positions a split), one
+    operation outside the loop writes the n_pad int32 values (the
+    product's fusion, its one-hot and steps made inside it), and nothing
+    under `pos_leaf` gathers, scatters or sorts; the builder scatters
+    nothing anywhere."""
+    n = 4 * 4096
+    core, shapes = builder(n, l=255)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    with fresh_compiles(), \
+            mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(core).lower(*args).compile().as_text()
+    path = lambda ln: re.search(r'op_name="([^"]*)"', ln).group(1).split("/")
+    under = [ln for ln in text.splitlines()
+             if re.search(r'op_name="[^"]*/tree_state/pos_leaf/', ln)]
+    assert under
+    in_loop = [ln for ln in under if "while" in path(ln)]
+    assert not in_loop, in_loop[0][:300]
+    size = lambda ln: re.match(r"\s*(ROOT )?%\S+ = s32\[([\d,]*)\]", ln)
+    whole = [ln for ln in top_level_lines(text)
+             if ln in under and size(ln)
+             and np.prod([int(d) for d in size(ln).group(2).split(",")
+                          if d]) == n]
+    assert len(whole) == 1 and " fusion(" in whole[0], whole
+    assert not any(re.search(r" (gather|scatter|sort)\(", ln)
+                   for ln in under)
+    assert not re.search(r" scatter\(", text)
 
 
 @pytest.mark.parametrize("leaves,classes", [(63, 0), (64, 0), (255, 0),

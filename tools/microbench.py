@@ -22,6 +22,10 @@ measured numbers instead of guesses:
                  after checking the two bit-equal
   - score_update: the end of a tree, generic scatter + gather against
                  the program's un-permute + leaf-value lookup, bit-equal
+  - pos_leaf:    a 255-leaf tree's position -> leaf map, the per-split
+                 select over all N positions (254 of them) against the
+                 once-a-tree derivation from the segment bounds
+                 (ops/partition.py segment_leaves), bit-equal
   - fused_iter:  one full boosting iteration (gradients + whole tree +
                  score update) for BOTH builders at the bench config
 
@@ -37,7 +41,7 @@ not in the table is an error, not a default.
 Usage:  python tools/microbench.py [N] [K] [rows]
         rows: comma-separated row groups to run (default all): stream,
         take, cumsum, argsort, masked_hist, segment_hist, partition,
-        score_update, fused_iter
+        score_update, pos_leaf, fused_iter
 """
 
 import os
@@ -329,6 +333,62 @@ def bench_score_update(n_pad, k, rng):
                        step_bytes=12 * n_pad + 12 * n)
 
 
+def bench_pos_leaf(n_pad, k, rng, leaves=255):
+    """A tree's position -> leaf map (scope `tree_state/pos_leaf`) two
+    ways, over one drawn leaf-wise tree of `leaves` leaves (a split
+    takes a leaf by its rows and sends a uniform share of them left):
+    the select over all n_pad positions at every split, as the split
+    loop kept it, against `segment_leaves` once from the final segment
+    bounds. Checked bit-equal first; a tree is a step of the chain."""
+    from lightgbm_tpu.ops.partition import segment_leaves
+
+    seg_begin = np.zeros(leaves, np.int32)
+    seg_cnt = np.zeros(leaves, np.int32)
+    seg_cnt[0] = n_pad
+    splits = []
+    for i in range(leaves - 1):
+        leaf = rng.choice(i + 1, p=seg_cnt[:i + 1] / n_pad)
+        b, c = int(seg_begin[leaf]), int(seg_cnt[leaf])
+        n_left = rng.randint(0, c + 1)
+        splits.append((b, c, n_left, i + 1))
+        seg_cnt[leaf] = n_left
+        seg_begin[i + 1], seg_cnt[i + 1] = b + n_left, c - n_left
+    split_arr = jnp.asarray(np.array(splits, np.int32))
+    seg_begin, seg_cnt = jnp.asarray(seg_begin), jnp.asarray(seg_cnt)
+
+    def per_split(pos_leaf):
+        pos = jnp.arange(n_pad, dtype=jnp.int32)
+
+        def select(i, pl):
+            b, c, n_left, right_id = (split_arr[i, j] for j in range(4))
+            return jnp.where((pos >= b + n_left) & (pos < b + c),
+                             right_id, pl)
+
+        # a bound read at run time keeps XLA from unrolling the loop and
+        # fusing the selects into one pass, which the split loop, with
+        # a tree's other work between them, cannot do
+        return jax.lax.fori_loop(0, leaves - 1 + (pos_leaf[0] >> 31),
+                                 select, pos_leaf)
+
+    def once(pos_leaf):
+        # the previous tree's map decides nothing (its ids are >= 0) but
+        # keeps XLA from hoisting the derivation out of the chain
+        return segment_leaves(seg_begin + (pos_leaf[0] >> 31), seg_cnt,
+                              n_pad)
+
+    want = np.asarray(jax.jit(per_split)(jnp.zeros(n_pad, jnp.int32)))
+    got = np.asarray(jax.jit(once)(jnp.zeros(n_pad, jnp.int32)))
+    if not np.array_equal(got, want):
+        raise SystemExit("pos_leaf once_a_tree: bits differ from the "
+                         "per-split select's")
+    for name, form in (("per_split_select", per_split),
+                       ("once_a_tree", once)):
+        chain_time(form, lambda i: jnp.asarray(want) + i, k,
+                   f"pos_leaf {name} n={n_pad} l={leaves}",
+                   step_bytes=4 * n_pad * (
+                       2 * (leaves - 1) if form is per_split else 1))
+
+
 def bench_fused_iter(n_pad, k):
     # ---- the ACTUAL bench unit: one full fused boosting iteration
     # (gradients + whole partitioned tree + score update) at the bench
@@ -419,6 +479,8 @@ def main():
         bench_partition(n_pad, k, words28, ghc_t)
     if want("score_update"):
         bench_score_update(n_pad, k, rng)
+    if want("pos_leaf"):
+        bench_pos_leaf(n_pad, k, rng)
     if want("fused_iter"):
         bench_fused_iter(n_pad, k)
 
